@@ -25,11 +25,12 @@ empty 128x128 tile and is reported by the bench as skip_fraction ~ 0.
 Sparse datapaths (``sparse_path_rows``): tile vs decoded
 (``EngineConfig.sparse``, DESIGN.md §9) on *fine-grained / ragged*
 spike patterns — the regime where whole-tile skips never fire
-(``skip_fraction ~ 0``) but per-row occupancy is low, so the
-gather-compacted kernel's pow2 bucket schedule still cuts MACs. Each
-row records both paths' wall time, the tile skip fraction, the decoded
-schedule's MAC fraction (executed / total c_block-steps, scaled by the
-compacted width), and the cross-validation of
+(``skip_fraction ~ 0``). Each executed decoded chunk is a masked full-K
+dot, so the decoded kernel saves only the row groups its occupancy sort
+leaves all dark. Each row records both paths' wall time, the tile skip
+fraction, the decoded schedule's MAC fraction (executed full-K chunk
+dots per row group, ``spike_decode.decoded_dot_fraction``), and the
+cross-validation of
 ``sim/balance_sim.predicted_schedule`` (Binomial occupancies from the
 generator's density model) against the measured tensor schedule
 (``kernels/spike_decode.build_schedule``) — ``sched_agreement`` is
@@ -226,7 +227,9 @@ def sparse_path_bench(fast: bool = False):
     import numpy as np
 
     from repro.core import engine as E
-    from repro.kernels.spike_decode import build_schedule, choose_sparse_path
+    from repro.kernels.spike_decode import (build_schedule,
+                                            choose_sparse_path,
+                                            decoded_dot_fraction)
     from repro.kernels.spike_matmul import block_occupancy
     from repro.sim.balance_sim import predicted_schedule
 
@@ -257,8 +260,7 @@ def sparse_path_bench(fast: bool = False):
             tile_skip = float(1.0 - occ_tiles.mean())
             occ_rows = (s != 0).sum(-1).astype(jnp.int32)
             meas = build_schedule(occ_rows, block, block, cap=k)
-            dec_frac = float(meas["mac_fraction"]) * \
-                meas["padded_cap"] / k
+            dec_frac = decoded_dot_fraction(meas)
             pred = predicted_schedule(m, k, np.asarray(dens), block,
                                       block, np.random.default_rng(0))
             rows.append({
@@ -586,8 +588,8 @@ def bench(fast: bool = False):
         "popcount_vs_mxu_median": med(
             [r["popcount_vs_mxu"] for r in attn_rows]),
         # tile-vs-decoded on fine-grained/ragged patterns (DESIGN.md §9):
-        # the tile skip is ~0 there by construction, so the decoded MAC
-        # reduction is the whole sparse-engine story in that regime
+        # the tile skip is ~0 there by construction, and each executed
+        # decoded chunk is a full-K dot, so decoded saves nothing either
         "sparse_path_points": len(sp_rows),
         "decoded_max_modeled_speedup": max(
             r["decoded_modeled_speedup"] for r in sp_rows),

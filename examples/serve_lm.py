@@ -2,7 +2,7 @@
 kind): submit N requests into a slot-limited decode server; finished
 sequences free slots for queued requests.
 
-    PYTHONPATH=src python examples/serve_lm.py --arch h2o-danube-3-4b
+    PYTHONPATH=src python examples/serve_lm.py --arch h2o-danube-3-4b --smoke
 """
 import sys
 

@@ -18,9 +18,9 @@ CONFIG = ModelConfig(
     # the attention volume clears the same flop floor). The floor keeps
     # CPU smoke shapes on the plain XLA paths (engine dispatch is still
     # exercised — it just resolves dense/jnp there). sparse='auto' lets
-    # eager (non-jit) sparse calls pick the gather-compacted decoded
-    # datapath from the occupancy histogram when the spikes are ragged
-    # rather than tile-coherent (DESIGN.md §9).
+    # eager (non-jit) sparse calls pick the decoded datapath from the
+    # occupancy histogram when its sort leaves whole row groups dark
+    # that the tile map cannot skip (DESIGN.md §9).
     engine=EngineConfig(mode="auto", sparse="auto", overlap="auto"),
 )
 

@@ -13,11 +13,10 @@ anything whose input is a {0,1} spike tensor) routes through
   * ``sparse`` — one of two zero-skipping Pallas datapaths, selected by
     ``EngineConfig.sparse`` (tile | decoded | auto, DESIGN.md §9):
     the block-sparse ``spike_matmul`` kernel skips all-zero (block_m x
-    block_k) spike tiles via the occupancy map, and the gather-compacted
-    ``spike_decode`` kernel prefix-compacts each row's non-zero
-    K-indices and contracts only the live weight rows, with rows binned
-    into pow2 occupancy buckets for uniform per-step work (the
-    fine-grained/ragged-sparsity regime the tile skip can't touch).
+    block_k) spike tiles via the occupancy map, and the rank-decoded
+    ``spike_decode`` kernel sorts rows by occupancy into pow2 buckets and
+    runs one masked full-K dot per executed compacted chunk, so it skips
+    only whole groups of dark rows (DESIGN.md §9).
 
 Binary engine — every spiking self-attention (``core.attention.
 spiking_attention``, the transformer family's spiking SSA) consults
@@ -93,15 +92,13 @@ class EngineConfig:
       - 'tile': the block-occupancy kernel (skips whole block_m x
         block_k spike tiles) — the conservative default, profitable at
         *coherent* sparsity;
-      - 'decoded': the gather-compacted kernel
-        (kernels/spike_decode.py) — per-row non-zero K-indices are
-        prefix-compacted and only the live weight rows are contracted,
-        with rows binned into pow2 occupancy buckets so every grid step
-        does uniform work. Wins at fine-grained / ragged sparsity where
-        whole tiles almost never go dark;
+      - 'decoded': the rank-decoded kernel (kernels/spike_decode.py) —
+        rows sorted by occupancy into groups, pow2 bucket caps, one
+        masked full-K dot per executed compacted chunk. It skips whole
+        groups of dark rows, never the MACs inside a live group;
       - 'auto': picks per call from the *concrete* occupancy histogram
-        (kernels/spike_decode.choose_sparse_path — tile skip fraction
-        vs bucket-schedule MAC fraction with the decoded path's
+        (kernels/spike_decode.choose_sparse_path — tile live-tile share
+        vs decoded full-K dots per row group, with the decoded path's
         overhead handicap). Under jit the spikes are traced and the
         histogram is unobservable, so auto falls back to 'tile' — the
         same static-dispatch principle as ``mode`` / ``binary``.
@@ -134,11 +131,10 @@ class EngineConfig:
       interleave with layer l+1's Q/K/V phases on a pipelined backend
       (bundle-level steps treat it as 'fused': the bundle has no MLP
       tail to pipeline). 'auto' fuses only when the step's flop volume
-      clears ``min_flops``, the input is concrete, and the backend is
-      interpretable (same static-dispatch discipline as ``sparse``:
-      under jit / on a real TPU auto resolves 'off'; explicit
-      'fused'/'pipeline' are honored everywhere — auto never volunteers
-      'pipeline'). The fused steps are eval-only (train-mode BN needs
+      clears ``min_flops`` and the input is concrete (same static-
+      dispatch discipline as ``sparse``: under jit auto resolves 'off';
+      explicit 'fused'/'pipeline' are honored everywhere — auto never
+      volunteers 'pipeline'). The fused steps are eval-only (train-mode BN needs
       global batch stats) and fall back to the sequential composition
       for layer shapes they do not cover (bias terms, mixed
       quantization, GQA, qk_norm, gated MLPs — see layer_step /
@@ -268,11 +264,8 @@ def resolve_sparse_path(engine: Optional[EngineConfig],
     Static when it has to be: 'auto' consults the concrete occupancy
     histogram (the decoded path's per-call crossover, DESIGN.md §9) only
     when the spikes are concrete — under jit the input is a tracer and
-    auto resolves 'tile', the conservative static default. On a real TPU
-    backend auto also resolves 'tile': the decoded kernel's in-kernel
-    row gather is validated in interpret mode but not yet against Mosaic
-    lowering (DESIGN.md §9 caveat), so auto never volunteers it there —
-    an explicit 'tile'/'decoded' declaration is honored everywhere.
+    auto resolves 'tile', the conservative static default. An explicit
+    'tile'/'decoded' declaration is honored everywhere.
     """
     if engine is None:
         return "tile"
@@ -281,8 +274,6 @@ def resolve_sparse_path(engine: Optional[EngineConfig],
     # EngineConfig.__post_init__ already rejected anything else
     assert engine.sparse == "auto", engine.sparse
     if s2d is None or isinstance(s2d, jax.core.Tracer):
-        return "tile"
-    if jax.default_backend() == "tpu":
         return "tile"
     from repro.kernels.spike_decode import choose_sparse_path  # lazy
     return choose_sparse_path(s2d, engine.block_m, engine.block_k)
@@ -317,9 +308,8 @@ def resolve_overlap(engine: Optional[EngineConfig],
 
     Same static-dispatch discipline as :func:`resolve_sparse_path`:
     'auto' fuses only when the input is concrete (under jit — e.g. inside
-    the block scan — it is a tracer and auto resolves 'off'), off a real
-    TPU backend (the fused kernel is validated in interpret mode, not yet
-    against Mosaic lowering), and when the bundle's flop volume
+    the block scan — it is a tracer and auto resolves 'off') and when the
+    bundle's flop volume
     (three projections + both attention matmuls) clears ``min_flops`` —
     the fused grid stages whole Q/K/V spike trains through VMEM scratch,
     which tiny smoke shapes can't amortize. Explicit 'fused' and
@@ -334,8 +324,6 @@ def resolve_overlap(engine: Optional[EngineConfig],
     # EngineConfig.__post_init__ already rejected anything else
     assert engine.overlap == "auto", engine.overlap
     if x is None or isinstance(x, jax.core.Tracer):
-        return "off"
-    if jax.default_backend() == "tpu":
         return "off"
     return "fused" if flops >= engine.min_flops else "off"
 

@@ -22,14 +22,13 @@ same wavefront), with P = 8 per-head phases (:data:`LAYER_PHASES`):
 Three sparsity mechanisms, all *measured* (only executed sub-blocks
 reach the counts output) and all exact (skipped work contributes +0):
 
-* decoded gather (``sparse='decoded'``): each spike slab's live
-  entries are prefix-compacted on-device
-  (:func:`repro.kernels.spike_decode.slab_decode`, built on the PR 5
-  ``decode_indices``) and the projection phases contract only
-  ``w[idx]`` gathers, chunk-skipped under per-L-block pow2
-  occupancy-bucket caps — the fine-grained decoded datapath, now
+* decoded chunks (``sparse='decoded'``): each spike slab's live
+  entries are rank-decoded on-device into compacted chunks
+  (:func:`repro.kernels.spike_decode.slab_decode`) and the projection
+  phases contract chunk by chunk, skipping chunks past per-L-block
+  pow2 occupancy-bucket caps — the fine-grained decoded datapath,
   reachable from inside the fused step. Restricted to the spike-driven
-  family (vision): splitting the K contraction into gather chunks is
+  family (vision): splitting the K contraction into decoded chunks is
   only order-free in fp32 — hence bitwise — when every partial sum is
   exact ({0,1} spikes x dyadic / integer-code weights, DESIGN.md §4);
   the token family's projections consume *analog* normed currents, so
@@ -59,9 +58,14 @@ grids execute identical math — so :func:`reference_layer` below (the
 sequential layer composition ``models/spikingformer._block`` /
 ``models/transformer.apply_layer`` used to inline) is matched bitwise
 on the layer output, and is the recompute target of the fused path's
-custom VJP (``core.engine``). Like PR 5/6, validated in interpret mode
-(the container's execution mode); ``overlap='auto'`` never volunteers
-the fused layer on a real TPU backend.
+custom VJP (``core.engine``).
+
+Mosaic layout (pinned by ``tests/test_tpu_compile.py``): per-head
+weight, scale and epilogue operands are laid out head-major so each
+block's two minor dims are whole array dims; the decoded caps and the
+occupancy map live in SMEM; every phase walks L in ``l_block`` row
+blocks through static ref slices; and the call asks for the VMEM its
+resident (T, L, .) working set needs (:data:`VMEM_CAP`).
 """
 from __future__ import annotations
 
@@ -82,22 +86,39 @@ LAYER_PHASES = ("q", "k", "v", "qkt", "qktv", "wo", "up", "down")
 N_PHASES = len(LAYER_PHASES)
 
 
-def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
+# v5e's VMEM is 128 MiB; Mosaic's default scoped limit is 16 MiB. The
+# layer program keeps a batch row's whole (T, L, .) working set resident,
+# so it asks for what its blocks and scratch need (reckoned by
+# :func:`_vmem_bytes`) plus headroom for Mosaic's own temporaries, and
+# refuses shapes whose need passes this cap.
+VMEM_CAP = 100 << 20
+VMEM_HEADROOM = 16 << 20
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """VMEM bytes of one buffer: the two minor dims round up to the
+    (sublane, 128-lane) tile of the dtype (8 rows of 32 bits, so 16 for
+    bf16 and 32 for int8)."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, r, c = (1,) * (2 - len(shape)) + tuple(shape)
+    sub = 8 * max(1, 4 // item)
+    n = 1
+    for d in lead:
+        n *= d
+    return n * (-(-r // sub) * sub) * (-(-c // 128) * 128) * item
+
+
+def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
             head_dim, num_heads, ffc, l_block, c_block, nc, nlb, scale,
             causal, binarize_scores, decay, v_th, soft_reset, eps,
             norm_eps, dtype):
-    if decoded:
-        (x_ref, s_ref, w3_ref, wo_ref, w1_ref, w2_ref, sc3_ref, sco_ref,
-         sc1_ref, sc2_ref, auxp_ref, auxo_ref, aux1_ref, aux2_ref,
-         delta_ref, idx_ref, val_ref, cap_ref, o_ref, cnt_ref,
-         sq, sk, sv, scr, ctxs, hids, attn_acc, dn_acc, x1s, s2s,
-         uq, uk, uv, us2, uh) = refs
-    else:
-        (x_ref, s_ref, w3_ref, wo_ref, w1_ref, w2_ref, sc3_ref, sco_ref,
-         sc1_ref, sc2_ref, auxp_ref, auxo_ref, aux1_ref, aux2_ref,
-         delta_ref, o_ref, cnt_ref,
-         sq, sk, sv, scr, ctxs, hids, attn_acc, dn_acc, x1s, s2s,
-         uq, uk, uv, us2, uh) = refs
+    (x_ref, s_ref, w3_ref, wo_ref, w1_ref, w2_ref, sc3_ref, sco_ref,
+     sc1_ref, sc2_ref, auxp_ref, auxo_ref, aux1_ref, aux2_ref,
+     delta_ref) = refs[:15]
+    n_in = 17 if decoded else 15
+    cid_ref, cap_ref = refs[15:17] if decoded else (None, None)
+    (o_ref, cnt_ref, sq, sk, sv, scr, ctxs, hids, attn_acc, dn_acc, x1s,
+     s2s, uq, uk, uv, us2, uh) = refs[n_in:]
     if pipeline:
         b, ti = pl.program_id(0), pl.program_id(1)
         p, h = pl.program_id(2), pl.program_id(3)
@@ -109,108 +130,93 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
         trange = tuple(range(t_steps))
         first_step = (b == 0) & (p == 0) & (h == 0)
     half = head_dim // 2
+    # every phase walks the L axis in l_block row blocks: the skip
+    # granularity of the occupancy map, and static ref slices that Mosaic
+    # stores with masks where a block is not a multiple of 8 rows
     blocks = [(lb, lb * l_block, min(l, (lb + 1) * l_block))
               for lb in range(nlb)]
     slot = lambda t: h * t_steps + t          # flattened (head, t) scratch
-
-    def _patch(buf, r0, r1, val, *, axis=0, add=False):
-        # .at[] with a static slice covering the whole axis lowers to a
-        # scatter whose empty int32 index array pallas rejects as a
-        # captured constant; full coverage needs no slicing at all
-        if r0 == 0 and r1 == buf.shape[axis]:
-            return buf + val if add else val
-        if axis == 0:
-            return (buf.at[r0:r1].add(val) if add
-                    else buf.at[r0:r1].set(val))
-        return (buf.at[:, r0:r1].add(val) if add
-                else buf.at[:, r0:r1].set(val))
+    delta = delta_ref[0, 0]
+    dot = lambda a, w: jax.lax.dot_general(
+        a, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     @pl.when(first_step)
     def _init_counts():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        def zero(i, c):
+            cnt_ref[i] = jnp.int32(0)
+            return c
+        jax.lax.fori_loop(0, num_heads * N_PHASES * nlb, zero, 0)
 
     def _bump(col, nexec):
-        # occupancy map: executed sub-blocks for phase `col`, per L-block
-        vec = jnp.stack([n.astype(jnp.int32) for n in nexec])
-        ij = (h, jnp.int32(col), slice(None))
-        pl.store(cnt_ref, ij, pl.load(cnt_ref, ij) + vec)
+        # occupancy map (SMEM): executed sub-blocks of phase `col`
+        for lb in range(nlb):
+            i = (h * N_PHASES + col) * nlb + lb
+            cnt_ref[i] = cnt_ref[i] + nexec[lb]
 
-    def _lif(u_ref, uslot, t, y_t):
-        # one lif_step; the membrane rides scratch so the pipeline grid
-        # carries it across the T axis (the fused grid round-trips it
-        # within one invocation — identical values either way)
+    def _lif(u_ref, uslot, t, y, r0, r1):
+        # one lif_step on rows r0:r1; the membrane rides scratch so the
+        # pipeline grid carries it across the T axis (the fused grid
+        # round-trips it within one invocation — identical values)
         if pipeline:
-            u = jnp.where(t == 0, jnp.zeros_like(y_t), u_ref[uslot])
+            u = jnp.where(t == 0, jnp.zeros_like(y), u_ref[uslot, r0:r1])
         else:
-            u = jnp.zeros_like(y_t) if t == 0 else u_ref[uslot]
-        u = decay * u + y_t
+            u = jnp.zeros_like(y) if t == 0 else u_ref[uslot, r0:r1]
+        u = decay * u + y
         s_t = (u - v_th >= 0).astype(dtype)
         u = u - s_t * v_th if soft_reset else u * (1.0 - s_t)
-        u_ref[uslot] = u
+        u_ref[uslot, r0:r1] = u
         return s_t
+
+    def _bn(y, rows):
+        # nn.batchnorm eval affine; rows: (4, C) [mean, var, scale, bias]
+        y32 = y.astype(jnp.float32)
+        y32 = (y32 - rows[0:1]) * jax.lax.rsqrt(rows[1:2] + eps)
+        return (y32 * rows[2:3] + rows[3:4]).astype(dtype)
+
+    def _skip_dot(rows, w, n):
+        # occupancy skip: an all-dark row block contributes exact zeros
+        occ = jnp.any(rows != 0)
+        acc = jax.lax.cond(occ, lambda: dot(rows, w),
+                           lambda: jnp.zeros((rows.shape[0], n),
+                                             jnp.float32))
+        return acc, occ.astype(jnp.int32)
 
     def project(dst, u_ref, col, roped):
         # sparse-engine projection phase: per (timestep, L-block) either
-        # the decoded w[idx] gather chunks under the bucket caps or the
-        # tile path's occupancy-skipped dense dot, then the projection
-        # epilogue (quant scale, BN affine / RoPE) and LIF — per head.
-        w = w3_ref[0]                                    # (K, hd)
+        # the decoded datapath's compacted chunks under the bucket caps or
+        # the tile path's occupancy-skipped dot, then the projection
+        # epilogue (quant scale, BN affine / RoPE) and LIF — per head
+        w = w3_ref[0, 0]                                 # (K, hd)
         nexec = [jnp.int32(0)] * nlb
         for t in trange:
-            if decoded:
-                idx_t = idx_ref[0][t]                    # (L, Cp) int32
-                val_t = val_ref[0][t]                    # (L, Cp) fp32
-                cap_t = cap_ref[0][t]                    # (nlb,) int32
-            else:
-                slab = s_ref[0][t]                       # (L, K)
-            cur = jnp.zeros((l, head_dim), jnp.float32)
             for lb, r0, r1 in blocks:
+                rows = s_ref[0, t, r0:r1]                # (r, K)
                 if decoded:
+                    cids = cid_ref[0, t, r0:r1]
+                    cap = cap_ref[(b * t_steps + t) * nlb + lb]
                     acc = jnp.zeros((r1 - r0, head_dim), jnp.float32)
                     for ci in range(nc):
-                        live = ci * c_block < cap_t[lb]
-                        iblk = idx_t[r0:r1,
-                                     ci * c_block:(ci + 1) * c_block]
-                        vblk = val_t[r0:r1,
-                                     ci * c_block:(ci + 1) * c_block]
+                        live = ci * c_block < cap
                         acc = jax.lax.cond(
                             live,
-                            lambda a=acc, i=iblk, v=vblk: a +
-                            jax.lax.dot_general(
-                                v[:, None, :],
-                                w[i].astype(jnp.float32),
-                                (((2,), (1,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32)[:, 0],
+                            lambda a=acc, ci=ci: a + dot(
+                                jnp.where(cids == ci, rows, 0), w),
                             lambda a=acc: a)
                         nexec[lb] += live.astype(jnp.int32)
                 else:
-                    rows = slab[r0:r1]
-                    occ = jnp.any(rows != 0)
-                    acc = jax.lax.cond(
-                        occ,
-                        lambda r=rows: jax.lax.dot_general(
-                            r, w, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32),
-                        lambda: jnp.zeros((r1 - r0, head_dim),
-                                          jnp.float32))
-                    nexec[lb] += occ.astype(jnp.int32)
-                cur = _patch(cur, r0, r1, acc)
-            cur = cur * sc3_ref[0].astype(jnp.float32)   # quant epilogue
-            y_t = cur.astype(dtype)                      # act dtype, like
-            if family == "bn":                           # the dense ref
-                mean, var = auxp_ref[0, 0], auxp_ref[0, 1]
-                sc, bi = auxp_ref[0, 2], auxp_ref[0, 3]
-                y32 = y_t.astype(jnp.float32)
-                y32 = (y32 - mean) * jax.lax.rsqrt(var + eps)
-                y_t = (y32 * sc + bi).astype(dtype)      # nn.batchnorm eval
-            elif roped:                                  # rope: q, k only
-                cos, sin = auxp_ref[0], auxp_ref[1]      # (L, half)
-                x1 = y_t[..., :half].astype(jnp.float32)
-                x2 = y_t[..., half:].astype(jnp.float32)
-                y_t = jnp.concatenate([x1 * cos - x2 * sin,
-                                       x2 * cos + x1 * sin],
-                                      -1).astype(dtype)
-            dst[slot(t)] = _lif(u_ref, h, t, y_t)
+                    acc, n = _skip_dot(rows, w, head_dim)
+                    nexec[lb] += n
+                y = (acc * sc3_ref[0, 0].astype(jnp.float32)).astype(dtype)
+                if family == "bn":
+                    y = _bn(y, auxp_ref[0, 0])
+                elif roped:                              # rope: q, k only
+                    cos, sin = auxp_ref[0, r0:r1], auxp_ref[1, r0:r1]
+                    x1 = y[:, :half].astype(jnp.float32)
+                    x2 = y[:, half:].astype(jnp.float32)
+                    y = jnp.concatenate([x1 * cos - x2 * sin,
+                                         x2 * cos + x1 * sin],
+                                        -1).astype(dtype)
+                dst[slot(t), r0:r1] = _lif(u_ref, h, t, y, r0, r1)
         _bump(col, nexec)
 
     @pl.when(p == 0)
@@ -230,7 +236,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
                                  preferred_element_type=jnp.float32)
         sc = sc * scale
         if binarize_scores:
-            a = (sc - delta_ref[0, 0] >= 0).astype(jnp.float32)
+            a = (sc - delta >= 0).astype(jnp.float32)
         else:
             a = sc
         if causal:
@@ -246,7 +252,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
         # skip stays exact (+0) by construction
         live = jnp.any(k_blk != 0)
         if binarize_scores:
-            live = live | (delta_ref[0, 0] <= 0)
+            live = live | (delta <= 0)
         else:
             live = live | True
         return live
@@ -258,19 +264,16 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
         # skip is recorded in the occupancy map
         nexec = [jnp.int32(0)] * nlb
         for t in trange:
-            q_t, k_t = sq[slot(t)], sk[slot(t)]
-            a_t = jnp.zeros((l, l), jnp.float32)
+            q_t = sq[slot(t)]
             for lb, r0, r1 in blocks:
-                k_blk = k_t[r0:r1]
+                k_blk = sk[slot(t), r0:r1]
                 live = _qkt_live(k_blk)
-                a_blk = jax.lax.cond(
+                scr[slot(t), :, r0:r1] = jax.lax.cond(
                     live,
-                    lambda q=q_t, kb=k_blk, r=r0, n=r1 - r0:
-                        _score_block(q, kb, r, n),
+                    lambda kb=k_blk, r=r0, n=r1 - r0:
+                        _score_block(q_t, kb, r, n),
                     lambda n=r1 - r0: jnp.zeros((l, n), jnp.float32))
-                a_t = _patch(a_t, r0, r1, a_blk, axis=1)
                 nexec[lb] += live.astype(jnp.int32)
-            scr[slot(t)] = a_t
         _bump(3, nexec)
 
     @pl.when(p == 4)
@@ -280,18 +283,14 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
         # spikes are all dark contributes exact +0 and is skipped
         nexec = [jnp.int32(0)] * nlb
         for t in trange:
-            k_t, v_t = sk[slot(t)], sv[slot(t)]
-            a_t = scr[slot(t)]
             ctx = jnp.zeros((l, head_dim), jnp.float32)
             for lb, r0, r1 in blocks:
-                v_blk = v_t[r0:r1]
-                live = _qkt_live(k_t[r0:r1]) & jnp.any(v_blk != 0)
+                v_blk = sv[slot(t), r0:r1]
+                a_blk = scr[slot(t), :, r0:r1]
+                live = _qkt_live(sk[slot(t), r0:r1]) & jnp.any(v_blk != 0)
                 ctx = ctx + jax.lax.cond(
                     live,
-                    lambda a=a_t[:, r0:r1], v=v_blk: jax.lax.dot_general(
-                        a, v.astype(jnp.float32),
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32),
+                    lambda a=a_blk, v=v_blk: dot(a, v.astype(jnp.float32)),
                     lambda: jnp.zeros((l, head_dim), jnp.float32))
                 nexec[lb] += live.astype(jnp.int32)
             ctxs[slot(t)] = ctx.astype(dtype)
@@ -305,44 +304,33 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
         # dark context row blocks skip. The last head runs the epilogue:
         # quant scale, bn_o (vision) -> residual -> input neuron /
         # ln2 rmsnorm (token) into the MLP input scratch.
-        w = wo_ref[...]                                  # (hd, D)
+        w = wo_ref[0]                                    # (hd, D)
         nexec = [jnp.int32(0)] * nlb
         for t in trange:
             @pl.when(h == 0)
             def _zero():
                 attn_acc[t] = jnp.zeros((l, d_model), jnp.float32)
-            ctx_t = ctxs[slot(t)]
             for lb, r0, r1 in blocks:
-                rows = ctx_t[r0:r1]
-                occ = jnp.any(rows != 0)
-                contrib = jax.lax.cond(
-                    occ,
-                    lambda r=rows: jax.lax.dot_general(
-                        r, w, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32),
-                    lambda: jnp.zeros((r1 - r0, d_model), jnp.float32))
-                attn_acc[t] = _patch(attn_acc[t], r0, r1, contrib, add=True)
-                nexec[lb] += occ.astype(jnp.int32)
+                contrib, n = _skip_dot(ctxs[slot(t), r0:r1], w, d_model)
+                attn_acc[t, r0:r1] = attn_acc[t, r0:r1] + contrib
+                nexec[lb] += n
 
             @pl.when(h == num_heads - 1)
             def _epilogue():
-                y = attn_acc[t] * sco_ref[0].astype(jnp.float32)
-                y = y.astype(dtype)
+                y = (attn_acc[t] * sco_ref[...].astype(jnp.float32)
+                     ).astype(dtype)
                 if family == "bn":
-                    y32 = y.astype(jnp.float32)
-                    y32 = ((y32 - auxo_ref[0])
-                           * jax.lax.rsqrt(auxo_ref[1] + eps))
-                    y = (y32 * auxo_ref[2] + auxo_ref[3]).astype(dtype)
-                x1 = x_ref[0][t] + y                     # residual stream
+                    y = _bn(y, auxo_ref[...])
+                x1 = x_ref[0, t] + y                     # residual stream
                 x1s[t] = x1
                 if family == "bn":
-                    s2s[t] = _lif(us2, 0, t, x1)         # input neuron
+                    s2s[t] = _lif(us2, 0, t, x1, 0, l)   # input neuron
                 else:                                    # ln2 (nn.rmsnorm)
                     x32 = x1.astype(jnp.float32)
                     var = jnp.mean(jnp.square(x32), axis=-1,
                                    keepdims=True)
                     s2s[t] = (x32 * jax.lax.rsqrt(var + norm_eps)
-                              * auxo_ref[0].astype(jnp.float32)
+                              * auxo_ref[...].astype(jnp.float32)
                               ).astype(dtype)
         _bump(5, nexec)
 
@@ -351,30 +339,16 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
         # sparse engine, MLP up: ff-chunk h of w1 against the full-D
         # spike (vision) / normed-current (token) rows; epilogue
         # bn_1 + LIF (vision) or LIF (token) into the hidden spikes
-        w = w1_ref[...]                                  # (D, ffc)
+        w = w1_ref[0]                                    # (D, ffc)
         nexec = [jnp.int32(0)] * nlb
         for t in trange:
-            s2_t = s2s[t]
-            cur = jnp.zeros((l, ffc), jnp.float32)
             for lb, r0, r1 in blocks:
-                rows = s2_t[r0:r1]
-                occ = jnp.any(rows != 0)
-                acc = jax.lax.cond(
-                    occ,
-                    lambda r=rows: jax.lax.dot_general(
-                        r, w, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32),
-                    lambda: jnp.zeros((r1 - r0, ffc), jnp.float32))
-                cur = _patch(cur, r0, r1, acc)
-                nexec[lb] += occ.astype(jnp.int32)
-            cur = cur * sc1_ref[0].astype(jnp.float32)
-            y_t = cur.astype(dtype)
-            if family == "bn":
-                y32 = y_t.astype(jnp.float32)
-                y32 = ((y32 - aux1_ref[0])
-                       * jax.lax.rsqrt(aux1_ref[1] + eps))
-                y_t = (y32 * aux1_ref[2] + aux1_ref[3]).astype(dtype)
-            hids[slot(t)] = _lif(uh, h, t, y_t)
+                acc, n = _skip_dot(s2s[t, r0:r1], w, ffc)
+                nexec[lb] += n
+                y = (acc * sc1_ref[0].astype(jnp.float32)).astype(dtype)
+                if family == "bn":
+                    y = _bn(y, aux1_ref[0])
+                hids[slot(t), r0:r1] = _lif(uh, h, t, y, r0, r1)
         _bump(6, nexec)
 
     @pl.when(p == 7)
@@ -383,37 +357,24 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, k_dim, d_model,
         # hidden spikes, fp32-accumulated across chunks; the last chunk
         # runs the epilogue (quant scale, bn_2, residual) and writes
         # the layer output
-        w = w2_ref[...]                                  # (ffc, D)
+        w = w2_ref[0]                                    # (ffc, D)
         nexec = [jnp.int32(0)] * nlb
         for t in trange:
             @pl.when(h == 0)
             def _zero():
                 dn_acc[t] = jnp.zeros((l, d_model), jnp.float32)
-            hid_t = hids[slot(t)]
             for lb, r0, r1 in blocks:
-                rows = hid_t[r0:r1]
-                occ = jnp.any(rows != 0)
-                contrib = jax.lax.cond(
-                    occ,
-                    lambda r=rows: jax.lax.dot_general(
-                        r, w, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32),
-                    lambda: jnp.zeros((r1 - r0, d_model), jnp.float32))
-                dn_acc[t] = _patch(dn_acc[t], r0, r1, contrib, add=True)
-                nexec[lb] += occ.astype(jnp.int32)
+                contrib, n = _skip_dot(hids[slot(t), r0:r1], w, d_model)
+                dn_acc[t, r0:r1] = dn_acc[t, r0:r1] + contrib
+                nexec[lb] += n
 
             @pl.when(h == num_heads - 1)
             def _epilogue():
-                y = dn_acc[t] * sc2_ref[0].astype(jnp.float32)
-                y = y.astype(dtype)
+                y = (dn_acc[t] * sc2_ref[...].astype(jnp.float32)
+                     ).astype(dtype)
                 if family == "bn":
-                    y32 = y.astype(jnp.float32)
-                    y32 = ((y32 - aux2_ref[0])
-                           * jax.lax.rsqrt(aux2_ref[1] + eps))
-                    y = (y32 * aux2_ref[2] + aux2_ref[3]).astype(dtype)
-                pl.store(o_ref, (jnp.int32(0), jnp.asarray(t, jnp.int32),
-                                 slice(None), slice(None)),
-                         x1s[t] + y)
+                    y = _bn(y, aux2_ref[...])
+                o_ref[0, t] = x1s[t] + y
         _bump(7, nexec)
 
 
@@ -455,7 +416,7 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
         ``(4, F)``, bn_2 ``(4, D)`` eval rows; family ``'rope'``: auxo
         is the ln2 rmsnorm scale ``(1, D)`` and aux1/aux2 are ignored.
       sparse: ``'tile'`` (L-block occupancy skip) or ``'decoded'``
-        (gather-compacted projection contraction; spike-driven family
+        (compacted-chunk projection contraction; spike-driven family
         only — see module docstring).
       pipeline: run the ``(B, T, P, H)`` per-timestep wavefront grid
         instead of ``(B, P, H)``; outputs and counts are identical.
@@ -464,6 +425,9 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
       (layer output ``(T, B, L, D)`` activation dtype,
        counts ``(H, 8, ceil(L / l_block))`` int32 — *executed* compute
        sub-blocks per head, phase (:data:`LAYER_PHASES`), L-block).
+
+    Raises ValueError when the layer's VMEM working set passes
+    :data:`VMEM_CAP`.
     """
     if family not in FAMILIES:
         raise ValueError(f"unknown fused-layer family {family!r} "
@@ -484,7 +448,7 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
     dtype = x.dtype
     l_block = max(1, min(l_block, l))
     nlb = -(-l // l_block)
-    # the decoded gather needs exact operands for order-free fp32
+    # the decoded chunks need exact operands for order-free fp32
     # accumulation; the token family's projection input is analog
     decoded = sparse == "decoded" and family == "bn"
     delta_op = jnp.asarray(delta, jnp.float32).reshape(1, 1)
@@ -506,115 +470,122 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
         grid = (b, N_PHASES, num_heads)
         ix = lambda f: (lambda bi, pi, hi: f(bi, pi, hi))
 
-    in_specs = [
-        pl.BlockSpec((1, t, l, d_model),
-                     ix(lambda bi, pi, hi: (bi, 0, 0, 0))),
-        pl.BlockSpec((1, t, l, d_model),
-                     ix(lambda bi, pi, hi: (bi, 0, 0, 0))),
-        pl.BlockSpec((1, k_dim, head_dim),
-                     ix(lambda bi, pi, hi: (jnp.minimum(pi, 2), 0, hi))),
-        pl.BlockSpec((head_dim, d_model), ix(lambda bi, pi, hi: (hi, 0))),
-        pl.BlockSpec((k_dim, ffc), ix(lambda bi, pi, hi: (0, hi))),
-        pl.BlockSpec((ffc, d_model), ix(lambda bi, pi, hi: (hi, 0))),
-        pl.BlockSpec((1, head_dim),
-                     ix(lambda bi, pi, hi: (jnp.minimum(pi, 2), hi))),
-        pl.BlockSpec((1, d_model), ix(lambda bi, pi, hi: (0, 0))),
-        pl.BlockSpec((1, ffc), ix(lambda bi, pi, hi: (0, hi))),
-        pl.BlockSpec((1, d_model), ix(lambda bi, pi, hi: (0, 0))),
-    ]
-    operands = [xb, sb, w3, wo, w1, w2, sc3, sco.reshape(1, d_model),
-                sc1.reshape(1, ff), sc2.reshape(1, d_model)]
+    # Per-head operands are laid out head-major, so every block's two
+    # minor dims are whole array dims: Mosaic's (8, 128) tiling rule then
+    # holds for any head_dim and ff-chunk width.
+    def heads(a, n):                  # (..., H*n) -> (H, ..., n)
+        return jnp.moveaxis(a.reshape(*a.shape[:-1], num_heads, n), -2, 0)
+
+    whole = lambda a: pl.BlockSpec(a.shape, ix(lambda bi, pi, hi:
+                                               (0,) * a.ndim))
+    per_head = lambda a: pl.BlockSpec(
+        (1, *a.shape[1:]), ix(lambda bi, pi, hi: (hi,) + (0,) * (a.ndim - 1)))
+    per_proj = lambda a: pl.BlockSpec(
+        (1, 1, *a.shape[2:]),
+        ix(lambda bi, pi, hi: (jnp.minimum(pi, 2), hi, 0, 0)))
+    per_row = pl.BlockSpec((1, t, l, d_model),
+                           ix(lambda bi, pi, hi: (bi, 0, 0, 0)))
+
+    w3h = jnp.swapaxes(heads(w3, head_dim), 0, 1)    # (3, H, K, hd)
+    sc3h = jnp.swapaxes(heads(sc3[:, None, :], head_dim), 0, 1)
+    woh = wo.reshape(num_heads, head_dim, d_model)   # (H, hd, D)
+    w1h = heads(w1, ffc)                             # (H, D, ffc)
+    w2h = w2.reshape(num_heads, ffc, d_model)        # (H, ffc, D)
+    sc1h = heads(sc1[None, :], ffc)                  # (H, 1, ffc)
+    operands = [xb, sb, w3h, woh, w1h, w2h, sc3h, sco.reshape(1, d_model),
+                sc1h, sc2.reshape(1, d_model)]
+    in_specs = [per_row, per_row, per_proj(w3h), per_head(woh),
+                per_head(w1h), per_head(w2h), per_proj(sc3h),
+                whole(operands[7]), per_head(sc1h), whole(operands[9])]
     if family == "bn":
         assert auxp.shape == (3, 4, q_dim), auxp.shape
         assert auxo.shape == (4, d_model), auxo.shape
         assert aux1.shape == (4, ff), aux1.shape
         assert aux2.shape == (4, d_model), aux2.shape
-        in_specs += [
-            pl.BlockSpec((1, 4, head_dim),
-                         ix(lambda bi, pi, hi:
-                            (jnp.minimum(pi, 2), 0, hi))),
-            pl.BlockSpec((4, d_model), ix(lambda bi, pi, hi: (0, 0))),
-            pl.BlockSpec((4, ffc), ix(lambda bi, pi, hi: (0, hi))),
-            pl.BlockSpec((4, d_model), ix(lambda bi, pi, hi: (0, 0))),
-        ]
+        auxph = jnp.swapaxes(heads(auxp.astype(jnp.float32), head_dim),
+                             0, 1)                   # (3, H, 4, hd)
+        aux1 = heads(aux1.astype(jnp.float32), ffc)  # (H, 4, ffc)
+        aux_ops = [auxph, auxo, aux1, aux2]
+        aux_specs = [per_proj(auxph), whole(auxo), per_head(aux1),
+                     whole(aux2)]
     else:
         assert auxp.shape == (2, l, head_dim // 2), auxp.shape
         assert auxo.shape == (1, d_model), auxo.shape
-        aux1 = jnp.zeros((1, 1), jnp.float32)
-        aux2 = jnp.zeros((1, 1), jnp.float32)
-        in_specs += [
-            pl.BlockSpec((2, l, head_dim // 2),
-                         ix(lambda bi, pi, hi: (0, 0, 0))),
-            pl.BlockSpec((1, d_model), ix(lambda bi, pi, hi: (0, 0))),
-            pl.BlockSpec((1, 1), ix(lambda bi, pi, hi: (0, 0))),
-            pl.BlockSpec((1, 1), ix(lambda bi, pi, hi: (0, 0))),
-        ]
-    operands += [auxp.astype(jnp.float32), auxo.astype(jnp.float32),
-                 aux1.astype(jnp.float32), aux2.astype(jnp.float32)]
-    in_specs.append(pl.BlockSpec((1, 1), ix(lambda bi, pi, hi: (0, 0))))
+        dummy = jnp.zeros((1, 1), jnp.float32)
+        aux_ops = [auxp, auxo, dummy, dummy]
+        aux_specs = [whole(a) for a in aux_ops]
+    operands += [a.astype(jnp.float32) for a in aux_ops]
+    in_specs += aux_specs
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     operands.append(delta_op)
+    in_specs.append(smem)
 
     nc = 1
     c_blk = c_block
     if decoded:
         from repro.kernels.spike_decode import slab_decode
-        idx, vals, caps, c_blk = slab_decode(s, l_block=l_block,
-                                             c_block=c_block)
-        cp = idx.shape[-1]
-        nc = cp // c_blk
-        in_specs += [
-            pl.BlockSpec((1, t, l, cp),
-                         ix(lambda bi, pi, hi: (bi, 0, 0, 0))),
-            pl.BlockSpec((1, t, l, cp),
-                         ix(lambda bi, pi, hi: (bi, 0, 0, 0))),
-            pl.BlockSpec((1, t, nlb),
-                         ix(lambda bi, pi, hi: (bi, 0, 0))),
-        ]
-        operands += [idx, vals, caps]
+        cid, caps, c_blk, nc = slab_decode(s, l_block=l_block,
+                                           c_block=c_block)
+        operands += [cid, caps]
+        in_specs += [per_row, smem]
 
     kernel = functools.partial(
         _kernel, family=family, decoded=decoded, pipeline=pipeline,
-        t_steps=t, l=l, k_dim=k_dim, d_model=d_model, head_dim=head_dim,
+        t_steps=t, l=l, d_model=d_model, head_dim=head_dim,
         num_heads=num_heads, ffc=ffc, l_block=l_block, c_block=c_blk,
         nc=nc, nlb=nlb, scale=float(scale), causal=causal,
         binarize_scores=binarize_scores, decay=float(decay),
         v_th=float(v_th), soft_reset=soft_reset, eps=float(eps),
         norm_eps=float(norm_eps), dtype=dtype)
 
+    f32 = jnp.float32
+    scratch = [
+        ((num_heads * t, l, head_dim), dtype),       # q spikes
+        ((num_heads * t, l, head_dim), dtype),       # k spikes
+        ((num_heads * t, l, head_dim), dtype),       # v spikes
+        ((num_heads * t, l, l), f32),                # scores
+        ((num_heads * t, l, head_dim), dtype),       # contexts
+        ((num_heads * t, l, ffc), dtype),            # mlp hidden
+        ((t, l, d_model), f32),                      # wo accum
+        ((t, l, d_model), f32),                      # down accum
+        ((t, l, d_model), dtype),                    # x + attn
+        ((t, l, d_model), dtype),                    # mlp input
+        ((num_heads, l, head_dim), dtype),           # q membrane
+        ((num_heads, l, head_dim), dtype),           # k membrane
+        ((num_heads, l, head_dim), dtype),           # v membrane
+        ((1, l, d_model), dtype),                    # s2 membrane
+        ((num_heads, l, ffc), dtype),                # mlp membrane
+    ]
+    # VMEM reckoning: scratch once, every VMEM block double-buffered by
+    # the grid pipeline (the output row block included)
+    blocks = [(spec.block_shape, op.dtype)
+              for spec, op in zip(in_specs, operands)
+              if spec.block_shape is not None]
+    blocks.append(((1, t, l, d_model), dtype))
+    need = sum(_vmem_bytes(sh, dt) for sh, dt in scratch) + \
+        2 * sum(_vmem_bytes(sh, dt) for sh, dt in blocks)
+    if need > VMEM_CAP:
+        raise ValueError(
+            f"fused layer needs {need / 2**20:.1f} MiB of VMEM at "
+            f"(T, L, D, H, hd, ffc) = {(t, l, d_model, num_heads, head_dim, ffc)}"
+            f"; the cap is {VMEM_CAP / 2**20:.0f} MiB")
+
     out, cnt = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, t, l, d_model),
-                         ix(lambda bi, pi, hi: (bi, 0, 0, 0))),
-            pl.BlockSpec((num_heads, N_PHASES, nlb),
-                         ix(lambda bi, pi, hi: (0, 0, 0))),
-        ],
+        out_specs=[per_row, smem],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, l, d_model), dtype),
-            jax.ShapeDtypeStruct((num_heads, N_PHASES, nlb), jnp.int32),
+            jax.ShapeDtypeStruct((num_heads * N_PHASES * nlb,), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((num_heads * t, l, head_dim), dtype),  # q spikes
-            pltpu.VMEM((num_heads * t, l, head_dim), dtype),  # k spikes
-            pltpu.VMEM((num_heads * t, l, head_dim), dtype),  # v spikes
-            pltpu.VMEM((num_heads * t, l, l), jnp.float32),   # scores
-            pltpu.VMEM((num_heads * t, l, head_dim), dtype),  # contexts
-            pltpu.VMEM((num_heads * t, l, ffc), dtype),       # mlp hidden
-            pltpu.VMEM((t, l, d_model), jnp.float32),         # wo accum
-            pltpu.VMEM((t, l, d_model), jnp.float32),         # down accum
-            pltpu.VMEM((t, l, d_model), dtype),               # x + attn
-            pltpu.VMEM((t, l, d_model), dtype),               # mlp input
-            pltpu.VMEM((num_heads, l, head_dim), dtype),      # q membrane
-            pltpu.VMEM((num_heads, l, head_dim), dtype),      # k membrane
-            pltpu.VMEM((num_heads, l, head_dim), dtype),      # v membrane
-            pltpu.VMEM((1, l, d_model), dtype),               # s2 membrane
-            pltpu.VMEM((num_heads, l, ffc), dtype),           # mlp membrane
-        ],
+        scratch_shapes=[pltpu.VMEM(sh, dt) for sh, dt in scratch],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(VMEM_CAP, need + VMEM_HEADROOM)),
         interpret=interpret,
     )(*operands)
-    return jnp.transpose(out, (1, 0, 2, 3)), cnt
+    return (jnp.transpose(out, (1, 0, 2, 3)),
+            cnt.reshape(num_heads, N_PHASES, nlb))
 
 
 def reference_layer(x: jax.Array, s: jax.Array, w3, wo, w1, w2,

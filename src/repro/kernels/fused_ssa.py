@@ -15,11 +15,10 @@ binary-engine phase (AND-PopCount score + value tiles). Adjacent grid
 steps ``(b, h, attend)`` -> ``(b, h+1, project-Q)`` are exactly the
 Fig. 5 adjacency: on TPU, Pallas's pipelined grid prefetches head
 h+1's weight block while head h's attention tiles occupy the MXU, and
-the per-time-step spike slabs stream through an explicit ping-pong VMEM
-scratch via ``pltpu.make_async_copy`` (the BRAM double-buffer of the
-overlay, DESIGN.md §10). Q/K/V spike trains persist across the four
-phases in VMEM scratch — the L x d_head attention operands never leave
-the chip.
+the batch row's (T, L, K) spike block is double-buffered by the same
+pipeline (the BRAM double-buffer of the overlay, DESIGN.md §10). Q/K/V
+spike trains persist across the four phases in VMEM scratch — the L x
+d_head attention operands never leave the chip.
 
 Bit-exactness (DESIGN.md §4 contract): every projection contracts the
 *full* K dim in one fp32-accumulated dot (no K tiling — term-for-term
@@ -39,9 +38,10 @@ feeds those counts to the Fig. 5 event schedule, so the bench's
 from the analytic MAC model. Counts are data-deterministic, so CI gates
 them (``benchmarks/check_regression.py``).
 
-Like the decoded datapath (§9), this kernel is validated in interpret
-mode (the container's execution mode); Mosaic lowering on a real TPU is
-future work, so ``overlap='auto'`` never volunteers it there.
+Per-head weight, scale and epilogue operands are laid out head-major,
+so every block's two minor dims are whole array dims (Mosaic's (8, 128)
+rule at head_dim 32 or 64); the counts and the score threshold live in
+SMEM. ``tests/test_tpu_compile.py`` compiles it for a v5e.
 """
 from __future__ import annotations
 
@@ -60,7 +60,7 @@ PHASES = ("q", "k", "v", "attend")
 
 
 def _kernel(x_ref, w_ref, scale_ref, aux_ref, delta_ref, o_ref, cnt_ref,
-            qs, ks, vs, xbuf, sem, *, family, t_steps, l, k_dim, head_dim,
+            qs, ks, vs, *, family, t_steps, l, k_dim, head_dim,
             scale, causal, binarize_scores, decay, v_th, soft_reset, eps,
             has_scale, dtype):
     b, h, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -68,27 +68,15 @@ def _kernel(x_ref, w_ref, scale_ref, aux_ref, delta_ref, o_ref, cnt_ref,
 
     @pl.when((b == 0) & (p == 0))
     def _init_counts():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        for col in range(4):
+            cnt_ref[h * 4 + col] = jnp.int32(0)
 
     def project(dst, col, roped):
-        # Per-time-step spike/current slabs stream through a 2-slot
-        # ping-pong VMEM scratch: the async copy for step t+1 is in
-        # flight while step t's dot runs (the overlay's BRAM double
-        # buffer; on CPU interpret the copies complete synchronously,
-        # values are identical either way).
-        def copy(t):
-            return pltpu.make_async_copy(x_ref.at[0, t], xbuf.at[t % 2],
-                                         sem.at[t % 2])
-
-        copy(0).start()
-        w = w_ref[0]
+        w = w_ref[0, 0]                              # (K, hd)
         nexec = jnp.int32(0)
         vals = []
         for t in range(t_steps):
-            if t + 1 < t_steps:
-                copy(t + 1).start()
-            copy(t).wait()
-            slab = xbuf[t % 2]                       # (L, K)
+            slab = x_ref[0, t]                       # (L, K)
             occ = jnp.any(slab != 0)
             # occupancy skip: a dark slab contributes exact fp32 zeros,
             # so skipping its dot is bitwise-free — and *measured*: only
@@ -105,11 +93,12 @@ def _kernel(x_ref, w_ref, scale_ref, aux_ref, delta_ref, o_ref, cnt_ref,
         if has_scale:
             # quantized codes: per-output-channel scale in the epilogue,
             # exactly dense_quant_linear's expression order
-            cur = cur * scale_ref[0].astype(jnp.float32)
+            cur = cur * scale_ref[0, 0].astype(jnp.float32)
         y = cur.astype(dtype)                        # linear emits act dtype
         if family == "bn":
-            mean, var = aux_ref[0, 0], aux_ref[0, 1]
-            sc, bi = aux_ref[0, 2], aux_ref[0, 3]
+            rows = aux_ref[0, 0]                     # (4, hd)
+            mean, var = rows[0:1], rows[1:2]
+            sc, bi = rows[2:3], rows[3:4]
             y32 = y.astype(jnp.float32)
             y32 = (y32 - mean) * jax.lax.rsqrt(var + eps)
             y32 = y32 * sc + bi                      # nn.batchnorm (eval)
@@ -128,7 +117,7 @@ def _kernel(x_ref, w_ref, scale_ref, aux_ref, delta_ref, o_ref, cnt_ref,
             s_t = (u - v_th >= 0).astype(dtype)
             u = u - s_t * v_th if soft_reset else u * (1.0 - s_t)
             dst[t] = s_t
-        cnt_ref[0, col] += nexec
+        cnt_ref[h * 4 + col] += nexec
 
     @pl.when(p == 0)
     def _q():
@@ -159,8 +148,8 @@ def _kernel(x_ref, w_ref, scale_ref, aux_ref, delta_ref, o_ref, cnt_ref,
                 a = jnp.where(rows >= cols, a, 0.0)
             ctx = jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
-            o_ref[0, t] = ctx.astype(dtype)
-        cnt_ref[0, 3] += jnp.int32(2 * t_steps)
+            o_ref[0, 0, t] = ctx.astype(dtype)
+        cnt_ref[h * 4 + 3] += jnp.int32(2 * t_steps)
 
 
 def fused_ssa(x: jax.Array, w3: jax.Array, scale3: Optional[jax.Array],
@@ -205,30 +194,35 @@ def fused_ssa(x: jax.Array, w3: jax.Array, scale3: Optional[jax.Array],
     xb = jnp.transpose(x, (1, 0, 2, 3))              # (B, T, L, K)
     delta_op = jnp.asarray(delta, jnp.float32).reshape(1, 1)
 
-    w_idx = lambda bi, hi, pi: (jnp.minimum(pi, 2), 0, hi)
+    # Per-head operands are laid out head-major so every block's two
+    # minor dims are whole array dims: Mosaic's (8, 128) tiling rule then
+    # holds for any head_dim (a (K, hd) lane slice of the (K, H*hd)
+    # weights would not, at hd = 32 or 64).
+    heads = lambda a: jnp.moveaxis(
+        a.reshape(*a.shape[:-1], num_heads, head_dim), -2, 1)
+    w_idx = lambda bi, hi, pi: (jnp.minimum(pi, 2), hi, 0, 0)
     in_specs = [
         pl.BlockSpec((1, t, l, k_dim), lambda bi, hi, pi: (bi, 0, 0, 0)),
-        pl.BlockSpec((1, k_dim, head_dim), w_idx),
+        pl.BlockSpec((1, 1, k_dim, head_dim), w_idx),
     ]
-    operands = [xb, w3]
+    operands = [xb, heads(w3)]
     has_scale = scale3 is not None
     if not has_scale:
         # uniform kernel signature; multiplying fp32 by 1.0 is a bitwise
         # identity, so the fp-native path is unaffected
         scale3 = jnp.ones((3, q_dim), jnp.float32)
-    in_specs.append(pl.BlockSpec(
-        (1, head_dim), lambda bi, hi, pi: (jnp.minimum(pi, 2), hi)))
-    operands.append(scale3.astype(jnp.float32))
+    in_specs.append(pl.BlockSpec((1, 1, 1, head_dim), w_idx))
+    operands.append(heads(scale3.astype(jnp.float32)[:, None, :]))
     if family == "bn":
         assert aux.shape == (3, 4, q_dim), aux.shape
-        in_specs.append(pl.BlockSpec(
-            (1, 4, head_dim), lambda bi, hi, pi: (jnp.minimum(pi, 2), 0, hi)))
+        in_specs.append(pl.BlockSpec((1, 1, 4, head_dim), w_idx))
+        operands.append(heads(aux.astype(jnp.float32)))
     else:
         assert aux.shape == (2, l, head_dim // 2), aux.shape
         in_specs.append(pl.BlockSpec(
             (2, l, head_dim // 2), lambda bi, hi, pi: (0, 0, 0)))
-    operands.append(aux.astype(jnp.float32))
-    in_specs.append(pl.BlockSpec((1, 1), lambda bi, hi, pi: (0, 0)))
+        operands.append(aux.astype(jnp.float32))
+    in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     operands.append(delta_op)
 
     kernel = functools.partial(
@@ -243,24 +237,23 @@ def fused_ssa(x: jax.Array, w3: jax.Array, scale3: Optional[jax.Array],
         grid=(b, num_heads, 4),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, t, l, head_dim),
-                         lambda bi, hi, pi: (bi, 0, 0, hi)),
-            pl.BlockSpec((1, 4), lambda bi, hi, pi: (hi, 0)),
+            pl.BlockSpec((1, 1, t, l, head_dim),
+                         lambda bi, hi, pi: (bi, hi, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, t, l, q_dim), dtype),
-            jax.ShapeDtypeStruct((num_heads, 4), jnp.int32),
+            jax.ShapeDtypeStruct((b, num_heads, t, l, head_dim), dtype),
+            jax.ShapeDtypeStruct((num_heads * 4,), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((t, l, head_dim), dtype),     # q spikes
             pltpu.VMEM((t, l, head_dim), dtype),     # k spikes
             pltpu.VMEM((t, l, head_dim), dtype),     # v spikes
-            pltpu.VMEM((2, l, k_dim), dtype),        # ping-pong spike slab
-            pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
     )(*operands)
-    return jnp.transpose(out, (1, 0, 2, 3)), cnt
+    ctx = jnp.transpose(out, (2, 0, 3, 1, 4)).reshape(t, b, l, q_dim)
+    return ctx, cnt.reshape(num_heads, 4)
 
 
 def reference_bundle(x: jax.Array, w3: jax.Array,

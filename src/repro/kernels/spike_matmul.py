@@ -12,7 +12,10 @@ often at >=75% sparsity only when channel-blocks are coherently sparse;
 the occupancy reduction itself is the multi-lane decode).
 
 Grid: (nM, nN, nK), K innermost; fp32 accumulator in the revisited output
-block. The occupancy map is a tiny (nM, nK) int32 array staged per-step.
+block. The occupancy map is a tiny flattened (nM * nK,) int32 table handed
+to the kernel as a scalar-prefetch operand: it lives in SMEM for the whole
+grid, where the ``@pl.when`` predicate reads it as a scalar (a (1, 1)
+VMEM block per step would break Mosaic's (8, 128) tiling rule).
 A fused bias lands on the last K step, after the final accumulation, so
 the dense reference (fp32 dot, then bias) is reproduced term-for-term.
 
@@ -35,6 +38,12 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.bitpack import pad_to_multiple
 
 
+def _live(occ_ref):
+    """This step's spike block has a non-zero (scalar SMEM read)."""
+    nk = pl.num_programs(2)
+    return occ_ref[pl.program_id(0) * nk + pl.program_id(2)] > 0
+
+
 def _kernel(occ_ref, s_ref, w_ref, o_ref):
     ki = pl.program_id(2)
 
@@ -42,7 +51,7 @@ def _kernel(occ_ref, s_ref, w_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(occ_ref[0, 0] > 0)
+    @pl.when(_live(occ_ref))
     def _compute():
         s = s_ref[...].astype(jnp.float32)
         w = w_ref[...].astype(jnp.float32)
@@ -58,7 +67,7 @@ def _kernel_bias(occ_ref, s_ref, w_ref, b_ref, o_ref, *, nk):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(occ_ref[0, 0] > 0)
+    @pl.when(_live(occ_ref))
     def _compute():
         s = s_ref[...].astype(jnp.float32)
         w = w_ref[...].astype(jnp.float32)
@@ -84,11 +93,9 @@ def _qkernel(occ_ref, s_ref, w_ref, scale_ref, o_ref, acc_ref, *, nk):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(occ_ref[0, 0] > 0)
+    @pl.when(_live(occ_ref))
     def _compute():
-        acc_ref[...] += jax.lax.dot_general(
-            s_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        acc_ref[...] += int8_dot(s_ref[...], w_ref[...])
 
     @pl.when(ki == nk - 1)
     def _epilogue():
@@ -104,17 +111,30 @@ def _qkernel_bias(occ_ref, s_ref, w_ref, scale_ref, b_ref, o_ref, acc_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(occ_ref[0, 0] > 0)
+    @pl.when(_live(occ_ref))
     def _compute():
-        acc_ref[...] += jax.lax.dot_general(
-            s_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        acc_ref[...] += int8_dot(s_ref[...], w_ref[...])
 
     @pl.when(ki == nk - 1)
     def _epilogue():
         o_ref[...] = acc_ref[...].astype(jnp.float32) * \
             scale_ref[...].astype(jnp.float32) + \
             b_ref[...].astype(jnp.float32)
+
+
+def int8_dot(s: jax.Array, w: jax.Array) -> jax.Array:
+    """int32-exact ``s @ w`` on the MXU's int8 path: s int8 spikes, or
+    int32 binary-attention counts in [0, 2**14), against int8 weight
+    codes. The MXU multiplies int8 x int8 only (Mosaic lowers no int32
+    operand), so counts split into two 7-bit digits: ``lo + 128 * hi``,
+    each digit an exact int8 — the two int32-accumulated dots sum to the
+    exact product."""
+    dot = lambda a: jax.lax.dot_general(
+        a, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    if s.dtype == jnp.int8:
+        return dot(s)
+    return dot((s & 127).astype(jnp.int8)) + \
+        dot((s >> 7).astype(jnp.int8)) * 128
 
 
 def block_occupancy(s: jax.Array, block_m: int, block_k: int) -> jax.Array:
@@ -155,27 +175,26 @@ def spike_matmul(s: jax.Array, w: jax.Array, *,
 
     grid = (mp // block_m, np_ // block_n, kp // block_k)
     in_specs = [
-        pl.BlockSpec((1, 1), lambda mi, ni, ki: (mi, ki)),
-        pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
-        pl.BlockSpec((block_k, block_n), lambda mi, ni, ki: (ki, ni)),
+        pl.BlockSpec((block_m, block_k), lambda mi, ni, ki, occ: (mi, ki)),
+        pl.BlockSpec((block_k, block_n), lambda mi, ni, ki, occ: (ki, ni)),
     ]
-    operands = [occ, sp, wp]
+    operands = [sp, wp]
     if bias is None:
         kernel = _kernel
     else:
         kernel = functools.partial(_kernel_bias, nk=grid[2])
         in_specs.append(pl.BlockSpec((1, block_n),
-                                     lambda mi, ni, ki: (0, ni)))
+                                     lambda mi, ni, ki, occ: (0, ni)))
         operands.append(pad_to_multiple(bias.reshape(1, n), 1, block_n))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n),
-                               lambda mi, ni, ki: (mi, ni)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_m, block_n),
+                                   lambda mi, ni, ki, occ: (mi, ni))),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
-    )(*operands)
+    )(occ.reshape(-1), *operands)
     return out[:m, :n].astype(w.dtype if out_dtype is None else out_dtype)
 
 
@@ -224,12 +243,11 @@ def quant_spike_matmul(s: jax.Array, qw: jax.Array, scale: jax.Array, *,
 
     grid = (mp // block_m, np_ // block_n, kp // block_k)
     in_specs = [
-        pl.BlockSpec((1, 1), lambda mi, ni, ki: (mi, ki)),
-        pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
-        pl.BlockSpec((block_k, block_n), lambda mi, ni, ki: (ki, ni)),
-        pl.BlockSpec((1, block_n), lambda mi, ni, ki: (0, ni)),
+        pl.BlockSpec((block_m, block_k), lambda mi, ni, ki, occ: (mi, ki)),
+        pl.BlockSpec((block_k, block_n), lambda mi, ni, ki, occ: (ki, ni)),
+        pl.BlockSpec((1, block_n), lambda mi, ni, ki, occ: (0, ni)),
     ]
-    operands = [occ, s_int, wp,
+    operands = [s_int, wp,
                 pad_to_multiple(scale.reshape(1, n).astype(jnp.float32),
                                 1, block_n)]
     if bias is None:
@@ -237,19 +255,19 @@ def quant_spike_matmul(s: jax.Array, qw: jax.Array, scale: jax.Array, *,
     else:
         kernel = functools.partial(_qkernel_bias, nk=grid[2])
         in_specs.append(pl.BlockSpec((1, block_n),
-                                     lambda mi, ni, ki: (0, ni)))
+                                     lambda mi, ni, ki, occ: (0, ni)))
         operands.append(pad_to_multiple(
             bias.reshape(1, n).astype(jnp.float32), 1, block_n))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n),
-                               lambda mi, ni, ki: (mi, ni)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_m, block_n),
+                                   lambda mi, ni, ki, occ: (mi, ni)),
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=interpret,
-    )(*operands)
+    )(occ.reshape(-1), *operands)
     return out[:m, :n]
 
 

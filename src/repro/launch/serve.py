@@ -50,6 +50,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ALL_ARCHS, get_config
 from repro.configs.base import RunShape
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import registry
 from repro.parallel import rules as prules
 from repro.parallel.sharding import (fit_spec_to_shape, rules_for_mesh,
@@ -326,7 +327,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-3-4b",
                     choices=list(ALL_ARCHS))
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (CPU-sized)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=12)
@@ -343,7 +345,8 @@ def main():
                          "reports the measured footprint compression")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, smoke=True)
+    setup_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke)
     if not registry.has_decode(cfg):
         raise SystemExit(f"{args.arch} has no decode step")
     mesh = None
@@ -386,9 +389,12 @@ def main():
     dt = time.time() - t0
     n_gen = sum(len(r.generated) for r in server.completed)
     n_pre = sum(len(r.prompt) for r in server.completed)
+    dev = jax.devices()[0]
     print(f"[serve] {len(server.completed)} requests, {n_gen} generated "
           f"(+{n_pre} prompt) tokens, {steps} waves in {dt:.2f}s "
-          f"({(n_gen + n_pre)/dt:.1f} tok/s on CPU smoke config)")
+          f"({(n_gen + n_pre)/dt:.1f} tok/s, compiles included, on "
+          f"{dev.platform} {dev.device_kind} x{len(jax.devices())}, "
+          f"{cfg.name} {'smoke' if args.smoke else 'full'} config)")
     for r in server.completed[:3]:
         print(f"  req {r.rid}: {r.generated}")
 

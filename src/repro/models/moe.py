@@ -28,10 +28,6 @@ from repro.parallel.sharding import constrain, get_rules
 from . import nn
 from .transformer import _project_qkv, _attend_full_seq, _spike
 
-try:  # jax >= 0.4.35
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 # ---------------------------------------------------------------------------
 # Mesh context for EP (installed by the launch layer)
@@ -250,7 +246,7 @@ def moe_ffn(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
             return run(x_loc, router_w, up, gate, down,
                        e_local=e_local, offset=offset, in_map=True)
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             mapped, mesh=mesh,
             in_specs=(tok_spec, P(), P(expert_axis), P(expert_axis),
                       P(expert_axis)),

@@ -68,12 +68,7 @@ class use_rules:
 def _mesh_axes(mesh: Optional[Mesh]) -> Tuple[str, ...]:
     if mesh is not None:
         return tuple(mesh.axis_names)
-    # jax.sharding.get_abstract_mesh only exists on newer jax; on the
-    # pinned 0.4.x there is no ambient abstract mesh to consult.
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is None:
-        return ()
-    env = get_abstract_mesh()
+    env = jax.sharding.get_abstract_mesh()
     return tuple(env.axis_names) if env is not None else ()
 
 

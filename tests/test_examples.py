@@ -31,7 +31,7 @@ def run_example(script, *args, timeout=600):
 
 def test_serve_lm_smoke():
     out = run_example("serve_lm.py", "--arch", "spikingformer-lm",
-                      "--requests", "2", "--slots", "2",
+                      "--smoke", "--requests", "2", "--slots", "2",
                       "--prompt-len", "5", "--max-new", "2",
                       "--max-len", "32")
     assert "kv cache" in out and "requests" in out
@@ -39,7 +39,7 @@ def test_serve_lm_smoke():
 
 def test_serve_lm_quantized_smoke():
     out = run_example("serve_lm.py", "--arch", "spikingformer-lm",
-                      "--requests", "2", "--slots", "2",
+                      "--smoke", "--requests", "2", "--slots", "2",
                       "--prompt-len", "5", "--max-new", "2",
                       "--max-len", "32", "--quantize", "int8")
     assert "weights" in out and "int8" in out

@@ -48,6 +48,22 @@ def _req(rid, prompt, max_new):
     return Request(rid=rid, prompt=prompt, max_new_tokens=max_new)
 
 
+def _assert_same_logits(cfg, a, b):
+    """Logit rows of one request computed under different wave shapes.
+
+    Spiking configs compare bitwise: their attention is binary and their
+    projections see {0,1} spikes, so every sum is order-exact. The analog
+    control (h2o-danube-3-4b: softmax attention over real-valued
+    activations) reduces fp32 sums whose blocking XLA's CPU backend
+    picks per operand shape, and the batch shape differs between the
+    runs (slots x chunk width) — its rows agree to fp32 rounding
+    (observed: at most 3e-6 absolute), not to the bit."""
+    if cfg.spiking is not None:
+        np.testing.assert_array_equal(a, b)
+    else:
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # slot reuse isolation (the tentpole regression)
 # ---------------------------------------------------------------------------
@@ -145,8 +161,9 @@ def test_invalidate_slots_resets_only_masked_slot():
 def test_staggered_admission_matches_sequential_reference(arch):
     """Three requests with different prompt lengths over two slots: the
     third is admitted mid-flight while the survivors keep decoding. Every
-    request's sampled tokens and logit rows are bitwise-equal to its
-    single-request sequential run."""
+    request's sampled tokens equal its single-request sequential run, and
+    so do its logit rows (bitwise for spiking configs, see
+    :func:`_assert_same_logits`)."""
     cfg = get_config(arch, smoke=True)
     params = _params(cfg)
     mk = lambda: [_req(0, _prompt(cfg, 7, 5), 4),
@@ -159,7 +176,7 @@ def test_staggered_admission_matches_sequential_reference(arch):
         assert shared[proto.rid].generated == solo[proto.rid].generated
         for a, b in zip(shared[proto.rid].logit_trace,
                         solo[proto.rid].logit_trace):
-            np.testing.assert_array_equal(a, b)
+            _assert_same_logits(cfg, a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -172,7 +189,7 @@ def test_chunked_prefill_matches_whole_prompt_prefill(arch):
     """The first sampled logits row (the one conditioned on the whole
     prompt) agrees with build_prefill_step's last-position logits, for
     every chunk width; and all chunk widths agree with each other
-    bitwise."""
+    (bitwise for spiking configs, see :func:`_assert_same_logits`)."""
     cfg = get_config(arch, smoke=True)
     params = _params(cfg)
     prompt = _prompt(cfg, 11, 8)
@@ -186,7 +203,7 @@ def test_chunked_prefill_matches_whole_prompt_prefill(arch):
         rows.append(got[0].logit_trace[0])
         np.testing.assert_allclose(rows[-1], want, atol=2e-4, rtol=2e-4)
     for r in rows[1:]:
-        np.testing.assert_array_equal(rows[0], r)
+        _assert_same_logits(cfg, rows[0], r)
 
 
 def test_chunked_prefill_beyond_window_matches_tokenwise():

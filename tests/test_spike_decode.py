@@ -15,8 +15,10 @@ Pins, in order of the pipeline:
   * gradients flow through the shared custom VJP identically to dense;
   * whole-model logits are bitwise equal across dense/tile/decoded on
     both spikingformer configs;
-  * ``sparse='auto'`` picks tile at coherent sparsity, decoded at
-    fine-grained/ragged sparsity, and tile under jit (traced spikes).
+  * ``sparse='auto'`` picks tile at coherent and at fine-grained/ragged
+    sparsity (every live decoded row group costs a full-K dot per
+    chunk), decoded only where the occupancy sort leaves whole row
+    groups dark, and tile under jit (traced spikes).
 
 Bit-exactness strategy matches tests/test_engine.py: dyadic-grid weights
 make fp32 accumulation order-exact, so equality is to the bit, not a
@@ -221,9 +223,11 @@ def test_decoded_all_zero_input():
 
 
 def test_gather_matmul_equals_tile_kernel_on_arbitrary_weights():
-    """Both kernels accumulate the same fp32 terms in ascending-k order,
-    so on *sequentially accumulated* backends they agree on arbitrary
-    normal weights too (the tile kernel only adds exact zeros on top)."""
+    """With c_block = K every live entry decodes into chunk 0, so the
+    decoded kernel's one executed chunk is the tile kernel's block_k = K
+    dot on the same rows (sorted by occupancy, which leaves each row's
+    own sum alone): the two agree on arbitrary normal weights, not only
+    on order-exact dyadic ones."""
     ks = jax.random.split(jax.random.PRNGKey(2), 2)
     s = _ragged_spikes(ks[0], 80, 128, lo=0.0, hi=0.4)
     w = jax.random.normal(ks[1], (128, 48), jnp.float32)
@@ -314,6 +318,27 @@ def test_decoded_gradients_match_dense():
 # ---------------------------------------------------------------------------
 
 
+def _scattered_dark_rows(m, k):
+    """Three rows in four all-zero, interleaved with rows holding one
+    spike per 32 columns: every 32x32 tile is live, yet the occupancy
+    sort fills whole 32-row groups with dark rows."""
+    live = (jnp.arange(m)[:, None] % 4 == 0) & (jnp.arange(k)[None] % 32
+                                                == 0)
+    return live.astype(jnp.float32)
+
+
+def test_decoded_dot_fraction_counts_full_k_dots():
+    """Each executed chunk is a full-K dot: a live group costs at least
+    one dense sweep, only sorted-together dark groups cost none."""
+    def frac(s):
+        occ = (s != 0).sum(-1).astype(jnp.int32)
+        return SD.decoded_dot_fraction(SD.build_schedule(occ, 32, 32, 160))
+    ragged = _ragged_spikes(jax.random.PRNGKey(0), 96, 160, lo=0.0, hi=0.2)
+    assert frac(ragged) >= 1.0
+    assert frac(jnp.ones((96, 160))) == 5.0          # all 5 chunks, 3 groups
+    assert frac(_scattered_dark_rows(96, 160)) == 1 / 3   # 2 of 3 dark
+
+
 def test_resolve_sparse_path_modes():
     auto = E.EngineConfig(mode="sparse", sparse="auto", block_m=32,
                           block_n=32, block_k=32)
@@ -324,7 +349,10 @@ def test_resolve_sparse_path_modes():
     assert E.resolve_sparse_path(TILE32, ragged) == "tile"
     assert E.resolve_sparse_path(DEC32, coherent) == "decoded"
     assert E.resolve_sparse_path(auto, coherent) == "tile"
-    assert E.resolve_sparse_path(auto, ragged) == "decoded"
+    # no tile goes dark, but every live group costs a full-K dot per chunk
+    assert E.resolve_sparse_path(auto, ragged) == "tile"
+    assert E.resolve_sparse_path(auto, _scattered_dark_rows(96, 160)) == \
+        "decoded"
 
     seen = []
 
@@ -339,13 +367,14 @@ def test_resolve_sparse_path_modes():
 
 def test_sparse_auto_engine_end_to_end_bitwise():
     """auto dispatch through spike_linear is still bitwise vs dense on
-    both regimes (whichever datapath it picks)."""
+    every regime (whichever datapath it picks)."""
     auto = E.EngineConfig(mode="sparse", sparse="auto", block_m=32,
                           block_n=32, block_k=32)
     ks = jax.random.split(jax.random.PRNGKey(9), 3)
     w = _dyadic(ks[2], (160, 64))
     for s in (_ragged_spikes(ks[0], 96, 160, lo=0.0, hi=0.2),
-              jnp.zeros((96, 160)).at[:, :32].set(1.0)):
+              jnp.zeros((96, 160)).at[:, :32].set(1.0),
+              _scattered_dark_rows(96, 160)):
         dense = E.spike_linear({"w": w}, s, engine=E.DENSE)
         got = E.spike_linear({"w": w}, s, engine=auto)
         np.testing.assert_array_equal(np.asarray(dense), np.asarray(got))
