@@ -1,7 +1,6 @@
 """Benchmark harness — one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV per bench plus the full row dumps,
-and (when dry-run artifacts exist) the roofline table.
+Prints ``name,us_per_call,derived`` CSV per bench plus the full row dumps.
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--fast]
 """
@@ -21,13 +20,11 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="skip the model-training sparsity bench")
-    ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--smoke", action="store_true",
-                    help="CI mode: --fast + --skip-roofline")
+                    help="CI mode: same as --fast")
     args = ap.parse_args()
     if args.smoke:
         args.fast = True
-        args.skip_roofline = True
 
     import dual_engine_bench
     import paper_figures as pf
@@ -80,14 +77,6 @@ def main() -> None:
     for name, blob in all_rows.items():
         for row in blob["rows"]:
             print(json.dumps(row))
-
-    if not args.skip_roofline and os.path.isdir("artifacts/dryrun"):
-        print("\n== roofline (single-pod, per device) ==")
-        import roofline
-        rows = roofline.full_table()
-        with open("artifacts/roofline.json", "w") as f:
-            json.dump(rows, f, indent=1)
-        print(roofline.render(rows))
 
 
 if __name__ == "__main__":

@@ -73,6 +73,7 @@ from typing import Any, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.scopes import annotate, disable_annotations  # noqa: F401
 
 SPARSE_PATHS = ("tile", "decoded")
 OVERLAP_MODES = ("off", "fused", "pipeline")
@@ -219,30 +220,6 @@ def engine_scope(cfg) -> contextlib.AbstractContextManager:
     if engine is None:
         return contextlib.nullcontext()
     return use_engine(engine)
-
-
-def annotate(name: str) -> contextlib.AbstractContextManager:
-    """Profiler scope for an engine dispatch site (``jax.named_scope``):
-    every sparse-engine matmul, binary-engine attention, and fused
-    dual-engine step carries one, so the overlap is legible in a profile
-    dump (xprof / jax.profiler). Purely metadata — annotated and
-    unannotated traces are bitwise-identical (pinned by tests) — and
-    toggleable via :func:`disable_annotations` to prove exactly that.
-    """
-    if getattr(_state, "no_annotations", False):
-        return contextlib.nullcontext()
-    return jax.named_scope(name)
-
-
-@contextlib.contextmanager
-def disable_annotations():
-    """Run without profiler scopes (the bitwise smoke test's control arm)."""
-    prev = getattr(_state, "no_annotations", False)
-    _state.no_annotations = True
-    try:
-        yield
-    finally:
-        _state.no_annotations = prev
 
 
 def resolve_mode(engine: Optional[EngineConfig], m: int, k: int, n: int

@@ -27,6 +27,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.scopes import annotate
+
 
 @dataclasses.dataclass(frozen=True)
 class SpikingConfig:
@@ -112,8 +114,9 @@ def lif_scan(currents: jax.Array, cfg: SpikingConfig,
                         soft_reset=cfg.soft_reset, alpha=cfg.surrogate_alpha)
         return u, s
 
-    u0 = jnp.zeros_like(currents[0]) if v0 is None else v0
-    u_final, spikes = jax.lax.scan(step, u0, currents)
+    with annotate("lif.scan"):
+        u0 = jnp.zeros_like(currents[0]) if v0 is None else v0
+        u_final, spikes = jax.lax.scan(step, u0, currents)
     return spikes, u_final
 
 

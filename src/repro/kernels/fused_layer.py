@@ -77,6 +77,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.scopes import annotate
 from repro.core.spiking import SpikingConfig, lif_scan
 
 FAMILIES = ("bn", "rope")
@@ -608,10 +609,11 @@ def reference_layer(x: jax.Array, s: jax.Array, w3, wo, w1, w2,
         sc3, sco, sc1, sc2 = scales
 
     def lin(u, w, sc):
-        acc = jnp.dot(u, w, preferred_element_type=jnp.float32)
-        if sc is not None:
-            acc = acc * sc.astype(jnp.float32)
-        return acc.astype(u.dtype)
+        with annotate("sparse_engine.dense"):
+            acc = jnp.dot(u, w, preferred_element_type=jnp.float32)
+            if sc is not None:
+                acc = acc * sc.astype(jnp.float32)
+            return acc.astype(u.dtype)
 
     def bn(u, aux):
         u32 = u.astype(jnp.float32)

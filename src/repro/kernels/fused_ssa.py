@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.scopes import annotate
 from repro.core.spiking import SpikingConfig, binarize, lif_scan
 
 FAMILIES = ("bn", "rope")
@@ -272,10 +273,11 @@ def reference_bundle(x: jax.Array, w3: jax.Array,
     half = head_dim // 2
     projected = []
     for j in range(3):
-        acc = jnp.dot(x, w3[j], preferred_element_type=jnp.float32)
-        if scale3 is not None:
-            acc = acc * scale3[j].astype(jnp.float32)
-        y = acc.astype(x.dtype)
+        with annotate("sparse_engine.dense"):
+            acc = jnp.dot(x, w3[j], preferred_element_type=jnp.float32)
+            if scale3 is not None:
+                acc = acc * scale3[j].astype(jnp.float32)
+            y = acc.astype(x.dtype)
         if family == "bn":
             mean, var = aux[j, 0], aux[j, 1]
             y32 = y.astype(jnp.float32)
@@ -295,16 +297,17 @@ def reference_bundle(x: jax.Array, w3: jax.Array,
         projected.append(s_j)
     fold = lambda u: u.reshape(t * b, l, num_heads,
                                head_dim).transpose(0, 2, 1, 3)
-    q, k, v = (fold(u) for u in projected)
-    scores = jnp.einsum("...qd,...kd->...qk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if scfg.binarize_scores:
-        attn = binarize(scores, delta, scfg.surrogate_alpha)
-    else:
-        attn = scores
-    if causal:
-        mask = jnp.tril(jnp.ones((l, l), bool))
-        attn = jnp.where(mask, attn, 0.0)
-    ctx = jnp.einsum("...qk,...kd->...qd", attn, v,
-                     preferred_element_type=jnp.float32).astype(q.dtype)
-    return ctx.transpose(0, 2, 1, 3).reshape(t, b, l, q_dim)
+    with annotate("binary_engine.jnp"):
+        q, k, v = (fold(u) for u in projected)
+        scores = jnp.einsum("...qd,...kd->...qk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        if scfg.binarize_scores:
+            attn = binarize(scores, delta, scfg.surrogate_alpha)
+        else:
+            attn = scores
+        if causal:
+            mask = jnp.tril(jnp.ones((l, l), bool))
+            attn = jnp.where(mask, attn, 0.0)
+        ctx = jnp.einsum("...qk,...kd->...qd", attn, v,
+                         preferred_element_type=jnp.float32).astype(q.dtype)
+        return ctx.transpose(0, 2, 1, 3).reshape(t, b, l, q_dim)

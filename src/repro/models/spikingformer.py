@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.scopes import annotate
 from repro.core.spiking import SpikingConfig, binarize, lif_scan
 from repro.parallel.sharding import constrain
 from . import nn
@@ -182,8 +183,9 @@ def forward(params, cfg: ModelConfig, batch, *, train: bool = False,
     if cfg.family == "cifarnet":
         return _forward_cifarnet(params, cfg, batch, train=train, state=state)
     state = state if state is not None else init_state(cfg)
-    x, sps_state = _sps(params, state, cfg, batch["images"], train)
-    x = constrain(x, None, "batch", "seq", "embed")
+    with annotate("sps.stem"):
+        x, sps_state = _sps(params, state, cfg, batch["images"], train)
+        x = constrain(x, None, "batch", "seq", "embed")
 
     block_fn = _block
     if cfg.remat and train:
@@ -194,13 +196,17 @@ def forward(params, cfg: ModelConfig, batch, *, train: bool = False,
         bp, bst = inp
         x, new_bst = block_fn(bp, bst, cfg, x, train)
         return x, new_bst
-    x, blocks_state = jax.lax.scan(body, x,
-                                   (params["blocks"], state["blocks"]))
-    spikes = _lif(x, cfg)
-    rate = spikes.astype(jnp.float32).mean(axis=(0, 2))       # (B, D)
-    logits = nn.linear(params["head"], rate.astype(x.dtype)).astype(jnp.float32)
+    # the scan's own stacking and slicing ops fall under the scope too
+    with annotate("spikingformer.blocks"):
+        x, blocks_state = jax.lax.scan(body, x,
+                                       (params["blocks"], state["blocks"]))
+    with annotate("spikingformer.head"):
+        spikes = _lif(x, cfg)
+        rate = spikes.astype(jnp.float32).mean(axis=(0, 2))   # (B, D)
+        logits = nn.linear(params["head"],
+                           rate.astype(x.dtype)).astype(jnp.float32)
+        fire_rate = spikes.astype(jnp.float32).mean()
     new_state = {"sps": sps_state, "blocks": blocks_state}
-    fire_rate = spikes.astype(jnp.float32).mean()
     return logits, {"state": new_state, "fire_rate": fire_rate}
 
 

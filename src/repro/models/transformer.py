@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.bitpack import pack_bits, unpack_bits
+from repro.core.scopes import annotate
 from repro.core.spiking import binarize, lif_scan
 from repro.parallel.sharding import constrain
 from . import nn
@@ -198,13 +199,15 @@ def forward(params, cfg: ModelConfig, batch, *, train: bool = False,
             inputs_embeds: Optional[jax.Array] = None):
     """batch: {'tokens': (B, S)}; returns (logits (B, S, V), aux dict)."""
     tokens = batch["tokens"]
-    x = nn.embed(params["embed"], tokens) if inputs_embeds is None \
-        else inputs_embeds
-    x = constrain(x, "batch", "seq", "embed")
-    s = x.shape[-2]
-    positions = jnp.arange(s)
-    if cfg.spiking is not None:
-        x = jnp.broadcast_to(x[None], (cfg.spiking.time_steps,) + x.shape)
+    with annotate("transformer.embed"):
+        x = nn.embed(params["embed"], tokens) if inputs_embeds is None \
+            else inputs_embeds
+        x = constrain(x, "batch", "seq", "embed")
+        s = x.shape[-2]
+        positions = jnp.arange(s)
+        if cfg.spiking is not None:
+            x = jnp.broadcast_to(x[None],
+                                 (cfg.spiking.time_steps,) + x.shape)
 
     layer_fn = apply_layer
     if cfg.remat and train:
@@ -213,28 +216,31 @@ def forward(params, cfg: ModelConfig, batch, *, train: bool = False,
                                   policy=jax.checkpoint_policies.nothing_saveable)
 
     if cfg.attn_type == "local_global":
-        def group_body(x, gp):
+        def body(x, gp):
             for j in range(cfg.global_every):
                 sub = jax.tree_util.tree_map(lambda a: a[j], gp)
                 kind = "full" if j == cfg.global_every - 1 else "window"
                 x = layer_fn(sub, cfg, x, positions, kind, train)
             return x, None
-        x, _ = jax.lax.scan(group_body, x, params["groups"])
+        stacked = params["groups"]
     else:
         kind = "window" if cfg.attn_type == "swa" else "full"
 
         def body(x, lp):
             return layer_fn(lp, cfg, x, positions, kind, train), None
-        x, _ = jax.lax.scan(body, x, params["layers"])
+        stacked = params["layers"]
+    with annotate("transformer.layers"):
+        x, _ = jax.lax.scan(body, x, stacked)
 
-    if cfg.spiking is not None:
-        x = x.mean(axis=0)  # rate decoding over T_s
-    x = nn.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = nn.unembed(params["embed"], x)
-    else:
-        logits = nn.linear(params["lm_head"], x).astype(jnp.float32)
-    logits = constrain(logits, "batch", "seq", "vocab")
+    with annotate("transformer.head"):
+        if cfg.spiking is not None:
+            x = x.mean(axis=0)  # rate decoding over T_s
+        x = nn.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = nn.unembed(params["embed"], x)
+        else:
+            logits = nn.linear(params["lm_head"], x).astype(jnp.float32)
+        logits = constrain(logits, "batch", "seq", "vocab")
     return logits, {}
 
 

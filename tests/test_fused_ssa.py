@@ -263,9 +263,16 @@ def test_model_logits_bitwise_under_jit():
     np.testing.assert_array_equal(outs["off"], outs["fused"])
 
 
-@pytest.mark.parametrize("ov", ["off", "fused"])
-def test_annotations_are_bitwise_neutral(ov):
-    cfg, params, batch = _model_setup("spikingformer-4-256")
+@pytest.mark.parametrize("arch,ov", [
+    pytest.param("spikingformer-4-256", "off", id="off"),
+    pytest.param("spikingformer-4-256", "fused", id="fused"),
+    pytest.param("spikingformer-8-512", "off", id="8-512-off"),
+    pytest.param("spikingformer-8-512", "fused", id="8-512-fused"),
+])
+def test_annotations_are_bitwise_neutral(arch, ov):
+    """Every program scope (stem, blocks, head, LIF, both engines, the
+    layer program) is metadata: logits match with scopes disabled."""
+    cfg, params, batch = _model_setup(arch)
     eng = cfg.engine.replace(overlap=ov)
     with E.use_engine(eng):
         annotated, _ = registry.forward(params, cfg, batch)

@@ -7,6 +7,10 @@ for a described (not attached) v5e chip, with ``interpret=False``, and
 assert the compiled program holds the kernel (``tpu_custom_call``).
 Nothing runs, so they say nothing about results or times.
 
+One more compile holds the eval step's profiler scopes to the chip's
+fusions: a device trace names a fusion by its own ``op_name``, so every
+fusion that runs a layer-program matmul must carry an engine scope.
+
 Widths: spikingformer-8-512 (T=4, B=8, L=196 at 224x224, D=512, d_ff=2048,
 8 heads x 64) and spikingformer-lm (T=4, D=256, d_ff=1024, 8 causal heads
 x 32, a 128-token prompt, 4 slots).
@@ -16,18 +20,22 @@ one process may load the TPU library, and the suite's workers all import
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.fused_layer import fused_layer
 from repro.kernels.fused_ssa import fused_ssa
 from repro.kernels.spike_attention import spike_attention
 from repro.kernels.spike_decode import (gather_spike_matmul,
                                         quant_gather_spike_matmul)
 from repro.kernels.spike_matmul import quant_spike_matmul, spike_matmul
+from repro.launch import steps
+from repro.models import registry
 
 T, B, L, D, H, HD, FF = 4, 8, 196, 512, 8, 64, 2048      # 8-512
 M = T * B * L
@@ -153,3 +161,59 @@ def test_fused_layer(one_chip, family, overlap, sparse):
             pipeline=overlap == "pipeline", interpret=False)[0]
 
     _compile(step, one_chip, *shapes)
+
+
+_MATMUL = re.compile(r"\s(?:dot|convolution)\(")
+
+
+def _matmul_sites(text):
+    """op_names of the instructions that run a matmul or convolution on
+    the device: each fusion whose computation holds one, and each such op
+    outside a fusion."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line[:1] not in ("", " ") and line.rstrip().endswith("{"):
+            head = line.split()
+            cur = comps.setdefault(head[head[0] == "ENTRY"].lstrip("%"), [])
+        elif cur is not None:
+            cur.append(line)
+    fused = {m.group(1) for lines in comps.values() for line in lines
+             for m in [re.search(r" fusion\(.*calls=%?([\w.\-]+)", line)]
+             if m}
+    heavy = {n for n in fused if any(_MATMUL.search(x) for x in comps[n])}
+    sites = []
+    for name, lines in comps.items():
+        for line in lines:
+            op = re.search(r'op_name="([^"]*)"', line)
+            call = re.search(r" fusion\(.*calls=%?([\w.\-]+)", line)
+            if op and ((call and call.group(1) in heavy)
+                       or (name not in fused and _MATMUL.search(line))):
+                sites.append(op.group(1))
+    return sites
+
+
+def test_eval_step_matmul_fusions_carry_engine_scopes(one_chip):
+    """spikingformer-8-512's eval step (bf16, batch 8 at 224x224,
+    ``overlap='auto'``, as the benchmark runs it): inside the layer
+    program, every fusion holding a matmul sits in the sparse or binary
+    engine's scope; outside it, in the stem's or the head's."""
+    cfg = get_config("spikingformer-8-512")
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: registry.init(cfg, jax.random.PRNGKey(0))))
+    images = jax.ShapeDtypeStruct((B, 224, 224, 3), jnp.bfloat16,
+                                  sharding=one_chip)
+    text = jax.jit(steps.build_prefill_step(cfg)).lower(
+        params, {"images": images}).compile().as_text()
+    sites = [s.split("/") for s in _matmul_sites(text)]
+    layer = [s for s in sites if "dual_engine.fused_layer" in s]
+    engines = [[p for p in s if p.startswith(("sparse_engine.",
+                                              "binary_engine."))]
+               for s in layer]
+    assert all(engines), [s for s, e in zip(layer, engines) if not e]
+    # Q, K, V, wo, w1, w2 and the two attention matmuls
+    assert sum(e[0].startswith("sparse_engine.") for e in engines) >= 6
+    assert sum(e[0].startswith("binary_engine.") for e in engines) >= 2
+    rest = [s for s in sites if "dual_engine.fused_layer" not in s]
+    assert rest and all("sps.stem" in s or "spikingformer.head" in s
+                        for s in rest)
