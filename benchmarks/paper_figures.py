@@ -157,6 +157,7 @@ def kernels_bench():
     popcount port — the DESIGN.md §3 adaptation argument)."""
     import jax
     import jax.numpy as jnp
+    from repro.core.spiking import SpikingConfig
     from repro.kernels import ops
 
     def timeit(fn, *args, n=3):
@@ -192,7 +193,7 @@ def kernels_bench():
                                                block_n=128, block_k=128))
     t_mm = timeit(mm, s, w)
     lif_in = jax.random.normal(ks[2], (4, 256, 512))
-    lf = jax.jit(lambda x: ops.lif(x, decay=0.5))
+    lf = jax.jit(lambda x: ops.lif_one_pass(x, SpikingConfig())[0])
     t_lif = timeit(lf, lif_in)
     rows = [
         {"bench": "kernels", "kernel": "spike_attention(interp)",

@@ -7,7 +7,8 @@ dynamics substrate:
 * ``spike``            — Heaviside with a sigmoid surrogate gradient
                          (``custom_jvp`` so both fwd- and rev-mode work).
 * ``lif_scan``         — multi-step Leaky Integrate-and-Fire over the time
-                         axis (``lax.scan``), soft or hard reset.
+                         axis, soft or hard reset: one pass over T on the
+                         chip, ``lax.scan`` otherwise.
 * ``binarize``         — learnable-threshold binarization used by binary
                          attention (Shen et al. [17] / BESTformer [18]).
 * ``SpikingConfig``    — the knob models use to switch spiking mode on.
@@ -97,9 +98,28 @@ def lif_step(u: jax.Array, x: jax.Array, *, decay: float, v_th: float,
     return u, s
 
 
+def lif_lax_scan(currents: jax.Array, cfg: SpikingConfig,
+                 v0: Optional[jax.Array] = None):
+    """``lif_scan`` as a ``lax.scan`` over T: the form off TPU, and the
+    one whose surrogate gradient every form uses."""
+    def step(u, x):
+        u, s = lif_step(u, x, decay=cfg.decay, v_th=cfg.v_threshold,
+                        soft_reset=cfg.soft_reset, alpha=cfg.surrogate_alpha)
+        return u, s
+
+    u0 = jnp.zeros_like(currents[0]) if v0 is None else v0
+    u_final, spikes = jax.lax.scan(step, u0, currents)
+    return spikes, u_final
+
+
 def lif_scan(currents: jax.Array, cfg: SpikingConfig,
              v0: Optional[jax.Array] = None):
     """Run LIF dynamics over the leading time axis.
+
+    ``kernels.ops.lif`` chooses the form: on the chip, from a zero
+    membrane and outside a mesh, the spikes come from one pass over T
+    (scope ``lif.kernel``); elsewhere, and under differentiation, from
+    ``lif_lax_scan``. Both compute ``lif_step`` op for op.
 
     Args:
       currents: ``(T, ...)`` input currents.
@@ -109,15 +129,9 @@ def lif_scan(currents: jax.Array, cfg: SpikingConfig,
     Returns:
       (spikes ``(T, ...)``, final membrane ``(...)``).
     """
-    def step(u, x):
-        u, s = lif_step(u, x, decay=cfg.decay, v_th=cfg.v_threshold,
-                        soft_reset=cfg.soft_reset, alpha=cfg.surrogate_alpha)
-        return u, s
-
+    from repro.kernels import ops  # lazy: no cycle
     with annotate("lif.scan"):
-        u0 = jnp.zeros_like(currents[0]) if v0 is None else v0
-        u_final, spikes = jax.lax.scan(step, u0, currents)
-    return spikes, u_final
+        return ops.lif(currents, cfg, v0)
 
 
 def lif_loop_reference(currents, cfg: SpikingConfig, v0=None):

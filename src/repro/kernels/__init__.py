@@ -6,7 +6,8 @@
   spike_decode       — gather-compacted spike matmul (sparse engine,
                        decoded datapath: cumsum prefix-compaction +
                        pow2 occupancy-bucket load balancing)
-  lif                — fused LIF membrane scan (neuronal dynamics module)
+  lif                — LIF neurons in one pass over T_s (neuronal dynamics
+                       module)
   popcount_attention — bit-packed AND-PopCount scores (faithful FPGA port,
                        kept for comparison; the MXU form wins on TPU)
 

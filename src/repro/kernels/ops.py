@@ -5,6 +5,8 @@ runs a Pallas kernel (the fused MXU pass, or the bit-packed AND-PopCount
 score stage); the backward recomputes through the pure-jnp oracle with
 surrogate gradients (standard recompute-in-bwd pattern — the L x L
 attention matrix still never persists between fwd and bwd).
+``lif_one_pass`` runs its kernel only where nothing differentiates it:
+under differentiation it is the scan, with the scan's own VJP.
 
 On non-TPU backends kernels run in ``interpret=True`` mode (bit-exact
 Python execution of the kernel body) — that is how this CPU container
@@ -19,7 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bitpack import pack_bits
-from repro.core.spiking import binarize
+from repro.core.scopes import annotate
+from repro.core.spiking import SpikingConfig, binarize, lif_lax_scan
+from repro.parallel.sharding import get_rules
 from . import ref
 from .lif import lif_forward as _lif_pallas
 from .popcount_attention import popcount_scores as _popcount_pallas
@@ -156,16 +160,52 @@ def spike_matmul_batched(s, w, *, bias=None, block_m: int = 128,
 # LIF
 # ---------------------------------------------------------------------------
 
-def lif(currents, *, decay: float, v_th: float = 1.0,
-        soft_reset: bool = False):
-    """Fused LIF over (T, ..., D): folds middle dims into M."""
-    t = currents.shape[0]
-    d = currents.shape[-1]
-    flat = currents.reshape(t, -1, d)
-    out = _lif_pallas(flat, decay=decay, v_th=v_th, soft_reset=soft_reset,
-                      block_m=min(256, flat.shape[1]),
-                      block_d=min(512, d))
-    return out.reshape(currents.shape)
+def lif(currents, cfg: SpikingConfig, v0=None):
+    """LIF over (T, ...) as ``core.spiking.lif_scan`` runs it: (spikes,
+    final membrane). Where ``_one_pass`` holds, ``lif_one_pass``; else
+    ``lif_lax_scan``."""
+    if _one_pass(v0):
+        return lif_one_pass(currents, cfg)
+    return lif_lax_scan(currents, cfg, v0)
+
+
+def _one_pass(v0) -> bool:
+    """Whether the LIF takes the one-pass kernel: on the chip, from a zero
+    membrane, and not while a mesh partitions the program (sharding rules
+    installed): XLA cannot partition a Mosaic kernel.
+
+    No size bound: one bf16 call at T 4 on a v5e took 0.42 us one pass
+    against the scan's 1.68 at (4, 1, 256), 0.66 / 3.83 at (4, 4, 256),
+    2.91 / 7.31 at (4, 256, 256) and 11.2 / 17.2 at (4, 1024, 256); the
+    kernel won at every size measured, and 256 neurons a step (a
+    one-slot decode of spikingformer-lm) is the smallest LIF in the repo.
+    Those times predate the kernel's cut of R and D (see PERF.md)."""
+    return (jax.default_backend() == "tpu" and get_rules() is None
+            and v0 is None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def lif_one_pass(currents, cfg: SpikingConfig):
+    """LIF over (T, ...) from a zero membrane: the spikes from the one-pass
+    kernel (scope ``lif.kernel``), the final membrane from the scan, which
+    XLA removes where the caller drops it."""
+    with annotate("lif.kernel"):
+        spikes = _lif_pallas(currents, decay=cfg.decay, v_th=cfg.v_threshold,
+                             soft_reset=cfg.soft_reset)
+    return spikes, lif_lax_scan(currents, cfg)[1]
+
+
+def _lif_one_pass_fwd(currents, cfg):
+    """Under differentiation the scan runs, and its pullback is the
+    residual: training computes what it did before the kernel."""
+    return jax.vjp(lambda c: lif_lax_scan(c, cfg), currents)
+
+
+def _lif_one_pass_bwd(cfg, pullback, cts):
+    return pullback(cts)
+
+
+lif_one_pass.defvjp(_lif_one_pass_fwd, _lif_one_pass_bwd)
 
 
 # ---------------------------------------------------------------------------

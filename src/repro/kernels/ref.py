@@ -1,7 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the "ref.py" contract).
 
 These define bit-exact semantics the kernels must match (tests sweep shapes
-and dtypes against them with assert_allclose).
+and dtypes against them with assert_allclose). The LIF kernel's oracle is
+``core.spiking.lif_loop_reference``.
 """
 from __future__ import annotations
 
@@ -42,18 +43,6 @@ def spike_matmul_ref(s, w):
     """
     return jnp.dot(s.astype(jnp.float32),
                    w.astype(jnp.float32)).astype(w.dtype)
-
-
-def lif_ref(currents, *, decay: float, v_th: float, soft_reset: bool):
-    """LIF oracle over leading time axis. currents: (T, ...) -> spikes."""
-    def step(u, x):
-        u = decay * u + x.astype(jnp.float32)
-        s = (u >= v_th).astype(jnp.float32)
-        u = u - s * v_th if soft_reset else u * (1.0 - s)
-        return u, s
-    u0 = jnp.zeros(currents.shape[1:], jnp.float32)
-    _, spikes = jax.lax.scan(step, u0, currents)
-    return spikes.astype(currents.dtype)
 
 
 def popcount_scores_ref(q_packed, k_packed):

@@ -9,7 +9,8 @@ from repro.core.bitpack import pack_bits
 from repro.kernels import ops, ref
 from repro.kernels.spike_attention import spike_attention as attn_raw
 from repro.kernels.spike_matmul import spike_matmul as matmul_raw
-from repro.kernels.lif import lif_forward
+from repro.core.spiking import (SpikingConfig, lif_lax_scan, lif_loop_reference,
+                                lif_scan)
 
 
 def _spikes(key, shape, p=0.25, dtype=jnp.float32):
@@ -94,22 +95,112 @@ def test_spike_matmul_skips_zero_blocks_correctly():
     assert not occ[:, 1].any() and not occ[:, 2].any()
 
 
-@pytest.mark.parametrize("t,m,d", [(4, 64, 128), (2, 256, 512), (8, 32, 64)])
+def _bits(a):
+    return np.asarray(jnp.asarray(a, jnp.float32)).view(np.int32)
+
+
+# (T, ..., R, D), with the block budget a case forces (None: the kernel's)
+# and the (P, R, D) view that budget cuts raggedly: a conv output with a
+# minor dim of 64 (T-major view) and one of 128 (the (H*W, T, B, C)
+# view); the cell's block layout; R off the (8, 128) tile; a 2-D input;
+# a size-1 dim (a decode step's); a ragged last block along D, P (in
+# either view) and R
+LIF_CASES = [((4, 2, 6, 24, 64), None, None),
+             ((4, 2, 14, 14, 128), None, None),
+             ((4, 2, 196, 512), None, None), ((2, 5, 10, 100), None, None),
+             ((8, 300), None, None), ((4, 6, 1, 256), None, None),
+             ((2, 3, 8, 700), None, (3, 8, 700)),
+             ((4, 5, 16, 64), 64 << 10, (5, 16, 64)),
+             ((4, 2, 14, 14, 128), 48 << 10, (196, 2, 128)),
+             ((4, 2, 100, 128), 16 << 10, (2, 100, 128))]
+
+
+@pytest.mark.parametrize("shape,block_bytes,view", LIF_CASES)
 @pytest.mark.parametrize("soft", [False, True])
-def test_lif_kernel_sweep(t, m, d, soft):
-    x = jax.random.normal(jax.random.PRNGKey(t * d), (t, m, d)) * 2
-    got = lif_forward(x, decay=0.5, v_th=1.0, soft_reset=soft,
-                      block_m=min(64, m), block_d=min(128, d))
-    want = ref.lif_ref(x, decay=0.5, v_th=1.0, soft_reset=soft)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_lif_kernel_sweep(shape, block_bytes, view, soft, dtype,
+                          monkeypatch):
+    """One pass over T: spikes (the kernel's) and final membrane bitwise
+    those of the op-by-op loop, in the precision the scan keeps."""
+    from repro.kernels import lif as K
+    if block_bytes:
+        monkeypatch.setattr(K, "BLOCK_BYTES", block_bytes)
+    if view:
+        blocks = K._blocks(shape[0], *view, jnp.dtype(dtype).itemsize)
+        assert any(n % b for n, b in zip(view, blocks)), blocks
+    cfg = SpikingConfig(time_steps=shape[0], soft_reset=soft)
+    x = (jax.random.normal(jax.random.PRNGKey(sum(shape)), shape) * 1.5
+         ).astype(dtype)
+    s, u = ops.lif_one_pass(x, cfg)
+    s_ref, u_ref = lif_loop_reference(x, cfg)
+    assert s.dtype == s_ref.dtype == dtype and u.dtype == dtype
+    assert s.shape == x.shape and u.shape == x.shape[1:]
+    np.testing.assert_array_equal(_bits(s), _bits(s_ref))
+    np.testing.assert_array_equal(_bits(u), _bits(u_ref))
+    assert 0.05 < float(s.astype(jnp.float32).mean()) < 0.5
+
+
+@pytest.mark.parametrize("soft", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_lif_one_pass_gradient_is_scan_surrogate(soft, dtype):
+    """Under differentiation ``ops.lif_one_pass`` is the scan: its
+    gradient is the scan's surrogate gradient bit for bit, whatever the
+    cotangents, and the differentiated program holds no kernel."""
+    cfg = SpikingConfig(soft_reset=soft)
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    x = (jax.random.normal(ks[0], (4, 2, 8, 128)) * 1.5).astype(dtype)
+    ws = jax.random.normal(ks[1], x.shape)
+    wu = jax.random.normal(ks[2], x.shape[1:])
+
+    def grad(f):
+        def g(x):
+            s, u = f(x, cfg)
+            return (s * ws).sum() + (u * wu).sum()
+        return jax.grad(g)
+
+    got, want = grad(ops.lif_one_pass)(x), grad(lif_lax_scan)(x)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert float(jnp.abs(got.astype(jnp.float32)).sum()) > 0
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        grad(ops.lif_one_pass))(x))
 
 
 def test_lif_ops_wrapper_arbitrary_dims():
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 2, 8, 64))
-    got = ops.lif(x, decay=0.5)
-    want = ref.lif_ref(x.reshape(4, -1, 64), decay=0.5, v_th=1.0,
-                       soft_reset=False).reshape(x.shape)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    cfg = SpikingConfig()
+    got = ops.lif_one_pass(x, cfg)
+    want = lif_loop_reference(x, cfg)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("shape,backend,v0,one_pass", [
+    ((4, 64, 224, 224, 64), "tpu", False, True),    # the cell's stem
+    ((4, 64, 196, 512), "tpu", False, True),        # the cell's blocks
+    ((4, 1, 1, 256), "tpu", False, True),           # decode, one slot
+    ((4, 64, 196, 512), "tpu", True, False),        # a given membrane
+    ((4, 64, 196, 512), "cpu", False, False),       # off TPU: the scan
+    ((4, 4, 128, 256), "mesh", False, False),       # a mesh partitions it
+])
+def test_lif_one_pass_rule(shape, backend, v0, one_pass, monkeypatch):
+    """On the chip every LIF from a zero membrane takes one pass, whatever
+    its size; a given membrane, every input off TPU, and programs a mesh
+    partitions (which cannot hold a Mosaic kernel), the scan."""
+    from repro.parallel.sharding import use_rules
+    rules = {"batch": "data"} if backend == "mesh" else None
+    monkeypatch.setattr(jax, "default_backend",
+                        lambda: "tpu" if backend == "mesh" else backend)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    u = jax.ShapeDtypeStruct(shape[1:], jnp.bfloat16) if v0 else None
+    with use_rules(rules):
+        assert ops._one_pass(u) is one_pass
+        jaxpr = jax.make_jaxpr(
+            lambda c, u: lif_scan(c, SpikingConfig(), u))(x, u)
+    prims = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+    assert ("custom_vjp_call" in prims) is one_pass
+    assert ("scan" in prims) is not one_pass
+    assert ("pallas_call" in str(jaxpr)) is one_pass
 
 
 @pytest.mark.parametrize("l,d", [(64, 64), (128, 128), (64, 256)])

@@ -17,6 +17,9 @@ They compile the jitted eval step at SMOKE size on the CPU and read its
 * with ``overlap='fused'`` the layer kernel sits inside
   ``dual_engine.fused_layer``, and the stem, the neurons and the head
   keep their own scopes;
+* with the LIFs on the one-pass kernel (the chip's branch of
+  ``lif_scan``, run here in interpret mode) every op stays scoped, and
+  all LIF work sits in ``lif.kernel`` inside ``lif.scan``;
 * ``disable_annotations`` compiles the same step with none of them;
 * a program loaded from the persistent compilation cache carries its own
   scopes, not those of a program that differs from it only in scopes.
@@ -110,6 +113,18 @@ def test_fused_layer_kernel_and_vision_phases():
                for n in names)
     assert any(_has(n, "spikingformer.blocks") and _has(n, "lif.")
                and not _has(n, "dual_engine.") for n in names)
+
+
+def test_one_pass_lifs_keep_scopes(monkeypatch):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_one_pass", lambda v0: True)
+    names = _op_names("spikingformer-8-512", "off")
+    unscoped = sorted({n for n in names if not _has(n, *PROGRAM_SCOPES)})
+    assert not unscoped, unscoped
+    lif = [_parts(n) for n in names if _has(n, "lif.")]
+    assert lif and all("lif.kernel" in p and "lif.scan" in p
+                       and p.index("lif.scan") < p.index("lif.kernel")
+                       for p in lif), sorted({"/".join(p) for p in lif})
 
 
 def test_disable_annotations_drops_every_scope():

@@ -11,6 +11,12 @@ One more compile holds the eval step's profiler scopes to the chip's
 fusions: a device trace names a fusion by its own ``op_name``, so every
 fusion that runs a layer-program matmul must carry an engine scope.
 
+The one-pass LIF compiles at the benchmark cell's five LIF shapes (batch
+64), and the compiled batch-64 eval step, steered onto the chip's branch,
+runs every LIF as that kernel: no ``while`` under ``lif.``, and no more
+relayouts (``copy``/``transpose``) next to its LIF custom calls than next
+to the scans of the same step compiled the scan's way.
+
 Widths: spikingformer-8-512 (T=4, B=8, L=196 at 224x224, D=512, d_ff=2048,
 8 heads x 64) and spikingformer-lm (T=4, D=256, d_ff=1024, 8 causal heads
 x 32, a 128-token prompt, 4 slots).
@@ -19,6 +25,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the suite's workers all import
 this file.
 """
+import collections
 import os
 import re
 
@@ -30,6 +37,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.fused_layer import fused_layer
 from repro.kernels.fused_ssa import fused_ssa
+from repro.kernels.lif import lif_forward
 from repro.kernels.spike_attention import spike_attention
 from repro.kernels.spike_decode import (gather_spike_matmul,
                                         quant_gather_spike_matmul)
@@ -217,3 +225,125 @@ def test_eval_step_matmul_fusions_carry_engine_scopes(one_chip):
     rest = [s for s in sites if "dual_engine.fused_layer" not in s]
     assert rest and all("sps.stem" in s or "spikingformer.head" in s
                         for s in rest)
+
+
+LIF_SHAPES = [(4, 64, 224, 224, 64), (4, 64, 112, 112, 128),   # SPS stem
+              (4, 64, 56, 56, 256), (4, 64, 196, 512),         # and blocks
+              (4, 64, 196, 2048)]
+# spikingformer-lm: a decode step of 4 slots, and a 2048-token prefill,
+# whose rows R must be split to fit VMEM; a D cut raggedly
+LIF_OTHER = [(4, 4, 1, 256), (4, 4, 2048, 256), (4, 8, 128, 700)]
+
+
+@pytest.mark.parametrize("shape", LIF_SHAPES + LIF_OTHER)
+def test_lif_one_pass(one_chip, shape):
+    _compile(lambda x: lif_forward(x, decay=0.5, interpret=False),
+             one_chip, (shape, jnp.bfloat16))
+
+
+def _instructions(text):
+    """name -> (opcode, result type, operand names, op_name) of every
+    instruction of a compiled program's text."""
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*", line)
+        if not m:
+            continue
+        rest, depth = line[m.end():], 0
+        if rest.startswith("("):                 # a tuple's type
+            for i, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if not depth:
+                    break
+            typ, rest = rest[:i + 1], rest[i + 1:].lstrip()
+        else:
+            typ, _, rest = rest.partition(" ")
+        op = re.match(r"([\w\-]+)\(", rest)
+        if not op:
+            continue
+        for i, ch in enumerate(rest[op.end() - 1:]):
+            depth += (ch == "(") - (ch == ")")
+            if not depth:
+                break
+        args = re.findall(r"%([\w.\-]+)", rest[op.end():op.end() + i])
+        name = re.search(r'op_name="([^"]*)"', line)
+        out[m.group(1)] = (op.group(1), typ, args,
+                           name.group(1) if name else "")
+    return out
+
+
+def _relayouts_next_to(ins, sites):
+    """Array copies and transposes that feed a site or read its result,
+    through bitcasts and tuples."""
+    users = collections.defaultdict(list)
+    for n, (_, _, args, _) in ins.items():
+        for a in args:
+            users[a].append(n)
+    found = set()
+
+    def walk(n, step, seen):
+        for m in step(n):
+            if m in seen or m not in ins:
+                continue
+            seen.add(m)
+            op, typ = ins[m][:2]
+            if op in ("copy", "transpose") and "[]" not in typ:
+                found.add(m)
+            elif op in ("bitcast", "get-tuple-element", "tuple"):
+                walk(m, step, seen)
+    for site in sites:
+        walk(site, lambda n: ins[n][2], set())
+        walk(site, lambda n: users[n], set())
+    return found
+
+
+@pytest.fixture(scope="module")
+def eval_steps(one_chip):
+    """spikingformer-8-512's eval step at the cell's batch of 64, compiled
+    with the LIFs one pass (the chip's branch of ``lif_scan``) and with
+    the scan: {form: instructions}."""
+    cfg = get_config("spikingformer-8-512")
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: registry.init(cfg, jax.random.PRNGKey(0))))
+    images = jax.ShapeDtypeStruct((64, 224, 224, 3), jnp.bfloat16,
+                                  sharding=one_chip)
+    out = {}
+    for form, backend in (("one_pass", "tpu"), ("scan", None)):
+        with pytest.MonkeyPatch.context() as mp:
+            if backend:
+                # what the chip runs: the code asks the backend it traces on
+                mp.setattr(jax, "default_backend", lambda: backend)
+            text = jax.jit(steps.build_prefill_step(cfg)).lower(
+                params, {"images": images}).compile().as_text()
+        out[form] = _instructions(text)
+    return out
+
+
+def _has_scope(op_name, prefix):
+    return any(p.startswith(prefix) for p in op_name.split("/"))
+
+
+def test_eval_step_lifs_run_one_pass(eval_steps):
+    ins = eval_steps["one_pass"]
+    loops = [n for n, (op, _, _, name) in ins.items()
+             if op == "while" and _has_scope(name, "lif.")]
+    assert not loops, loops
+    calls = [n for n, (op, _, _, name) in ins.items()
+             if op == "custom-call" and _has_scope(name, "lif.kernel")]
+    # three in the stem, six in the block program, the head's
+    assert len(calls) == 10, calls
+    scans = [n for n, (op, _, _, name) in eval_steps["scan"].items()
+             if op == "while" and _has_scope(name, "lif.scan")]
+    assert len(scans) == 10, scans
+
+
+def test_eval_step_lif_kernels_add_no_relayout(eval_steps):
+    def relayouts(form, op):
+        ins = eval_steps[form]
+        sites = [n for n, (o, _, _, name) in ins.items()
+                 if o == op and _has_scope(name, "lif.")]
+        return sorted(_relayouts_next_to(ins, sites))
+    one_pass = relayouts("one_pass", "custom-call")
+    scan = relayouts("scan", "while")
+    assert len(one_pass) <= len(scan), (one_pass, scan)
