@@ -15,6 +15,7 @@ import importlib.util
 import json
 import math
 import pathlib
+import statistics
 import sys
 import time
 from typing import Dict, List, Optional
@@ -131,6 +132,7 @@ def measure(runner, seconds: float, trace: bool, compiles: CompileCounter):
     capture = counts0 = traced = None
     trace_from = max(0.0, seconds - TRACE_SECONDS)
     compiles.armed = True
+    ends = []
     with contextlib.ExitStack() as tracing:
         t0 = time.perf_counter()
         end = t0 + seconds
@@ -146,15 +148,32 @@ def measure(runner, seconds: float, trace: bool, compiles: CompileCounter):
                 counts0 = dict(runner.counters())
             with jax.profiler.TraceAnnotation("bench.step"):
                 runner.step()
-            if time.perf_counter() >= end:
+            ends.append(time.perf_counter())
+            if ends[-1] >= end:
                 break
         runner.drain()
         t1 = time.perf_counter()
     compiles.armed = False
+    log(step_times(t0, ends))
     if capture is not None:
         traced = {k: v - counts0.get(k, 0)
                   for k, v in runner.counters().items()}
     return t0, t1, traced, capture
+
+
+def step_times(t0: float, ends: List[float], n: int = 5) -> str:
+    """One line on how the window's time fell to its steps: their count,
+    median and quartiles, and the ``n`` longest with when each ended, in
+    seconds into the window. A run that lost time to one pause shows it
+    as one long step; a chip or host that ran slower throughout shows it
+    as a higher median."""
+    d = [b - a for a, b in zip([t0] + ends, ends)]
+    q1, med, q3 = statistics.quantiles(d, n=4) if len(d) > 1 else d * 3
+    worst = sorted(range(len(d)), key=lambda i: -d[i])[:n]
+    return (f"steps {len(d)}: median {1e3 * med:.3f} ms, quartiles "
+            f"{1e3 * q1:.3f}-{1e3 * q3:.3f} ms; longest " + ", ".join(
+                f"{1e3 * d[i]:.3f} ms at {ends[i] - t0:.3f} s"
+                for i in worst))
 
 
 class Reading:

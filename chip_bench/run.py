@@ -95,6 +95,9 @@ def run_cell(cell, devices, seconds: float, trace: bool) -> dict:
     else:
         e2e = runner.end_to_end(t0, t1)
         e2e["setup_s"] = setup_s
+        # undeclared ones too, such as a tail too unsteady to gate on
+        harness.log("end to end: " + ", ".join(
+            f"{k} {v!r}" for k, v in e2e.items()))
         for m in cell.end_to_end:
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
     for line in runner.notes():

@@ -2,12 +2,15 @@
 
 Traffic parameters: ``batch`` images per step, ``batches`` distinct
 batches staged on the device before the window and cycled through it,
-``check`` images compared with the reference after it. Two steps are in
-flight at most: the host dispatches one while the device runs the other,
-so the device is never starved and the rate counts finished work only.
+and ``check`` images compared with the reference after it. At most
+``IN_FLIGHT`` steps are dispatched and not yet finished: the host runs
+that many steps ahead of the device, so a pause of the host shorter than
+``IN_FLIGHT - 1`` steps leaves the device busy. The rate counts finished
+work only.
 """
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -18,6 +21,8 @@ from chip_bench import weights
 from chip_bench.reference import spikingformer as ref
 from chip_bench.runners import common
 
+IN_FLIGHT = 32
+
 
 class Runner:
     def __init__(self, cell, devices):
@@ -26,7 +31,7 @@ class Runner:
         self.batch = self.mix["batch"]
         self.images_done = 0
         self.steps_done = 0
-        self.pending = None
+        self.pending = collections.deque()
         self.k = 0
 
     # -- set-up -------------------------------------------------------------
@@ -50,15 +55,18 @@ class Runner:
         out = self.fwd(self.params, {"images": self.inputs[k]})
         self.outputs[k] = out
         self.k = (k + 1) % len(self.inputs)
-        self.drain()
-        self.pending = out
+        self.pending.append(out)
+        while len(self.pending) >= IN_FLIGHT:
+            self._finish()
+
+    def _finish(self):
+        self.pending.popleft().block_until_ready()
+        self.images_done += self.batch
+        self.steps_done += 1
 
     def drain(self):
-        if self.pending is not None:
-            self.pending.block_until_ready()
-            self.pending = None
-            self.images_done += self.batch
-            self.steps_done += 1
+        while self.pending:
+            self._finish()
 
     def counters(self):
         return {"images": self.images_done, "steps": self.steps_done}

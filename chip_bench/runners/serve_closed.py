@@ -189,7 +189,21 @@ class Runner:
         return out
 
     def scopes(self):
-        return {}
+        """The serve step's scopes, joined from the compiled program of each
+        warmed width. The widths' programs share one module name, so an
+        instruction whose op_name differs between them is left out."""
+        from chip_bench.trace import hlo_scopes
+        s, slots = self.server, self.mix["slots"]
+        pos = jnp.zeros(slots, jnp.int32)
+        merged, clash = {}, set()
+        for w in self.widths:
+            text = s._step.lower(s.params, s.cache,
+                                 jnp.zeros((slots, w), jnp.int32), pos,
+                                 pos).compile().as_text()
+            for key, name in hlo_scopes(text).items():
+                if merged.setdefault(key, name) != name:
+                    clash.add(key)
+        return {k: v for k, v in merged.items() if k not in clash}
 
     # -- check --------------------------------------------------------------
 

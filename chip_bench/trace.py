@@ -197,20 +197,24 @@ class Trace:
     def top_ops(self, n: int = 10) -> List[List]:
         """The ``n`` op labels with the most device self time (seconds):
         an op that holds others (a ``while`` around a scanned layer) counts
-        only the time in which none of them runs."""
+        only the time in which none of them runs. An op with no scope is
+        labelled by its program (``jit_argmax/select_reduce_fusion``)."""
         acc: Dict[str, int] = {}
         stack: List[List] = []            # [end, label, self ns]
 
         def close(entry):
             acc[entry[1]] = acc.get(entry[1], 0) + entry[2]
-        for name, _, s, d, scope in sorted(self.ops, key=lambda o: (o[2],
-                                                                     -o[3])):
+        for name, mod, s, d, scope in sorted(self.ops,
+                                             key=lambda o: (o[2], -o[3])):
             s0, e0 = max(s, self.lo), min(s + d, self.hi)
             while stack and stack[-1][0] <= s0:
                 close(stack.pop())
             if stack:
                 stack[-1][2] -= max(0, min(e0, stack[-1][0]) - s0)
-            stack.append([e0, op_label(name, scope), e0 - s0])
+            label = op_label(name, scope)
+            if not scope and mod:
+                label = f"{mod}/{label}"
+            stack.append([e0, label, e0 - s0])
         for entry in stack:
             close(entry)
         top = sorted(acc.items(), key=lambda kv: -kv[1])[:n]

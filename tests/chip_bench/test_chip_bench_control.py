@@ -14,7 +14,7 @@ from chip_bench_smoke import SmokeCell
 
 CELL = "sflm.batch"
 TRAFFIC = {"runner": "serve_closed", "clients": 2, "slots": 2,
-           "max_len": 1024, "chunk": 0, "requests": 8, "lead_in_s": 0,
+           "max_len": 1024, "chunk": 4, "requests": 8, "lead_in_s": 0,
            "prompt": {"law": "lognormal", "median": 64, "sigma": 0.3,
                       "min": 32, "max": 128},
            "output": {"law": "lognormal", "median": 800, "sigma": 0.1,
@@ -35,6 +35,7 @@ def test_float8_control_fails_the_committed_limits():
     limit = harness.load_json(f"chip_bench/limits/{CELL}.json")["limits"]
     assert got["honest"]["correct"] is True, got
     assert got["float8_e4m3fn"]["correct"] is False, got
-    assert got["float8_e4m3fn"]["served_token_gap"] > \
-        limit["served_token_gap"] > got["honest"]["served_token_gap"]
+    for number in ("served_token_gap", "served_token_gap_mean"):
+        assert got["float8_e4m3fn"][number] > limit[number] > \
+            got["honest"][number], number
     assert got["honest"]["served_tokens_checked"] > 1000

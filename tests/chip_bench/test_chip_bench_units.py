@@ -98,6 +98,25 @@ def test_every_seed_gets_the_same_sizes_in_another_order():
     assert all(t.dtype == np.int32 and t.max() < 32000 for t in toks)
 
 
+def test_every_window_of_requests_asks_for_the_same_work():
+    """A window serves a run of a few hundred consecutive requests of the
+    cycled list: under every seed that run holds as many long prompts and
+    as many output tokens, to a few percent (a shuffle varies by a tenth)."""
+    mix = json.loads((ROOT / "chip_bench" / "traffic"
+                      / "sharegpt_closed256.json").read_text())
+    long_prompts, outputs = [], []
+    for seed in (1, 2, 2**31 + 5, 2**31 + 99):
+        sizes = traffic.request_sizes(mix, weights.seeded_rng(seed, 0))
+        for lo in (0, 200, 1900):
+            run = [sizes[(lo + i) % len(sizes)] for i in range(330)]
+            long_prompts.append(sum(p > 256 for p, _ in run))
+            outputs.append(sum(o for _, o in run))
+    assert max(long_prompts) - min(long_prompts) <= 3
+    assert max(outputs) / min(outputs) < 1.03
+    order = traffic.even_order(2048, traffic._STEPS[0], 0.3)
+    assert sorted(order.tolist()) == list(range(2048))
+
+
 @pytest.mark.parametrize("slots,longest,max_chunk", [(3, 16, 48),
                                                       (4, 40, 64)])
 def test_warmed_widths_cover_every_wave_the_policy_can_make(
@@ -160,6 +179,15 @@ def test_spreads_suggest_bounds(tmp_path):
     assert sp[1] == 0 and sp[0] == pytest.approx(stats.spread(
         [100, 101, 99, 100, 102, 98]))
     assert bound == pytest.approx(max(0.01, 5 * sp[0]))
+
+
+def test_step_times_names_the_pause():
+    from chip_bench.harness import step_times
+    ends = [0.1, 0.2, 0.3, 1.3, 1.4, 1.5]
+    line = step_times(0.0, ends, n=2)
+    assert line.startswith("steps 6: median 100.000 ms")
+    assert "longest 1000.000 ms at 1.300 s, 100.000 ms" in line
+    assert step_times(0.0, [0.25]).startswith("steps 1: median 250.000")
 
 
 # -- trace reductions ---------------------------------------------------------
@@ -265,3 +293,22 @@ def test_benchmark_json_finds_every_file_by_name():
     for m in bench["end_to_end"]:
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
+
+
+def test_each_cell_gets_only_its_own_metrics():
+    """``workloads`` on a metric keeps it to the cells it names: the
+    classification cell reports no serving metric, and the serving cell
+    reports exactly its own."""
+    from chip_bench import harness
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    names = lambda ms: {m["name"] for m in ms}
+    vision = harness.Cell(bench, "sf8-512.classify", 1)
+    serve = harness.Cell(bench, "sflm.batch", 1)
+    assert names(vision.end_to_end) == {"images_per_s", "setup_s"}
+    assert not any(n.endswith(".batch") for n in names(vision.per_layer))
+    assert names(serve.end_to_end) == {"tokens_per_s", "setup_s"}
+    assert names(serve.per_layer) == {"idle_share.batch", "mfu.batch",
+                                      "wave_device_ms.batch",
+                                      "wave_host_ms.batch"}
+    assert serve.config["registry"] == "spikingformer-lm"
+    assert serve.traffic["runner"] == "serve_closed" and serve.chips == 1
