@@ -1,13 +1,13 @@
 """Config registry: ``--arch <id>`` resolution.
 
-ARCHS maps every assigned architecture id (plus the paper's own models) to
-its module exposing CONFIG (published shape) and SMOKE (reduced config for
-CPU tests)."""
+ALL_ARCHS maps every architecture id (the assigned ones, the paper's own
+models, and the benchmark's mellum2-12b-a2.5b) to its module exposing
+CONFIG (published shape) and SMOKE (reduced config for CPU tests)."""
 from . import (cifarnet, deepseek_moe_16b, gemma3_12b, granite_20b,
                h2o_danube3_4b, hymba_1_5b, kimi_k2_1t_a32b,
-               llava_next_mistral_7b, nemotron_4_15b, rwkv6_3b, shapes,
-               spikingformer_4_256, spikingformer_8_512, spikingformer_lm,
-               whisper_small)
+               llava_next_mistral_7b, mellum2_12b_a2_5b, nemotron_4_15b,
+               rwkv6_3b, shapes, spikingformer_4_256, spikingformer_8_512,
+               spikingformer_lm, whisper_small)
 from .base import ModelConfig, RunShape
 from .shapes import SHAPES
 
@@ -26,10 +26,17 @@ _MODULES = {
     "spikingformer-8-512": spikingformer_8_512,
     "spikingformer-lm": spikingformer_lm,
     "cifarnet": cifarnet,
+    "mellum2-12b-a2.5b": mellum2_12b_a2_5b,
 }
 
-ASSIGNED_ARCHS = tuple(list(_MODULES)[:10])   # the 10 assigned cells
-PAPER_ARCHS = tuple(list(_MODULES)[10:])      # the paper's own models
+# the 10 assigned cells
+ASSIGNED_ARCHS = ("nemotron-4-15b", "gemma3-12b", "h2o-danube-3-4b",
+                  "granite-20b", "kimi-k2-1t-a32b", "deepseek-moe-16b",
+                  "rwkv6-3b", "hymba-1.5b", "whisper-small",
+                  "llava-next-mistral-7b")
+# the paper's own models
+PAPER_ARCHS = ("spikingformer-4-256", "spikingformer-8-512",
+               "spikingformer-lm", "cifarnet")
 ALL_ARCHS = tuple(_MODULES)
 
 

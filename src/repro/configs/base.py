@@ -15,16 +15,37 @@ from repro.core.spiking import SpikingConfig
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int                # experts held in the parameters
     top_k: int
     d_ff_expert: int
     num_shared: int = 0
     first_k_dense: int = 0          # leading dense layers (deepseek/kimi style)
     first_dense_ff: int = 0         # d_ff of those dense layers
+    # training forward only: 0 routes every token (dropless); serving
+    # paths are always dropless
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
     normalize_topk: bool = True     # renormalize top-k routing weights
+    # the router's outputs, the model's whole expert count, where a chip
+    # holds only ``num_experts`` of them (0: the router routes over the
+    # experts held)
+    router_width: int = 0
+
+    @property
+    def width(self) -> int:
+        return self.router_width or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rescaled RoPE (arXiv:2309.00071), as HF transformers'
+    ``_compute_yarn_parameters`` reads a ``rope_type: yarn`` entry."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +98,9 @@ class ModelConfig:
     global_every: int = 6            # local_global: one global layer per N
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # rope of the full-attention layers, where it is not the plain rope
+    # the window layers keep
+    yarn: Optional[YarnConfig] = None
     # mlp
     act: str = "silu"                # silu | gelu | relu2
     gated: bool = True

@@ -1,12 +1,23 @@
-"""Mixture-of-Experts decoder LM (deepseek-moe-16b, kimi-k2-1t-a32b).
+"""Mixture-of-Experts decoder LM (deepseek-moe-16b, kimi-k2-1t-a32b), and
+the expert layer that the dense family takes in place of its MLP when
+``cfg.moe`` is set (mellum2-12b-a2.5b).
+
+An expert layer is told which experts it holds: ids ``[offset, offset +
+held)`` of the router's ``MoEConfig.width``. It routes every token over
+all of them and computes the part of the result that its own experts
+give; what the others would add is left out (a chip's share of an
+expert-parallel deployment).
 
 Expert parallelism strategy (DESIGN.md §6): tokens are batch-sharded over
 ('pod','data') and *replicated* over 'model'; experts are sharded over
 'model'. Each model-shard computes its local experts' contribution for all
-of its tokens via **sort-based capacity dispatch** (argsort by expert id →
-capacity-bounded gather → batched expert matmul → scatter-add), then a
-psum over 'model' combines contributions. No all-to-all, no one-hot
-dispatch matmuls (which are FLOP-hostile at 384 experts).
+of its tokens, then a psum over 'model' combines contributions. No
+all-to-all, no one-hot dispatch matmuls (which are FLOP-hostile at 384
+experts). Two dispatches: **dropless** (argsort by expert id → gather →
+grouped matmul over the groups' real sizes → scatter-add), which every
+serving path takes, and **sort-based capacity dispatch** (capacity-bounded
+gather → batched expert matmul → scatter-add), which a training forward
+takes where ``capacity_factor`` is set.
 
 Dispatch runs inside ``shard_map`` when a mesh context is installed
 (launch layer), and falls back to the identical single-shard code path
@@ -20,9 +31,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental.pallas.ops.tpu.megablox import gmm
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoEConfig
+from repro.core.scopes import annotate
 from repro.core.spiking import lif_scan
 from repro.parallel.sharding import constrain, get_rules
 from . import nn
@@ -71,14 +84,16 @@ class use_ep_mesh:
 # ---------------------------------------------------------------------------
 
 
-def _moe_ffn_init(key, cfg: ModelConfig):
+def ffn_init(key, cfg: ModelConfig):
+    """The router over all ``m.width`` experts; the weights of the
+    ``m.num_experts`` held."""
     m = cfg.moe
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 5)
     e, d, f = m.num_experts, cfg.d_model, m.d_ff_expert
     std = 1.0 / math.sqrt(d)
     p = {
-        "router": nn.normal(ks[0], (d, e), std, jnp.float32),
+        "router": nn.normal(ks[0], (d, m.width), std, jnp.float32),
         "up": nn.normal(ks[1], (e, d, f), std, dt),
         "gate": nn.normal(ks[2], (e, d, f), std, dt),
         "down": nn.normal(ks[3], (e, f, d), 1.0 / math.sqrt(f), dt),
@@ -110,7 +125,7 @@ def _attn_init(key, cfg: ModelConfig):
 def _moe_layer_init(key, cfg: ModelConfig):
     k1, k2 = jax.random.split(key)
     p = _attn_init(k1, cfg)
-    p["moe"] = _moe_ffn_init(k2, cfg)
+    p["moe"] = ffn_init(k2, cfg)
     return p
 
 
@@ -157,7 +172,7 @@ def router_topk(x2d: jax.Array, router_w: jax.Array, m: MoEConfig):
     me = probs.mean(axis=0)                                   # (E,)
     assign = jnp.zeros_like(probs).at[
         jnp.arange(x2d.shape[0])[:, None], idx].set(1.0).mean(axis=0)
-    aux_lb = m.num_experts * jnp.sum(me * assign)
+    aux_lb = m.width * jnp.sum(me * assign)
     aux_z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     return w.astype(jnp.float32), idx, aux_lb, aux_z
 
@@ -182,7 +197,7 @@ def _dispatch_local(x2d, w, idx, up, gate, down, m: MoEConfig, act: str,
     """
     t, d = x2d.shape
     k = m.top_k
-    cap = max(1, int(math.ceil(t * k / m.num_experts * m.capacity_factor)))
+    cap = max(1, int(math.ceil(t * k / m.width * m.capacity_factor)))
 
     flat_e = idx.reshape(-1)                        # (T*K,) global expert ids
     local_e = flat_e - local_offset
@@ -211,17 +226,95 @@ def _dispatch_local(x2d, w, idx, up, gate, down, m: MoEConfig, act: str,
     return out.astype(x2d.dtype)
 
 
-def moe_ffn(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """x: (..., S, D) -> (y, aux_loss). EP via shard_map when mesh is set."""
+# (rows, K, N) tile of the grouped matmul: the fastest of those measured
+# at mellum2.chat's expert shapes on a v5e (PERF.md), and twice as
+# fast there as jax.lax.ragged_dot's own TPU kernel
+GMM_TILE = (128, 768, 896)
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """lhs (M, K), its rows sorted by group, M a multiple of
+    ``GMM_TILE[0]``; rhs (G, K, N); sizes (G,) int32 -> (M, N): each
+    group's rows times its matrix (the Pallas kernel ``megablox.gmm``,
+    interpreted off the TPU). Rows past ``sizes.sum()`` are left
+    undefined."""
+    tm, tk, tn = GMM_TILE
+    return gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+               tiling=(tm, min(tk, rhs.shape[1]), min(tn, rhs.shape[2])),
+               interpret=jax.default_backend() != "tpu")
+
+
+def _dispatch_dropless(x2d, w, idx, up, gate, down, act: str, offset,
+                       valid) -> jax.Array:
+    """Every (row, expert) pair that falls on a held expert, none dropped.
+
+    x2d (T, D); w/idx (T, K) renormalised weights and global expert ids;
+    expert weights (E_held, ...) for ids [offset, offset + E_held);
+    valid (T,) bool: a row where it is False (padding) joins no group.
+    The pairs are sorted by expert, the SwiGLU runs as grouped matmuls
+    over the groups' real sizes, and each output, times its weight, is
+    added back to its row.
+    """
+    t, d = x2d.shape
+    k = idx.shape[-1]
+    e_held = up.shape[0]
+    # a row picks distinct experts, so at most min(K, E_held) of its pairs
+    # are held: the held pairs sort into the first T * that, rounded up to
+    # the grouped matmul's row tile
+    tm = GMM_TILE[0]
+    m = -(-t * min(k, e_held) // tm) * tm
+    with annotate("moe.dispatch"):
+        local = idx - offset
+        held = (local >= 0) & (local < e_held) & valid[:, None]
+        key = jnp.where(held, local, e_held).reshape(-1)      # (T*K,)
+        key = jnp.pad(key, (0, max(0, m - t * k)), constant_values=e_held)
+        order = jnp.argsort(key, stable=True)[:m]
+        sizes = jnp.bincount(key, length=e_held + 1)[:e_held].astype(
+            jnp.int32)
+        row = jnp.minimum(order // k, t - 1)   # pad pairs: any real row
+        real = key[order] < e_held
+        # rows outside every group are zero: the kernel leaves those rows
+        # of its outputs undefined, and their gradients must not reach x
+        xs = jnp.where(real[:, None], jnp.take(x2d, row, axis=0), 0)
+    with annotate("moe.experts"):
+        h = nn.activation(act)(grouped_matmul(xs, gate, sizes)) \
+            * grouped_matmul(xs, up, sizes)
+        ys = grouped_matmul(h, down, sizes)
+    with annotate("moe.combine"):
+        weight = jnp.take(w.reshape(-1), order, mode="fill", fill_value=0)
+        ys = jnp.where(real[:, None], ys.astype(jnp.float32), 0.0)
+        out = jnp.zeros((t, d), jnp.float32).at[row].add(
+            ys * weight[:, None])
+    return out.astype(x2d.dtype)
+
+
+def moe_ffn(p, x: jax.Array, cfg: ModelConfig, *, train: bool = False,
+            valid: Optional[jax.Array] = None,
+            offset: int = 0) -> Tuple[jax.Array, jax.Array]:
+    """x: (..., S, D) -> (y, aux_loss): the part of the expert layer that
+    the experts held in ``p`` give, ids ``[offset, offset + held)``.
+    ``valid`` (x.shape[:-1] bool): rows that join no expert where False.
+    A training forward with ``capacity_factor`` set drops what overflows
+    an expert's capacity; every other call is dropless. EP via shard_map
+    when mesh is set, each shard's offset from its axis index."""
     m = cfg.moe
     lead = x.shape[:-1]
     mesh, token_axes, expert_axis = get_ep_mesh()
+    capacity = train and m.capacity_factor > 0
+    if valid is None:
+        valid = jnp.ones(lead, bool)
 
-    def run(x_loc, router_w, up, gate, down, *, e_local, offset, in_map):
+    def run(x_loc, router_w, up, gate, down, valid_loc, *, e_local, offset,
+            in_map):
         x2d = x_loc.reshape(-1, x_loc.shape[-1])
-        w, idx, aux_lb, aux_z = router_topk(x2d, router_w, m)
-        y = _dispatch_local(x2d, w, idx, up, gate, down, m, cfg.act,
-                            e_local, offset)
+        with annotate("moe.router"):
+            w, idx, aux_lb, aux_z = router_topk(x2d, router_w, m)
+        if capacity:
+            y = _dispatch_local(x2d, w, idx, up, gate, down, m, cfg.act,
+                                e_local, offset)
+        else:
+            y = _dispatch_dropless(x2d, w, idx, up, gate, down, cfg.act,
+                                   offset, valid_loc.reshape(-1))
         aux = m.router_aux_weight * aux_lb + m.router_z_weight * aux_z
         if in_map:
             # combine expert contributions across the EP axis in bf16 —
@@ -233,26 +326,27 @@ def moe_ffn(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
         return y.reshape(x_loc.shape), aux
 
     if mesh is None:
-        y, aux = run(x, p["router"], p["up"], p["gate"], p["down"],
-                     e_local=m.num_experts, offset=0, in_map=False)
+        y, aux = run(x, p["router"], p["up"], p["gate"], p["down"], valid,
+                     e_local=m.num_experts, offset=offset, in_map=False)
     else:
         ep_size = mesh.shape[expert_axis]
         e_local = m.num_experts // ep_size
-        tok_spec = P(tuple(a for a in token_axes if a in mesh.axis_names),
-                     *([None] * (x.ndim - 1)))
+        axes = tuple(a for a in token_axes if a in mesh.axis_names)
+        tok_spec = P(axes, *([None] * (x.ndim - 1)))
+        valid_spec = P(axes, *([None] * (valid.ndim - 1)))
 
-        def mapped(x_loc, router_w, up, gate, down):
-            offset = jax.lax.axis_index(expert_axis) * e_local
-            return run(x_loc, router_w, up, gate, down,
-                       e_local=e_local, offset=offset, in_map=True)
+        def mapped(x_loc, router_w, up, gate, down, valid_loc):
+            shard_offset = offset + jax.lax.axis_index(expert_axis) * e_local
+            return run(x_loc, router_w, up, gate, down, valid_loc,
+                       e_local=e_local, offset=shard_offset, in_map=True)
 
         y, aux = jax.shard_map(
             mapped, mesh=mesh,
             in_specs=(tok_spec, P(), P(expert_axis), P(expert_axis),
-                      P(expert_axis)),
+                      P(expert_axis), valid_spec),
             out_specs=(tok_spec, P()),
             check_vma=False,
-        )(x, p["router"], p["up"], p["gate"], p["down"])
+        )(x, p["router"], p["up"], p["gate"], p["down"], valid)
 
     if m.num_shared:
         y = y + nn.mlp(p["shared"], x, cfg.act)
@@ -283,7 +377,7 @@ def _attn_block(p, cfg: ModelConfig, x, positions, train: bool):
 def _moe_layer(p, cfg: ModelConfig, x, positions, train: bool):
     x = _attn_block(p, cfg, x, positions, train)
     h = nn.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    y, aux = moe_ffn(p["moe"], h, cfg)
+    y, aux = moe_ffn(p["moe"], h, cfg, train=train)
     # name the expert output so the remat policy can SAVE it: recomputing
     # the expert FFN in bwd would re-gather the FSDP-sharded expert
     # weights a 3rd time (§Perf K4)

@@ -16,6 +16,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.parallel.sharding import constrain
 
@@ -187,16 +188,48 @@ def mlp(p, x, act: str):
 # ---------------------------------------------------------------------------
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Apply RoPE. x: (B, L, H, D), positions: (B, L) or (L,)."""
+def yarn_freqs(d: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's (arXiv:2309.00071) rotary frequencies for head size ``d``,
+    as HF transformers' ``_compute_yarn_parameters`` computes them: the
+    dims that turn fewer than ``beta_slow`` times over the original
+    context are interpolated (frequency / factor), those that turn more
+    than ``beta_fast`` times keep their frequency, and a linear ramp
+    between the two correction dims blends the rest."""
+    half = d // 2
+    base = theta ** (np.arange(0, d, 2, dtype=np.float32) / d)
+
+    def correction_dim(rotations):
+        return d * math.log(yarn.original_max_position
+                            / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0, 1)                 # share of the interpolated one
+    return (ramp / (yarn.factor * base) + (1 - ramp) / base).astype(
+        np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         yarn=None) -> jax.Array:
+    """Apply RoPE. x: (B, L, H, D), positions: (B, L) or (L,). ``yarn``
+    (``configs.base.YarnConfig``): YaRN's frequencies, and cos and sin
+    scaled by its attention factor."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn_freqs(d, theta, yarn))
     if positions.ndim == 1:
         positions = positions[None]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, L, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -14,6 +14,7 @@ Execution modes:
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -45,9 +46,13 @@ def _layer_init(key, cfg: ModelConfig):
                              std=1.0 / math.sqrt(cfg.q_dim * 2 * cfg.num_layers),
                              dtype=dt),
         "ln2": nn.rmsnorm_init(cfg.d_model, dt),
-        "mlp": nn.mlp_init(ks[4], cfg.d_model, cfg.d_ff, gated=cfg.gated,
-                           dtype=dt),
     }
+    if cfg.moe is None:
+        p["mlp"] = nn.mlp_init(ks[4], cfg.d_model, cfg.d_ff, gated=cfg.gated,
+                               dtype=dt)
+    else:
+        from repro.models import moe
+        p["moe"] = moe.ffn_init(ks[4], cfg)
     if cfg.qk_norm:
         p["q_norm"] = nn.rmsnorm_init(cfg.head_dim, dt)
         p["k_norm"] = nn.rmsnorm_init(cfg.head_dim, dt)
@@ -82,8 +87,10 @@ def init(cfg: ModelConfig, key) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def _project_qkv(p, cfg: ModelConfig, h, positions, repeat_kv: bool = False):
-    """h: (..., S, D) -> q (..., S, H, hd), k/v (..., S, KH, hd), roped.
+def _project_qkv(p, cfg: ModelConfig, h, positions, repeat_kv: bool = False,
+                 kind: str = "full"):
+    """h: (..., S, D) -> q (..., S, H, hd), k/v (..., S, KH, hd), roped
+    (``cfg.yarn`` on layers of ``kind`` 'full').
 
     ``repeat_kv`` broadcasts KV heads up to H *before* attention (full-seq
     paths): with heads TP-sharded over 'model', the grouped-GQA reshape
@@ -101,10 +108,13 @@ def _project_qkv(p, cfg: ModelConfig, h, positions, repeat_kv: bool = False):
         q = nn.rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = nn.rmsnorm(p["k_norm"], k, cfg.norm_eps)
     # rope operates on (B, L, H, D): fold extra leading dims
+    yarn = cfg.yarn if kind == "full" else None
     q = nn.rope(q.reshape(-1, s, cfg.num_heads, cfg.head_dim), positions,
-                cfg.rope_theta).reshape(*lead, s, cfg.num_heads, cfg.head_dim)
+                cfg.rope_theta, yarn).reshape(*lead, s, cfg.num_heads,
+                                              cfg.head_dim)
     k = nn.rope(k.reshape(-1, s, cfg.num_kv_heads, cfg.head_dim), positions,
-                cfg.rope_theta).reshape(*lead, s, cfg.num_kv_heads, cfg.head_dim)
+                cfg.rope_theta, yarn).reshape(*lead, s, cfg.num_kv_heads,
+                                              cfg.head_dim)
     if repeat_kv and cfg.num_heads != cfg.num_kv_heads:
         rep = cfg.num_heads // cfg.num_kv_heads
         k = jnp.repeat(k, rep, axis=-2)
@@ -142,6 +152,23 @@ def _attend_full_seq(cfg: ModelConfig, kind: str, q, k, v, delta=None):
     return nn.flash_attention(q, k, v, causal=True)
 
 
+def _attn_scope(cfg: ModelConfig, kind: str):
+    """``attn.window`` / ``attn.full`` around a layer's attention half;
+    none in spiking mode, whose phases carry the engine's scopes."""
+    if cfg.spiking is not None:
+        return contextlib.nullcontext()
+    return annotate(f"attn.{kind}")
+
+
+def _ffn(p, cfg: ModelConfig, h, train: bool = False, valid=None):
+    """The analog FFN: the MLP, or with ``cfg.moe`` the expert layer
+    (rows where ``valid`` is False join no expert)."""
+    if cfg.moe is None:
+        return nn.mlp(p["mlp"], h, cfg.act)
+    from repro.models import moe
+    return moe.moe_ffn(p["moe"], h, cfg, train=train, valid=valid)[0]
+
+
 def _spike(x, cfg: ModelConfig, t_steps: int):
     """LIF over the time axis; x: (T, B, S, D) currents -> spikes."""
     spikes, _ = lif_scan(x, cfg.spiking)
@@ -163,30 +190,30 @@ def apply_layer(p, cfg: ModelConfig, x, positions, kind: str, train: bool):
         # (the fused grid is full-attention only).
         from repro.core.engine import layer_step_causal
         return layer_step_causal(p, cfg, x, positions, train=train)
-    h = nn.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    if spiking:
-        t = x.shape[0]
-        q, k, v = _project_qkv(p, cfg, h, positions, repeat_kv=True)
-        q, k, v = (_spike(u, cfg, t) for u in (q, k, v))
-        fold = lambda u: u.reshape(-1, *u.shape[2:])
-        attn = _attend_full_seq(cfg, kind, fold(q), fold(k), fold(v),
-                                delta=p["delta"])
+    with _attn_scope(cfg, kind):
+        h = nn.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        q, k, v = _project_qkv(p, cfg, h, positions, repeat_kv=True,
+                               kind=kind)
+        if spiking:
+            t = x.shape[0]
+            q, k, v = (_spike(u, cfg, t) for u in (q, k, v))
+            fold = lambda u: u.reshape(-1, *u.shape[2:])
+            attn = _attend_full_seq(cfg, kind, fold(q), fold(k), fold(v),
+                                    delta=p["delta"])
+        else:
+            attn = _attend_full_seq(cfg, kind, q, k, v)
         attn = attn.reshape(*x.shape[:-1], cfg.q_dim)
-    else:
-        q, k, v = _project_qkv(p, cfg, h, positions, repeat_kv=True)
-        attn = _attend_full_seq(cfg, kind, q, k, v)
-        attn = attn.reshape(*x.shape[:-1], cfg.q_dim)
-    # q_dim stays 'model'-sharded into the row-parallel wo (§Perf F2 —
-    # constraining to replicated here forced a (B,S,H,hd) all-gather)
-    attn = constrain(attn, "batch", "seq", "model")
-    x = x + nn.linear(p["wo"], attn)
+        # q_dim stays 'model'-sharded into the row-parallel wo (§Perf F2 —
+        # constraining to replicated here forced a (B,S,H,hd) all-gather)
+        attn = constrain(attn, "batch", "seq", "model")
+        x = x + nn.linear(p["wo"], attn)
     h2 = nn.rmsnorm(p["ln2"], x, cfg.norm_eps)
     if spiking:
         up = nn.linear(p["mlp"]["up"], h2)
         hidden = _spike(up, cfg, x.shape[0])
         x = x + nn.linear(p["mlp"]["down"], hidden)
     else:
-        x = x + nn.mlp(p["mlp"], h2, cfg.act)
+        x = x + _ffn(p, cfg, h2, train=train)
     return constrain(x, "batch", "seq", "embed")
 
 
@@ -273,6 +300,16 @@ def _packed_kv(cfg: ModelConfig) -> bool:
             and cfg.engine.packed_kv)
 
 
+def kv_heads_major(cfg: ModelConfig) -> bool:
+    """Analog K/V caches are stored heads-major, (..., B, KH, S, hd): each
+    head's positions lie together, as the attention products read them,
+    and the positions (not the few KV heads) are the tiled second-minor
+    dim. Stored (..., B, S, KH, hd), a cache carried through the layer
+    scan is relaid out whole, heads first, at every step (and held
+    twice). Spiking caches keep (..., B', S, KH, hd|words)."""
+    return cfg.spiking is None
+
+
 def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
                batch=None, params=None,
                chunk_headroom: int = 0) -> Dict[str, Any]:
@@ -295,9 +332,12 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
             return {"k": jnp.zeros(shape, jnp.uint32),
                     "v": jnp.zeros(shape, jnp.uint32),
                     "pos": jnp.full((n_layers, batch_size, s), -1, jnp.int32)}
+        shape = (n_layers, b, cfg.num_kv_heads, s, cfg.head_dim) \
+            if kv_heads_major(cfg) else \
+            (n_layers, b, s, cfg.num_kv_heads, cfg.head_dim)
         return {
-            "k": jnp.zeros((n_layers, b, s, cfg.num_kv_heads, cfg.head_dim), dt),
-            "v": jnp.zeros((n_layers, b, s, cfg.num_kv_heads, cfg.head_dim), dt),
+            "k": jnp.zeros(shape, dt),
+            "v": jnp.zeros(shape, dt),
             "pos": jnp.full((n_layers, batch_size, s), -1, jnp.int32),
         }
 
@@ -309,6 +349,19 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
     return {"layers": kv(cfg.num_layers, kind)}
 
 
+def _put_kv(cache, new, lead, slots, heads_major: bool):
+    """Write new (B', C, KH, hd) at each row's slots (B', C) of cache
+    (*lead, B', S, KH, hd), or (*lead, B', KH, S, hd) ``heads_major``;
+    slots == S (padding) are dropped."""
+    rows = jnp.arange(new.shape[0])[:, None]
+    if heads_major:
+        heads = jnp.arange(new.shape[2])[None, :, None]
+        return cache.at[lead + (rows[:, :, None], heads,
+                                slots[:, None, :])].set(
+            new.swapaxes(1, 2), mode="drop")
+    return cache.at[lead + (rows, slots)].set(new, mode="drop")
+
+
 def _scatter_rows(cache, new, slots):
     """Per-row cache write: cache (B', S, ...), new (B', C, ...), slots
     (B', C) int32 — row b writes new[b, i] at cache[b, slots[b, i]].
@@ -318,7 +371,8 @@ def _scatter_rows(cache, new, slots):
         cache, new, slots)
 
 
-def _decode_layer(p, cfg: ModelConfig, x, cache_l, pos, n_tok, kind: str):
+def _decode_layer(p, cfg: ModelConfig, x, cache_l, pos, n_tok, kind: str,
+                  layer=None):
     """One decode token or a chunked-prefill bite against this layer's KV
     cache, with a *per-slot* timeline.
 
@@ -329,6 +383,9 @@ def _decode_layer(p, cfg: ModelConfig, x, cache_l, pos, n_tok, kind: str):
     the common chunk width C; padded positions are neither written to the
     cache nor tagged valid, so a decode slot rides a prefill wave at C=1
     cost in cache state).
+    layer: where ``cache_l`` stacks many layers' caches, this layer's
+    index into them: the bite is written into the stack in place, and the
+    stack is returned.
     """
     b = pos.shape[0]
     b_rows, c = x.shape[0], x.shape[1]
@@ -337,65 +394,87 @@ def _decode_layer(p, cfg: ModelConfig, x, cache_l, pos, n_tok, kind: str):
         if reps_t > 1 else (lambda u: u)
     qpos = pos[:, None] + jnp.arange(c)        # (B, C) absolute q positions
     qpos_rows = tile(qpos)                     # (B', C)
-    h = nn.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    q, k, v = _project_qkv(p, cfg, h, qpos_rows)
-    if cfg.spiking is not None:
-        # T_s is folded into the batch dim; unfold for LIF dynamics over time.
-        t = cfg.spiking.time_steps
+    with _attn_scope(cfg, kind):
+        h = nn.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        q, k, v = _project_qkv(p, cfg, h, qpos_rows, kind=kind)
+        if cfg.spiking is not None:
+            # T_s is folded into the batch dim; unfold for LIF dynamics
+            # over time.
+            t = cfg.spiking.time_steps
 
-        def lif_t(u):
-            u_t = u.reshape(t, -1, *u.shape[1:])
-            s, _ = lif_scan(u_t, cfg.spiking)
-            return s.reshape(-1, *u.shape[1:])
-        q, k, v = lif_t(q), lif_t(k), lif_t(v)
-    else:
-        lif_t = None
-    window = cfg.window if kind == "window" else None
-    packed = _packed_kv(cfg)
-    if packed:
-        # spikes pack losslessly: K/V are {0,1} after the LIF, one uint32
-        # word per 32 channels (the binary engine's spike-RAM layout)
-        k, v = pack_bits(k), pack_bits(v)
-    s_len = cache_l["k"].shape[1]
-    # rolling write for window caches (== pos for full); chunk width must
-    # not exceed the window, or a bite would overwrite its own entries
-    slot = jnp.where(jnp.arange(c)[None, :] < n_tok[:, None],
-                     qpos % s_len, s_len).astype(jnp.int32)  # (B, C)
-    slot_rows = tile(slot)
-    k_cache = _scatter_rows(cache_l["k"], k, slot_rows)
-    v_cache = _scatter_rows(cache_l["v"], v, slot_rows)
-    entry_pos = jax.vmap(lambda e, s, val: e.at[s].set(val, mode="drop"))(
-        cache_l["pos"], slot, qpos.astype(jnp.int32))
-    if cfg.spiking is not None:
-        qf = q.reshape(b_rows, c, cfg.num_kv_heads,
-                       cfg.num_heads // cfg.num_kv_heads, cfg.head_dim)
-        if packed:
-            # AND-PopCount against the packed cache: exact integer overlap
-            # counts, bit-identical to the fp32 dot on unpacked spikes
-            qp = pack_bits(qf)                       # (B', C, KH, rep, W)
-            kcT = k_cache.transpose(0, 2, 1, 3)      # (B', KH, S, W)
-            counts = jax.lax.population_count(
-                qp[:, :, :, :, None, :] & kcT[:, None, :, None, :, :]).sum(
-                axis=-1).astype(jnp.int32)           # (B', C, KH, rep, S)
-            sc = counts.astype(jnp.float32) / math.sqrt(cfg.head_dim)
+            def lif_t(u):
+                u_t = u.reshape(t, -1, *u.shape[1:])
+                s, _ = lif_scan(u_t, cfg.spiking)
+                return s.reshape(-1, *u.shape[1:])
+            q, k, v = lif_t(q), lif_t(k), lif_t(v)
         else:
-            sc = jnp.einsum("bcgrd,bkgd->bcgrk", qf.astype(jnp.float32),
-                            k_cache.astype(jnp.float32)) / math.sqrt(cfg.head_dim)
-        a = binarize(sc, p["delta"], cfg.spiking.surrogate_alpha)
-        valid = (entry_pos[:, None, :] >= 0) & \
-            (entry_pos[:, None, :] <= qpos[:, :, None])       # (B, C, S)
-        if window is not None:
-            valid &= entry_pos[:, None, :] > qpos[:, :, None] - window
-        a = jnp.where(tile(valid)[:, :, None, None, :], a, 0.0)
-        vc = unpack_bits(v_cache, cfg.head_dim) if packed \
-            else v_cache.astype(jnp.float32)
-        attn = jnp.einsum("bcgrk,bkgd->bcgrd", a, vc)
-        attn = attn.reshape(b_rows, c, cfg.q_dim).astype(x.dtype)
-    else:
-        attn = nn.decode_attention(q, k_cache, v_cache, entry_pos=entry_pos,
-                                   cur_pos=qpos, window=window)
-        attn = attn.reshape(b_rows, c, cfg.q_dim)
-    x = x + nn.linear(p["wo"], attn)
+            lif_t = None
+        window = cfg.window if kind == "window" else None
+        packed = _packed_kv(cfg)
+        if packed:
+            # spikes pack losslessly: K/V are {0,1} after the LIF, one uint32
+            # word per 32 channels (the binary engine's spike-RAM layout)
+            k, v = pack_bits(k), pack_bits(v)
+        lead = () if layer is None else (layer,)
+        heads_major = kv_heads_major(cfg)
+        s_len = cache_l["pos"].shape[-1]
+        # rolling write for window caches (== pos for full); chunk width must
+        # not exceed the window, or a bite would overwrite its own entries
+        slot = jnp.where(jnp.arange(c)[None, :] < n_tok[:, None],
+                         qpos % s_len, s_len).astype(jnp.int32)  # (B, C)
+        slot_rows = tile(slot)
+        if layer is None and not heads_major:
+            k_all = _scatter_rows(cache_l["k"], k, slot_rows)
+            v_all = _scatter_rows(cache_l["v"], v, slot_rows)
+        else:
+            k_all, v_all = (_put_kv(cache_l[n], u, lead, slot_rows,
+                                    heads_major)
+                            for n, u in (("k", k), ("v", v)))
+        if layer is None:
+            pos_all = jax.vmap(
+                lambda e, s, val: e.at[s].set(val, mode="drop"))(
+                cache_l["pos"], slot, qpos.astype(jnp.int32))
+        else:
+            pos_all = cache_l["pos"].at[lead + (jnp.arange(b)[:, None],
+                                                slot)].set(
+                qpos.astype(jnp.int32), mode="drop")
+        new_cache = {"k": k_all, "v": v_all, "pos": pos_all}
+        k_cache, v_cache, entry_pos = (a[lead] for a in (k_all, v_all,
+                                                         pos_all))
+        if heads_major:
+            k_cache, v_cache = k_cache.swapaxes(1, 2), v_cache.swapaxes(1, 2)
+        if cfg.spiking is not None:
+            qf = q.reshape(b_rows, c, cfg.num_kv_heads,
+                           cfg.num_heads // cfg.num_kv_heads, cfg.head_dim)
+            if packed:
+                # AND-PopCount against the packed cache: exact integer overlap
+                # counts, bit-identical to the fp32 dot on unpacked spikes
+                qp = pack_bits(qf)                       # (B', C, KH, rep, W)
+                kcT = k_cache.transpose(0, 2, 1, 3)      # (B', KH, S, W)
+                counts = jax.lax.population_count(
+                    qp[:, :, :, :, None, :] & kcT[:, None, :, None, :, :]).sum(
+                    axis=-1).astype(jnp.int32)           # (B', C, KH, rep, S)
+                sc = counts.astype(jnp.float32) / math.sqrt(cfg.head_dim)
+            else:
+                sc = jnp.einsum("bcgrd,bkgd->bcgrk", qf.astype(jnp.float32),
+                                k_cache.astype(jnp.float32)) \
+                    / math.sqrt(cfg.head_dim)
+            a = binarize(sc, p["delta"], cfg.spiking.surrogate_alpha)
+            valid = (entry_pos[:, None, :] >= 0) & \
+                (entry_pos[:, None, :] <= qpos[:, :, None])       # (B, C, S)
+            if window is not None:
+                valid &= entry_pos[:, None, :] > qpos[:, :, None] - window
+            a = jnp.where(tile(valid)[:, :, None, None, :], a, 0.0)
+            vc = unpack_bits(v_cache, cfg.head_dim) if packed \
+                else v_cache.astype(jnp.float32)
+            attn = jnp.einsum("bcgrk,bkgd->bcgrd", a, vc)
+            attn = attn.reshape(b_rows, c, cfg.q_dim).astype(x.dtype)
+        else:
+            attn = nn.decode_attention(q, k_cache, v_cache,
+                                       entry_pos=entry_pos, cur_pos=qpos,
+                                       window=window)
+            attn = attn.reshape(b_rows, c, cfg.q_dim)
+        x = x + nn.linear(p["wo"], attn)
     h2 = nn.rmsnorm(p["ln2"], x, cfg.norm_eps)
     if cfg.spiking is not None:
         # mirror the full-seq spiking MLP (up -> LIF -> down, no gate/act)
@@ -403,8 +482,8 @@ def _decode_layer(p, cfg: ModelConfig, x, cache_l, pos, n_tok, kind: str):
         up = nn.linear(p["mlp"]["up"], h2)
         x = x + nn.linear(p["mlp"]["down"], lif_t(up))
     else:
-        x = x + nn.mlp(p["mlp"], h2, cfg.act)
-    new_cache = {"k": k_cache, "v": v_cache, "pos": entry_pos}
+        real = jnp.arange(c)[None, :] < n_tok[:, None]        # (B, C)
+        x = x + _ffn(p, cfg, h2, valid=real)
     return x, new_cache
 
 
@@ -433,34 +512,26 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, n_tok=None):
         g = cfg.num_layers // cfg.global_every
         n_local = cfg.global_every - 1
 
-        def group_body(x, inp):
-            gp, c_loc, c_glob = inp
-            new_loc, new_glob = [], []
+        # the caches ride the scan's carry, and each layer writes its bite
+        # into them in place: as the scan's xs and ys they would be held
+        # twice
+        def group_body(carry, inp):
+            x, c_loc, c_glob = carry
+            gp, i = inp
             for j in range(cfg.global_every):
                 sub = jax.tree_util.tree_map(lambda a: a[j], gp)
                 if j < n_local:
-                    cl = jax.tree_util.tree_map(lambda a: a[j], c_loc)
-                    x, nc = _decode_layer(sub, cfg, x, cl, pos, n_tok,
-                                          "window")
-                    new_loc.append(nc)
+                    x, c_loc = _decode_layer(sub, cfg, x, c_loc, pos, n_tok,
+                                             "window", layer=i * n_local + j)
                 else:
-                    cl = jax.tree_util.tree_map(lambda a: a[0], c_glob)
-                    x, nc = _decode_layer(sub, cfg, x, cl, pos, n_tok,
-                                          "full")
-                    new_glob.append(nc)
-            stack = lambda cs: jax.tree_util.tree_map(
-                lambda *a: jnp.stack(a), *cs)
-            return x, (stack(new_loc), stack(new_glob))
+                    x, c_glob = _decode_layer(sub, cfg, x, c_glob, pos,
+                                              n_tok, "full", layer=i)
+            return (x, c_loc, c_glob), None
 
-        resh = lambda c, n: jax.tree_util.tree_map(
-            lambda a: a.reshape(g, n, *a.shape[1:]), c)
-        x, (nl, ng) = jax.lax.scan(
-            group_body, x,
-            (params["groups"], resh(cache["local"], n_local),
-             resh(cache["global"], 1)))
-        flat = lambda c: jax.tree_util.tree_map(
-            lambda a: a.reshape(-1, *a.shape[2:]), c)
-        new_cache = {"local": flat(nl), "global": flat(ng)}
+        (x, nl, ng), _ = jax.lax.scan(
+            group_body, (x, cache["local"], cache["global"]),
+            (params["groups"], jnp.arange(g)))
+        new_cache = {"local": nl, "global": ng}
     else:
         kind = "window" if cfg.attn_type == "swa" else "full"
 

@@ -207,7 +207,12 @@ def cache_partition(cfg: ModelConfig, shape: RunShape, mesh: Mesh,
                 parts.append(part)
         return P(*parts)
 
-    rules = [(rx, materialize(spec)) for rx, spec in _CACHE_RULES_BASE]
+    base = _CACHE_RULES_BASE
+    from repro.models import transformer
+    if cfg.family in ("dense", "vlm") and transformer.kv_heads_major(cfg):
+        # (layers, B, KH, S, hd): heads before positions
+        base = [(r"(^|/)(k|v)$", P(None, BATCH, TP, "__SEQ__", None))] + base
+    rules = [(rx, materialize(spec)) for rx, spec in base]
     specs = param_specs(abstract_cache, rules, default=P(), mesh=mesh)
 
     # slotted-decode validity tags are (n_layers, B, s) — shard the slot

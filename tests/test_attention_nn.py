@@ -111,3 +111,48 @@ def test_flash_fp32_accumulation_stability():
     assert np.isfinite(np.asarray(out, np.float32)).all()
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want), atol=0.05, rtol=0.05)
+
+
+def test_yarn_rope_matches_the_formula():
+    """YaRN (arXiv:2309.00071) as HF transformers' _compute_yarn_parameters
+    writes it, spelled out at a few positions for Mellum2's full layers:
+    theta 500000, factor 16 over 8192 positions, beta 32 and 1, head 128;
+    cos and sin scaled by the attention factor."""
+    import math
+    from repro.configs.base import YarnConfig
+    yarn = YarnConfig(factor=16.0, original_max_position=8192,
+                      beta_fast=32.0, beta_slow=1.0,
+                      attention_factor=1.2772588722239782)
+    d, theta = 128, 500000.0
+    pos_freqs = theta ** (np.arange(0, d, 2) / d)
+    extrapolation, interpolation = 1 / pos_freqs, 1 / (16.0 * pos_freqs)
+
+    def dim(rot):
+        return d * math.log(8192 / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low, high = max(math.floor(dim(32)), 0), min(math.ceil(dim(1)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0, 1)
+    extrap_factor = 1 - ramp
+    inv_freq = interpolation * (1 - extrap_factor) \
+        + extrapolation * extrap_factor
+    assert (low, high) == (18, 35)   # 18.08 and 34.98 before rounding
+    np.testing.assert_allclose(nn.yarn_freqs(d, theta, yarn), inv_freq,
+                               rtol=1e-6)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 4, 2, d))
+    positions = jnp.asarray([0, 1, 1000, 4095])
+    got = nn.rope(x, positions, theta, yarn)
+    # the angle in float32, as the program forms it
+    ang = np.float32(np.asarray(positions)[:, None]) * np.float32(inv_freq)
+    cos = (np.cos(ang) * yarn.attention_factor)[None, :, None, :]
+    sin = (np.sin(ang) * yarn.attention_factor)[None, :, None, :]
+    x1, x2 = np.asarray(x[..., :64]), np.asarray(x[..., 64:])
+    want = np.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    # float32 cos and sin of angles up to 4095 rad are good to about an
+    # ulp of the angle, 2.4e-4 rad, which |x| * 1.28 (under 5) scales
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-3, rtol=0)
+    np.testing.assert_allclose(np.asarray(got[:, :2]), want[:, :2],
+                               atol=1e-5, rtol=0)
+    # position 0 only scales: the rope at angle 0 times the factor
+    np.testing.assert_allclose(np.asarray(got[:, 0]),
+                               np.asarray(x[:, 0]) * yarn.attention_factor,
+                               rtol=1e-6)

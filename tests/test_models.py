@@ -40,10 +40,12 @@ def _decode_vs_forward(arch, n=10, max_len=24):
 @pytest.mark.parametrize("arch", ["nemotron-4-15b", "gemma3-12b",
                                   "h2o-danube-3-4b", "granite-20b",
                                   "deepseek-moe-16b", "rwkv6-3b",
-                                  "hymba-1.5b", "whisper-small"])
+                                  "hymba-1.5b", "whisper-small",
+                                  "mellum2-12b-a2.5b"])
 def test_decode_matches_forward(arch):
     """Token-by-token decode == full-sequence forward (all cache kinds:
-    full, rolling-window, local+global, MoE, recurrent states)."""
+    full, rolling-window, local+global, MoE, expert layers in the dense
+    family, recurrent states)."""
     _decode_vs_forward(arch)
 
 
@@ -144,3 +146,93 @@ def test_moe_capacity_drops_overflow():
     got = moe_mod._dispatch_local(x, w, idx, up, gate, down, m, "silu", 2, 0)
     served = (np.abs(np.asarray(got)).sum(-1) > 0).sum()
     assert served == 2  # capacity = ceil(8*1/2*0.5) = 2
+
+
+# ---------------------------------------------------------------------------
+# expert layer of the dense family: held shares, dropless dispatch
+# ---------------------------------------------------------------------------
+
+
+def _expert_cfg(held, width=16, top_k=4):
+    return get_config("mellum2-12b-a2.5b", smoke=True).replace(
+        moe=MoEConfig(num_experts=held, router_width=width, top_k=top_k,
+                      d_ff_expert=32, capacity_factor=0.0))
+
+
+def _expert_params(cfg, seed=0):
+    return moe_mod.ffn_init(jax.random.PRNGKey(seed), cfg)
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """Four chips' shares (4 of 16 experts each, offsets 0, 4, 8, 12) add
+    up to the layer that holds all 16."""
+    whole_cfg = _expert_cfg(16)
+    p = _expert_params(whole_cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, whole_cfg.d_model))
+    whole, _ = moe_mod.moe_ffn(p, x, whole_cfg)
+    share_cfg = _expert_cfg(4)
+    parts = []
+    for off in (0, 4, 8, 12):
+        sl = {k: (v if k == "router" else v[off:off + 4])
+              for k, v in p.items()}
+        parts.append(moe_mod.moe_ffn(sl, x, share_cfg, offset=off)[0])
+    # each share alone is a real part of the layer, not all of it
+    assert all(float(jnp.abs(y - whole).max()) > 1e-3 for y in parts)
+    np.testing.assert_allclose(np.asarray(sum(parts)), np.asarray(whole),
+                               atol=1e-5, rtol=1e-5)
+
+
+def _explicit_mixture(p, x2d, cfg):
+    """Per token: softmax over the router, top-k, renormalised, each
+    picked expert's SwiGLU times its weight."""
+    probs = jax.nn.softmax(x2d @ p["router"], -1)
+    w, idx = jax.lax.top_k(probs, cfg.moe.top_k)
+    w = w / w.sum(-1, keepdims=True)
+    out = np.zeros(x2d.shape, np.float32)
+    for i in range(x2d.shape[0]):
+        for j in range(cfg.moe.top_k):
+            e = int(idx[i, j])
+            if e < p["up"].shape[0]:
+                h = jax.nn.silu(x2d[i] @ p["gate"][e]) * (x2d[i] @ p["up"][e])
+                out[i] += float(w[i, j]) * np.asarray(h @ p["down"][e])
+    return out
+
+
+def test_dropless_serves_every_token_on_one_expert():
+    """Every token picks the same experts: the capacity dispatch of a
+    training forward drops most of them, the serving path none."""
+    cfg = _expert_cfg(4, width=4, top_k=2).replace(
+        moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=32,
+                      capacity_factor=1.25))
+    p = _expert_params(cfg)
+    # a router that sends every token to experts 0 and then 1
+    p = dict(p, router=jnp.zeros_like(p["router"]).at[:, 0].set(1.0)
+             .at[:, 1].set(0.5))
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(2),
+                                  (24, cfg.d_model)))
+    served, _ = moe_mod.moe_ffn(p, x, cfg)
+    np.testing.assert_allclose(np.asarray(served),
+                               _explicit_mixture(p, x, cfg),
+                               atol=1e-5, rtol=1e-5)
+    # capacity ceil(24 * 2 / 4 * 1.25) = 15 rows an expert: the last 9
+    # tokens reach neither of their experts
+    dropped, _ = moe_mod.moe_ffn(p, x, cfg, train=True)
+    assert (np.abs(np.asarray(dropped)).sum(-1) == 0).sum() == 9
+
+
+def test_padded_rows_join_no_expert():
+    """Rows marked invalid come out 0 and change no real row, whatever
+    they hold."""
+    cfg = _expert_cfg(4)
+    p = _expert_params(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(3), (3, 5, cfg.d_model))
+    valid = jnp.arange(5)[None, :] < jnp.asarray([5, 2, 0])[:, None]
+    y, _ = moe_mod.moe_ffn(p, x, cfg, valid=valid)
+    junk = jnp.where(valid[..., None], x, 1e4)
+    y2, _ = moe_mod.moe_ffn(p, junk, cfg, valid=valid)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y2))
+    assert float(jnp.abs(jnp.where(valid[..., None], 0.0, y)).max()) == 0.0
+    want = _explicit_mixture(p, x.reshape(-1, cfg.d_model), cfg)
+    got = np.asarray(y).reshape(-1, cfg.d_model)
+    rows = np.asarray(valid).reshape(-1)
+    np.testing.assert_allclose(got[rows], want[rows], atol=1e-5, rtol=1e-5)

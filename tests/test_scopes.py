@@ -165,3 +165,44 @@ def test_cached_program_keeps_its_own_scopes(tmp_path, monkeypatch):
         for k, v in prev.items():
             jax.config.update(k, v)
         compilation_cache.reset_cache()
+
+
+NEW_SCOPES = ("moe.router", "moe.dispatch", "moe.experts", "moe.combine",
+              "attn.window", "attn.full")
+
+
+def _serve_step_text(arch):
+    """The batched serve step's compiled HLO at SMOKE size, width 4."""
+    cfg = get_config(arch, smoke=True)
+    params = jax.eval_shape(lambda: registry.init(cfg, jax.random.PRNGKey(0)))
+    headroom = 0 if cfg.attn_type == "full" else 3
+    cache = jax.eval_shape(lambda: registry.init_cache(
+        cfg, 3, 32, chunk_headroom=headroom))
+    tok = jax.ShapeDtypeStruct((3, 4), jnp.int32)
+    vec = jax.ShapeDtypeStruct((3,), jnp.int32)
+    return jax.jit(steps.build_batched_serve_step(cfg)).lower(
+        params, cache, tok, vec, vec).compile().as_text()
+
+
+def _without_metadata(text):
+    import re
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return "\n".join(l for l in text.splitlines()
+                     if l.startswith(("  ", "ENTRY", "%", "ROOT", "}")))
+
+
+def test_expert_and_attention_scopes_in_the_serve_step():
+    """mellum2's serve step names its expert layer's four phases and both
+    kinds of attention layer; the spiking LM's step carries none of
+    them, and its program is the same with every scope turned off."""
+    names = set(hlo_scopes(_serve_step_text("mellum2-12b-a2.5b")).values())
+    for scope in NEW_SCOPES:
+        assert any(scope in _parts(n) for n in names), scope
+    # the grouped matmuls sit in moe.experts, inside the layer loop
+    assert any("moe.experts" in _parts(n) and "while" in n for n in names)
+    text = _serve_step_text("spikingformer-lm")
+    sflm = set(hlo_scopes(text).values())
+    assert not [n for n in sflm if _has(n, *NEW_SCOPES)]
+    with E.disable_annotations():
+        bare = _serve_step_text("spikingformer-lm")
+    assert _without_metadata(bare) == _without_metadata(text)

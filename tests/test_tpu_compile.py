@@ -347,3 +347,59 @@ def test_eval_step_lif_kernels_add_no_relayout(eval_steps):
     one_pass = relayouts("one_pass", "custom-call")
     scan = relayouts("scan", "while")
     assert len(one_pass) <= len(scan), (one_pass, scan)
+
+
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)])
+def test_grouped_matmul_lowers_at_mellum_shapes(one_chip, monkeypatch, k,
+                                                n):
+    """The expert layer's grouped matmul (megablox ``gmm`` at
+    ``moe.GMM_TILE``) at mellum2.chat's widest wave: 1,024 rows x 8 picks
+    into 8 held experts, gate/up (2304 -> 896) and down (896 -> 2304),
+    whose K and N the tile does not divide."""
+    from repro.models import moe
+    # the chip's branch: the kernel runs interpreted off the TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compile(moe.grouped_matmul, one_chip,
+                    ((8192, k), jnp.bfloat16), ((8, k, n), jnp.bfloat16),
+                    ((8,), jnp.int32))
+    assert "gmm" in text
+
+
+def test_mellum_serve_step_fits_one_chip(one_chip, monkeypatch):
+    """The mellum2.chat serve step at the cell's shape (64 slots of 4096
+    positions, a width-16 wave, 8 held experts of each of 28 layers)
+    fits a v5e's 16 GB: the weights and the cache are its arguments, the
+    cache is updated in place (aliased to the output), and no copy of
+    the whole cache is made (temporaries well under one layer group's
+    cache; a (B, S, KH, hd) cache carried through the layer scan was
+    relaid out whole, 6.2 GB more). The grouped expert matmuls lower to
+    Mosaic kernels, three a layer."""
+    cfg = get_config("mellum2-12b-a2.5b")
+    slots, width = 64, 16
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def put(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+    params = put(jax.eval_shape(lambda: registry.init(
+        cfg, jax.random.PRNGKey(0))))
+    cache = put(jax.eval_shape(lambda: registry.init_cache(
+        cfg, slots, 4096, chunk_headroom=width - 1)))
+    tok = jax.ShapeDtypeStruct((slots, width), jnp.int32, sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(steps.build_batched_serve_step(cfg),
+                       donate_argnums=(1,)).lower(
+        params, cache, tok, vec, vec).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes     # tags padded
+    assert mem.temp_size_in_bytes < 1.0e9, mem
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert used < 15.0e9, mem
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 3 * cfg.global_every, len(calls)
