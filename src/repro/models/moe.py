@@ -14,7 +14,8 @@ Expert parallelism strategy (DESIGN.md §6): tokens are batch-sharded over
 of its tokens, then a psum over 'model' combines contributions. No
 all-to-all, no one-hot dispatch matmuls (which are FLOP-hostile at 384
 experts). Two dispatches: **dropless** (argsort by expert id → gather →
-grouped matmul over the groups' real sizes → scatter-add), which every
+grouped matmul over the groups' real sizes → gather through the sort's
+inverse and a weighted sum over each row's K picks), which every
 serving path takes, and **sort-based capacity dispatch** (capacity-bounded
 gather → batched expert matmul → scatter-add), which a training forward
 takes where ``capacity_factor`` is set.
@@ -251,9 +252,10 @@ def _dispatch_dropless(x2d, w, idx, up, gate, down, act: str, offset,
     x2d (T, D); w/idx (T, K) renormalised weights and global expert ids;
     expert weights (E_held, ...) for ids [offset, offset + E_held);
     valid (T,) bool: a row where it is False (padding) joins no group.
-    The pairs are sorted by expert, the SwiGLU runs as grouped matmuls
-    over the groups' real sizes, and each output, times its weight, is
-    added back to its row.
+    The pairs are sorted by expert and the SwiGLU runs as grouped matmuls
+    over the groups' real sizes. Each row then gathers its K outputs
+    through the inverse of the sort (no scatter: a row's pairs collide)
+    and sums them, each times its weight, in float32.
     """
     t, d = x2d.shape
     k = idx.shape[-1]
@@ -268,9 +270,13 @@ def _dispatch_dropless(x2d, w, idx, up, gate, down, act: str, offset,
         held = (local >= 0) & (local < e_held) & valid[:, None]
         key = jnp.where(held, local, e_held).reshape(-1)      # (T*K,)
         key = jnp.pad(key, (0, max(0, m - t * k)), constant_values=e_held)
-        order = jnp.argsort(key, stable=True)[:m]
-        sizes = jnp.bincount(key, length=e_held + 1)[:e_held].astype(
-            jnp.int32)
+        perm = jnp.argsort(key, stable=True)
+        # inv[t * K + j]: the place in the sorted order of row t's j-th pick
+        inv = jnp.argsort(perm)[:t * k]
+        order = perm[:m]
+        # a compare and a sum: bincount would scatter, each add colliding
+        sizes = jnp.sum(key[:, None] == jnp.arange(e_held), axis=0,
+                        dtype=jnp.int32)
         row = jnp.minimum(order // k, t - 1)   # pad pairs: any real row
         real = key[order] < e_held
         # rows outside every group are zero: the kernel leaves those rows
@@ -281,10 +287,12 @@ def _dispatch_dropless(x2d, w, idx, up, gate, down, act: str, offset,
             * grouped_matmul(xs, up, sizes)
         ys = grouped_matmul(h, down, sizes)
     with annotate("moe.combine"):
-        weight = jnp.take(w.reshape(-1), order, mode="fill", fill_value=0)
-        ys = jnp.where(real[:, None], ys.astype(jnp.float32), 0.0)
-        out = jnp.zeros((t, d), jnp.float32).at[row].add(
-            ys * weight[:, None])
+        # a held pair sorts before sizes.sum(); every other pair points
+        # past ys and reads zeros, so no undefined row of ys is read
+        at = jnp.where(held.reshape(-1), inv, m)
+        yk = jnp.take(ys, at, axis=0, mode="fill", fill_value=0)
+        out = jnp.sum(yk.reshape(t, k, d).astype(jnp.float32)
+                      * w[..., None], axis=1)
     return out.astype(x2d.dtype)
 
 

@@ -236,3 +236,27 @@ def test_padded_rows_join_no_expert():
     got = np.asarray(y).reshape(-1, cfg.d_model)
     rows = np.asarray(valid).reshape(-1)
     np.testing.assert_allclose(got[rows], want[rows], atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("held,top_k", [(8, 8), (4, 8), (16, 4)])
+def test_combine_reads_no_row_past_the_groups(monkeypatch, held, top_k):
+    """The grouped matmul leaves its rows past ``sizes.sum()`` undefined:
+    made NaN here, they reach no output, padded rows included."""
+    cfg = _expert_cfg(held, top_k=top_k)
+    p = _expert_params(cfg)
+    gmm = moe_mod.grouped_matmul
+
+    def poisoned(lhs, rhs, sizes):
+        out = gmm(lhs, rhs, sizes)
+        past = jnp.arange(out.shape[0])[:, None] >= sizes.sum()
+        return jnp.where(past, jnp.nan, out)
+    monkeypatch.setattr(moe_mod, "grouped_matmul", poisoned)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 7, cfg.d_model))
+    valid = jnp.arange(7)[None, :] < jnp.asarray([7, 3])[:, None]
+    y, _ = moe_mod.moe_ffn(p, x, cfg, valid=valid)
+    got = np.asarray(y).reshape(-1, cfg.d_model)
+    rows = np.asarray(valid).reshape(-1)
+    assert np.isfinite(got).all()
+    assert (got[~rows] == 0).all()
+    want = _explicit_mixture(p, x.reshape(-1, cfg.d_model), cfg)
+    np.testing.assert_allclose(got[rows], want[rows], atol=1e-5, rtol=1e-5)

@@ -365,35 +365,44 @@ def test_grouped_matmul_lowers_at_mellum_shapes(one_chip, monkeypatch, k,
     assert "gmm" in text
 
 
-def test_mellum_serve_step_fits_one_chip(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def mellum_serve_step(one_chip):
     """The mellum2.chat serve step at the cell's shape (64 slots of 4096
-    positions, a width-16 wave, 8 held experts of each of 28 layers)
-    fits a v5e's 16 GB: the weights and the cache are its arguments, the
-    cache is updated in place (aliased to the output), and no copy of
-    the whole cache is made (temporaries well under one layer group's
-    cache; a (B, S, KH, hd) cache carried through the layer scan was
-    relaid out whole, 6.2 GB more). The grouped expert matmuls lower to
-    Mosaic kernels, three a layer."""
+    positions, a width-16 wave, 8 held experts of each of 28 layers),
+    compiled once: (config, its cache's bytes, the compiled step)."""
     cfg = get_config("mellum2-12b-a2.5b")
     slots, width = 64, 16
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def put(tree):
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), tree)
-    params = put(jax.eval_shape(lambda: registry.init(
-        cfg, jax.random.PRNGKey(0))))
-    cache = put(jax.eval_shape(lambda: registry.init_cache(
-        cfg, slots, 4096, chunk_headroom=width - 1)))
-    tok = jax.ShapeDtypeStruct((slots, width), jnp.int32, sharding=one_chip)
-    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(steps.build_batched_serve_step(cfg),
-                       donate_argnums=(1,)).lower(
-        params, cache, tok, vec, vec).compile()
-    mem = compiled.memory_analysis()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        params = put(jax.eval_shape(lambda: registry.init(
+            cfg, jax.random.PRNGKey(0))))
+        cache = put(jax.eval_shape(lambda: registry.init_cache(
+            cfg, slots, 4096, chunk_headroom=width - 1)))
+        tok = jax.ShapeDtypeStruct((slots, width), jnp.int32,
+                                   sharding=one_chip)
+        vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(steps.build_batched_serve_step(cfg),
+                           donate_argnums=(1,)).lower(
+            params, cache, tok, vec, vec).compile()
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree_util.tree_leaves(cache))
+    return cfg, cache_bytes, compiled
+
+
+def test_mellum_serve_step_fits_one_chip(mellum_serve_step):
+    """The serve step fits a v5e's 16 GB: the weights and the cache are
+    its arguments, the cache is updated in place (aliased to the output),
+    and no copy of the whole cache is made (temporaries well under one
+    layer group's cache; a (B, S, KH, hd) cache carried through the layer
+    scan was relaid out whole, 6.2 GB more). The grouped expert matmuls
+    lower to Mosaic kernels, three a layer."""
+    cfg, cache_bytes, compiled = mellum_serve_step
+    mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cache_bytes     # tags padded
     assert mem.temp_size_in_bytes < 1.0e9, mem
     used = mem.argument_size_in_bytes + mem.output_size_in_bytes \
@@ -403,3 +412,21 @@ def test_mellum_serve_step_fits_one_chip(one_chip, monkeypatch):
     calls = [l for l in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in l]
     assert len(calls) == 3 * cfg.global_every, len(calls)
+
+
+def test_mellum_expert_layer_scatters_nothing(mellum_serve_step):
+    """The expert layer combines each row's outputs with a gather through
+    the inverse of its sort, and counts its groups: no scatter (which the
+    TPU serialises where indices repeat, as a row's K pairs do) runs under
+    a ``moe.`` scope, as an instruction or as a fusion the device trace
+    names by one. Left aside: megablox ``gmm``'s own tile table, built
+    from the eight group sizes under ``jit(gmm)``."""
+    ins = _instructions(mellum_serve_step[2].as_text())
+    moe_ops = {op for op, _, _, name in ins.values()
+               if _has_scope(name, "moe.")}
+    assert "gather" in moe_ops, moe_ops
+    scatters = [n for n, (op, _, _, name) in ins.items()
+                if _has_scope(name, "moe.") and "jit(gmm)" not in name
+                and (op == "scatter"
+                     or name.rsplit("/", 1)[-1].startswith("scatter"))]
+    assert not scatters, scatters
