@@ -34,8 +34,7 @@ cross-validation of
 ``sim/balance_sim.predicted_schedule`` (Binomial occupancies from the
 generator's density model) against the measured tensor schedule
 (``kernels/spike_decode.build_schedule``) — ``sched_agreement`` is
-predicted/measured executed steps. ``auto_choice`` is what
-``sparse='auto'`` would pick from the concrete histogram.
+predicted/measured executed steps.
 
 Binary engine (``attention_rows``): the three SSA execution targets of
 ``core.engine.resolve_binary_mode`` — pure jnp, the fused MXU Pallas
@@ -51,36 +50,29 @@ The measured medians also feed the overlap model: ``derived
 (sparse_us, mxu_us) — the Fig. 5 latency-hiding fraction from measured
 engine timings instead of the analytic MAC model.
 
-Fused layer step (``fused_rows``): the ``overlap='fused'`` bundle
-(``kernels/fused_ssa``) on the three spikingformer-shaped SSA
-workloads. Each row feeds the kernel's per-head executed-step counts to
-``core.dual_engine.fused_step_metrics``, so ``hidden_fraction`` here is
-*measured* — derived from the dots the kernel actually ran (dark spike
-slabs skipped, attention pipelined behind the next head's projections)
-with exact per-dot MAC weights — not the analytic model. Those counts
-are deterministic for the fixed PRNG inputs, so CI gates them
-(``benchmarks/check_regression.py``); ``fused_us``/``sequential_us``
-are interpret-mode wall clock on CPU and stay informative-only.
-
 Layer-program step (``layer_rows``): the whole encoder layer — SSA
 bundle + output projection + spiking MLP — as one engine step
 (``kernels/fused_layer`` behind ``core.engine.layer_step``), swept over
-``overlap in {off, fused, pipeline}`` x ``sparse in {tile, decoded}``
-on the same three spikingformer workloads. Each row feeds the kernel's
-``(H, 8, n_l_blocks)`` occupancy map to ``fused_step_metrics``:
-``hidden_fraction`` here is the *binary-hidden* fraction — the share of
-the binary engine's executed attention MACs that ride under sparse-
-engine busy time in the measured schedule. The layer program's MLP
-phases keep the sparse engine saturated past the SSA bundle's horizon,
-which is exactly why it beats the bundle-only ``fused_rows`` number —
-the CI floor on the token config (``check_regression.FLOORS``) pins
-that claim. Rows also cross-validate ``sim/balance_sim
-.binary_block_schedule`` (the numpy twin of the binary-phase occupancy
-predicate) sub-block-exact against the measured counts.
+``overlap in {off, fused}`` x ``sparse in {tile, decoded}`` on three
+spikingformer-shaped workloads. Each row feeds the kernel's
+``(H, 8, n_l_blocks)`` occupancy map to
+``core.dual_engine.fused_step_metrics``: ``hidden_fraction`` here is
+the *binary-hidden* fraction — the share of the binary engine's
+executed attention MACs that ride under sparse-engine busy time in the
+measured schedule, derived from the sub-blocks the kernel actually ran,
+not from the analytic model. The layer program's MLP phases keep the
+sparse engine saturated past the SSA bundle's horizon; the CI floor on
+the token config (``check_regression.FLOORS``) pins that claim. The
+counts are deterministic for the fixed PRNG inputs, so CI gates them
+(``benchmarks/check_regression.py``); ``layer_us``/``sequential_us``
+are interpret-mode wall clock on CPU and stay informative-only. Rows
+also cross-validate ``sim/balance_sim.binary_block_schedule`` (the
+numpy twin of the binary-phase occupancy predicate) sub-block-exact
+against the measured counts.
 
 Output: ``artifacts/dual_engine_bench.json`` in the benchmark harness's
 ``{"rows": [...], "attention_rows": [...], "sparse_path_rows": [...],
-"fused_rows": [...], "layer_rows": [...], "derived": {...}}`` format
+"layer_rows": [...], "derived": {...}}`` format
 (also wired into ``benchmarks/run.py``, which re-emits the same file).
 
 Usage: PYTHONPATH=src python benchmarks/dual_engine_bench.py [--fast]
@@ -152,17 +144,17 @@ SPARSE_PATTERNS = [
 SPARSE_PATH_SHAPES = [(512, 256, 256), (1024, 256, 512)]
 SPARSE_PATH_BLOCK = 64  # block_m/block_n; block_k doubles as c_block
 
-# fused-step workloads: (name, family, T, B, L, D, heads, head_dim,
+# layer-program workloads: (name, family, T, B, L, D, heads, head_dim,
 # causal) — the SSA shapes of the three spikingformer configs. The two
 # vision points are projection-dominated (3D >> 2L: little to hide);
-# the token point has L == D, where attention is 2/5 of the serial work
-# and the head pipeline hides most of it (hidden_fraction ~ 0.4).
-FUSED_CONFIGS = [
+# the token point has L == D, where attention is 2/5 of the bundle's
+# serial work.
+LAYER_CONFIGS = [
     ("spikingformer-4-256", "bn", 4, 2, 64, 256, 8, 32, False),
     ("spikingformer-8-512", "bn", 4, 1, 64, 512, 8, 64, False),
     ("spikingformer-lm", "rope", 4, 1, 256, 256, 4, 64, True),
 ]
-FUSED_DENSITY = 0.25
+LAYER_DENSITY = 0.25
 
 # layer-program sweep (layer_rows): block sizes for the whole-layer
 # occupancy map and the decoded projection path; d_ff = 4 * d_model
@@ -171,8 +163,7 @@ FUSED_DENSITY = 0.25
 # family — the token family's ln1-normed currents are dense.
 LAYER_L_BLOCK = 32
 LAYER_C_BLOCK = 64
-LAYER_MODES = [("off", "tile"), ("fused", "tile"), ("fused", "decoded"),
-               ("pipeline", "tile"), ("pipeline", "decoded")]
+LAYER_MODES = [("off", "tile"), ("fused", "tile"), ("fused", "decoded")]
 
 
 def _time(fn, *args) -> float:
@@ -228,7 +219,6 @@ def sparse_path_bench(fast: bool = False):
 
     from repro.core import engine as E
     from repro.kernels.spike_decode import (build_schedule,
-                                            choose_sparse_path,
                                             decoded_dot_fraction)
     from repro.kernels.spike_matmul import block_occupancy
     from repro.sim.balance_sim import predicted_schedule
@@ -282,83 +272,7 @@ def sparse_path_bench(fast: bool = False):
                 "sched_agreement": round(
                     int(pred["executed"]) / max(1, int(meas["executed"])),
                     3),
-                "auto_choice": choose_sparse_path(s, block, block),
             })
-    return rows
-
-
-def fused_bench(fast: bool = False):
-    """Fused SSA layer step on the spikingformer-shaped workloads: the
-    kernel's executed-step counts -> measured Fig. 5 schedule. All three
-    configs run even under ``--fast`` — the counts are what CI gates,
-    and the token config is the one whose measured hidden fraction
-    demonstrates the overlap (the sweep is three kernel calls, cheap
-    even in interpret mode)."""
-    del fast
-    from repro.core import dual_engine as de
-    from repro.core.spiking import SpikingConfig
-    from repro.kernels.fused_ssa import fused_ssa, reference_bundle
-
-    scfg = SpikingConfig()
-    delta = 0.3
-    rows = []
-    for name, fam, t, b, l, d, heads, hd, causal in FUSED_CONFIGS:
-        q_dim = heads * hd
-        # deterministic across processes (str hash() is salted)
-        key = jax.random.PRNGKey(t + b + l + d + sum(map(ord, name)))
-        kx, kw, ka = jax.random.split(key, 3)
-        x = (jax.random.uniform(kx, (t, b, l, d)) < FUSED_DENSITY
-             ).astype(jnp.float32)
-        # silent warm-up: LIF membranes start discharged, so the first
-        # timestep of a sequence often fires nothing — model it with one
-        # all-dark (t=0, b=0) slab the occupancy skip can measurably elide
-        x = x.at[0, 0].set(0.0)
-        w3 = jax.random.normal(kw, (3, d, q_dim), jnp.float32) * d ** -0.5
-        if fam == "bn":
-            sc, bi = jax.random.split(ka)
-            aux = jnp.stack([
-                jnp.zeros((q_dim,)), jnp.ones((q_dim,)),
-                1.0 + 0.1 * jax.random.normal(sc, (q_dim,)),
-                0.1 * jax.random.normal(bi, (q_dim,))])
-            aux = jnp.broadcast_to(aux, (3, 4, q_dim))
-        else:  # rope: cos/sin table for positions 0..L-1 (theta 1e4)
-            half = hd // 2
-            freqs = 10000.0 ** (-jnp.arange(0, half, dtype=jnp.float32)
-                                / half)
-            ang = jnp.arange(l, dtype=jnp.float32)[:, None] * freqs
-            aux = jnp.stack([jnp.cos(ang), jnp.sin(ang)])
-        kw_args = dict(family=fam, num_heads=heads, head_dim=hd,
-                       scale=hd ** -0.5, causal=causal)
-
-        def fused_call(x, w3=w3, aux=aux, kw_args=kw_args):
-            return fused_ssa(x, w3, None, aux, delta, **kw_args)[0]
-
-        def seq_call(x, w3=w3, aux=aux, kw_args=kw_args):
-            return reference_bundle(x, w3, None, aux, delta, scfg,
-                                    **kw_args)
-        fused_us = _time(jax.jit(fused_call), x)
-        seq_us = _time(jax.jit(seq_call), x)
-        _, counts = fused_ssa(x, w3, None, aux, delta, **kw_args)
-        m = de.fused_step_metrics(counts, seq=l, k_dim=d, head_dim=hd,
-                                  t_steps=t, batch=b)
-        rows.append({
-            "bench": "fused", "config": name, "family": fam,
-            "shape": [t, b, l, d, heads, hd], "causal": causal,
-            "fused_us": round(fused_us, 1),
-            "sequential_us": round(seq_us, 1),
-            # interpret-mode emulation on CPU — informative, never gated
-            "wall_ratio": round(seq_us / fused_us, 3),
-            "hidden_fraction": round(m["hidden_fraction"], 4),
-            "sparse_util": round(m["sparse_util"], 4),
-            "binary_util": round(m["binary_util"], 4),
-            "executed_q": m["executed_q"], "executed_k": m["executed_k"],
-            "executed_v": m["executed_v"],
-            "executed_attn": m["executed_attn"],
-            "possible_steps": m["possible_steps"],
-            "executed_steps": m["executed_steps"],
-            "step_reduction": round(m["step_reduction"], 4),
-            "proj_skip_fraction": round(m["proj_skip_fraction"], 4),
-        })
     return rows
 
 
@@ -374,12 +288,13 @@ def _dyadic(key, shape, sc):
 def _layer_workload(name, fam, t, b, l, d, heads, hd):
     """Whole-layer operands in the raw kernel layout (the same tensors
     ``core.engine.layer_step`` hands ``kernels/fused_layer``), fp-native
-    (scales=None), with the fused_bench dark (t=0, b=0) slab."""
+    (scales=None), with one all-dark (t=0, b=0) slab: LIF membranes
+    start discharged, so the first timestep often fires nothing."""
     from repro.core.spiking import SpikingConfig, lif_scan
     q_dim, ff = heads * hd, 4 * d
     key = jax.random.PRNGKey(t + b + l + d + sum(map(ord, name)) + 7)
     kx, kw, ka, k1, k2, ko = jax.random.split(key, 6)
-    x = (jax.random.uniform(kx, (t, b, l, d)) < FUSED_DENSITY
+    x = (jax.random.uniform(kx, (t, b, l, d)) < LAYER_DENSITY
          ).astype(jnp.float32)
     x = x.at[0, 0].set(0.0)
     w3 = _dyadic(kw, (3, d, q_dim), d ** -0.5)
@@ -449,10 +364,10 @@ def _proj_kv_spikes(s, w3, auxp, fam, heads, hd):
 def layer_bench(fast: bool = False):
     """Layer-program step (``kernels/fused_layer`` via the engine's
     ``layer_step`` surface) on the three spikingformer-shaped whole-layer
-    workloads: off (sequential oracle) vs fused vs pipeline x tile vs
-    decoded. Counts-derived metrics are deterministic and CI-gated; wall
-    clock is interpret-mode emulation, informative only. Each fused /
-    pipeline row also cross-validates ``sim/balance_sim
+    workloads: off (sequential oracle) vs fused x tile vs decoded.
+    Counts-derived metrics are deterministic and CI-gated; wall clock is
+    interpret-mode emulation, informative only. Each fused row also
+    cross-validates ``sim/balance_sim
     .binary_block_schedule`` — the numpy twin of the kernel's
     binary-phase occupancy map — against the measured ``counts[:, 3:5]``
     (``sim_binary_agreement`` = predicted / measured executed binary
@@ -470,7 +385,7 @@ def layer_bench(fast: bool = False):
     scfg = SpikingConfig()
     delta = 0.3
     rows = []
-    for name, fam, t, b, l, d, heads, hd, causal in FUSED_CONFIGS:
+    for name, fam, t, b, l, d, heads, hd, causal in LAYER_CONFIGS:
         ops, ff = _layer_workload(name, fam, t, b, l, d, heads, hd)
         args = ops + (delta,)
         kw_args = dict(family=fam, num_heads=heads, head_dim=hd,
@@ -494,21 +409,19 @@ def layer_bench(fast: bool = False):
                                  wall_ratio=1.0, hidden_fraction=0.0,
                                  step_reduction=0.0))
                 continue
-            pipe = overlap == "pipeline"
 
-            def call(*a, sp=sparse, pi=pipe, k=kw_args):
-                return fused_layer(*a, sparse=sp, pipeline=pi,
-                                   l_block=LAYER_L_BLOCK,
+            def call(*a, sp=sparse, k=kw_args):
+                return fused_layer(*a, sparse=sp, l_block=LAYER_L_BLOCK,
                                    c_block=LAYER_C_BLOCK, **k)[0]
             layer_us = _time(jax.jit(call), *args)
-            _, counts = fused_layer(*args, sparse=sparse, pipeline=pipe,
+            _, counts = fused_layer(*args, sparse=sparse,
                                     l_block=LAYER_L_BLOCK,
                                     c_block=LAYER_C_BLOCK, **kw_args)
             meas = np.asarray(counts)[:, 3:5, :]
             m = de.fused_step_metrics(
                 counts, seq=l, k_dim=d, head_dim=hd, t_steps=t, batch=b,
                 d_model=d, d_ff=ff, l_block=LAYER_L_BLOCK, sparse=sparse,
-                c_block=LAYER_C_BLOCK, pipeline=pipe)
+                c_block=LAYER_C_BLOCK)
             rows.append(dict(
                 base, layer_us=round(layer_us, 1),
                 wall_ratio=round(seq_us / layer_us, 3),
@@ -517,7 +430,6 @@ def layer_bench(fast: bool = False):
                 qktv_hidden_fraction=round(m["qktv_hidden_fraction"], 4),
                 sparse_util=round(m["sparse_util"], 4),
                 binary_util=round(m["binary_util"], 4),
-                pipeline_iters=m["pipeline_iters"],
                 executed_steps=m["executed_steps"],
                 possible_steps=m["possible_steps"],
                 step_reduction=round(m["step_reduction"], 4),
@@ -569,7 +481,6 @@ def bench(fast: bool = False):
                 })
     attn_rows = attention_bench(fast=fast)
     sp_rows = sparse_path_bench(fast=fast)
-    fu_rows = fused_bench(fast=fast)
     la_rows = layer_bench(fast=fast)
     med = lambda xs: sorted(xs)[len(xs) // 2]
     sparse_med = med([r["sparse_us"] for r in rows])
@@ -595,8 +506,6 @@ def bench(fast: bool = False):
             r["decoded_modeled_speedup"] for r in sp_rows),
         "tile_skip_on_ragged_max": max(
             r["tile_skip_fraction"] for r in sp_rows),
-        "decoded_auto_wins": sum(
-            1 for r in sp_rows if r["auto_choice"] == "decoded"),
         "sched_agreement_median": med(
             [r["sched_agreement"] for r in sp_rows]),
         # Fig. 5 overlap model on measured engine medians (us events)
@@ -608,20 +517,9 @@ def bench(fast: bool = False):
             "hidden_fraction": round(
                 measured_overlap_efficiency(sparse_med, mxu_med), 4),
         },
-        # fused layer step: hidden fraction measured from the kernel's
-        # own executed-step counts (per-row detail in fused_rows)
-        "fused_overlap": {
-            "points": len(fu_rows),
-            "max_hidden_fraction": max(
-                r["hidden_fraction"] for r in fu_rows),
-            "best_config": max(fu_rows,
-                               key=lambda r: r["hidden_fraction"])
-            ["config"],
-        },
         # layer-program step: the whole-layer occupancy map's measured
         # binary-hidden fraction (per-row detail in layer_rows); the
-        # token config's fused/tile row carries the CI floor vs the
-        # SSA-only bundle's hidden fraction
+        # token config's fused/tile row carries the CI floor
         "layer_overlap": {
             "points": len(la_rows),
             "token_hidden_fraction": next(
@@ -636,22 +534,21 @@ def bench(fast: bool = False):
                 if r["overlap"] != "off"),
         },
     }
-    return rows + attn_rows + sp_rows + fu_rows + la_rows, derived
+    return rows + attn_rows + sp_rows + la_rows, derived
 
 
 def to_blob(rows, derived):
     """Split the tagged row list into the artifact layout
     ({'rows': linear, 'attention_rows': attention, 'sparse_path_rows':
-    tile-vs-decoded, 'fused_rows': fused SSA bundle, 'layer_rows':
-    whole-layer program, 'derived': ...})."""
+    tile-vs-decoded, 'layer_rows': whole-layer program, 'derived':
+    ...})."""
     return {"rows": [r for r in rows
                      if r.get("bench") not in ("attention", "sparse_path",
-                                               "fused", "layer")],
+                                               "layer")],
             "attention_rows": [r for r in rows
                                if r.get("bench") == "attention"],
             "sparse_path_rows": [r for r in rows
                                  if r.get("bench") == "sparse_path"],
-            "fused_rows": [r for r in rows if r.get("bench") == "fused"],
             "layer_rows": [r for r in rows if r.get("bench") == "layer"],
             "derived": derived}
 
@@ -680,20 +577,11 @@ def main():
               f"{r['jnp_us']},{r['mxu_us']},{r['popcount_us']},"
               f"{r['mxu_vs_jnp']},{r['popcount_vs_mxu']}")
     print("pattern,shape,tile_skip,decoded_mac_reduction,"
-          "decoded_modeled_speedup,sched_agreement,auto")
+          "decoded_modeled_speedup,sched_agreement")
     for r in blob["sparse_path_rows"]:
         print(f"{r['pattern']},{'x'.join(map(str, r['shape']))},"
               f"{r['tile_skip_fraction']},{r['decoded_mac_reduction']},"
-              f"{r['decoded_modeled_speedup']},{r['sched_agreement']},"
-              f"{r['auto_choice']}")
-    print("config,shape,hidden_fraction,sparse_util,binary_util,"
-          "step_reduction,proj_skip_fraction,fused_us,sequential_us")
-    for r in blob["fused_rows"]:
-        print(f"{r['config']},{'x'.join(map(str, r['shape']))},"
-              f"{r['hidden_fraction']},{r['sparse_util']},"
-              f"{r['binary_util']},{r['step_reduction']},"
-              f"{r['proj_skip_fraction']},{r['fused_us']},"
-              f"{r['sequential_us']}")
+              f"{r['decoded_modeled_speedup']},{r['sched_agreement']}")
     print("config,overlap,sparse,hidden_fraction,step_reduction,"
           "sim_binary_agreement,layer_us,sequential_us")
     for r in blob["layer_rows"]:

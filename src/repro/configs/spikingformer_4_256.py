@@ -17,11 +17,10 @@ CONFIG = ModelConfig(
     # the binary engine (binary='auto' picks the fused MXU kernel once
     # the attention volume clears the same flop floor). The floor keeps
     # CPU smoke shapes on the plain XLA paths (engine dispatch is still
-    # exercised — it just resolves dense/jnp there). sparse='auto' lets
-    # eager (non-jit) sparse calls pick the decoded datapath from the
-    # occupancy histogram when its sort leaves whole row groups dark
-    # that the tile map cannot skip (DESIGN.md §9).
-    engine=EngineConfig(mode="auto", sparse="auto", overlap="auto"),
+    # exercised — it just resolves dense/jnp there). The layer program
+    # runs its sequential oracle (overlap 'off') and sparse matmuls the
+    # tile datapath, the engine's defaults.
+    engine=EngineConfig(mode="auto"),
 )
 
 SMOKE = CONFIG.replace(

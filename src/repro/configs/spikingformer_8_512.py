@@ -13,7 +13,7 @@ CONFIG = ModelConfig(
     spiking=SpikingConfig(time_steps=4),
     # auto on both engines: sparse matmuls + MXU-kernel SSA at the 196-
     # token ImageNet shape (see spikingformer_4_256 for the dispatch note)
-    engine=EngineConfig(mode="auto", sparse="auto", overlap="auto"),
+    engine=EngineConfig(mode="auto"),
 )
 
 SMOKE = CONFIG.replace(

@@ -20,7 +20,7 @@ CONFIG = ModelConfig(
     spiking=SpikingConfig(time_steps=4),
     # binary='auto': full-size shapes clear the flop floor and run the
     # fused MXU kernel; packed_kv turns on the popcount decode cache.
-    engine=EngineConfig(mode="auto", sparse="auto", overlap="auto"),
+    engine=EngineConfig(mode="auto"),
 )
 
 # head_dim=16 deliberately non-word-sized: the packed KV cache pads the

@@ -3,17 +3,16 @@ Eq. 3/4) — analytic model *and* measurement consumer.
 
 FireFly-T overlaps the sparse engine (Q/K/V projections) with the binary
 engine (QK^T, QK^T V) across attention heads. This module holds the
-discrete-event model of that schedule (Fig. 5) and, since the fused
-dual-engine kernel landed (``kernels/fused_ssa.py``), the consumer that
-turns the kernel's *measured* per-phase executed-step counts into a
-hidden-fraction / utilization report (:func:`fused_step_metrics`). It is
-used by:
+discrete-event model of that schedule (Fig. 5) and the consumer that
+turns the fused layer kernel's (``kernels/fused_layer.py``) *measured*
+per-phase executed-step counts into a hidden-fraction / utilization
+report (:func:`fused_step_metrics`). It is used by:
 
 * ``benchmarks/paper_figures.py``        — the Fig. 5 spatial-temporal
   overlap diagram (``pipeline_schedule``),
 * ``benchmarks/dual_engine_bench.py``    — the measured-overlap rows
   (``measured_schedule`` on wall-clock medians; ``fused_step_metrics``
-  on the fused kernel's step counts),
+  on the fused layer kernel's step counts),
 * ``examples/dual_engine_walkthrough.py``— the Eq. 4 engine-sizing rule
   (``required_binary_parallelism``) used to pick ``P_B*`` for a network.
 
@@ -230,8 +229,7 @@ def _interval_overlap(binary_events: List[tuple],
     return total
 
 
-def layer_event_schedule(macs: Dict[str, List[float]], heads: int,
-                         iters: int = 1
+def layer_event_schedule(macs: Dict[str, List[float]], heads: int
                          ) -> Tuple[List[tuple], List[tuple]]:
     """Discrete-event schedule of the *layer program* (the fused-layer
     grid of ``kernels/fused_layer.py``): the sparse engine walks the
@@ -241,68 +239,66 @@ def layer_event_schedule(macs: Dict[str, List[float]], heads: int,
     (the context dependency). ``macs[phase][h]`` is the executed-MAC
     duration of that (phase, head) work item.
 
-    ``iters > 1`` models the pipeline grid's timestep wavefront: the
-    per-phase work splits evenly over ``iters`` chained iterations, so
-    iteration i+1's q/k/v tiles fill the sparse-engine stall windows
-    and overlap iteration i's binary tail — the reason the pipeline
-    mode's measured hidden fraction exceeds the fused grid's.
-
     Returns (sparse_events, binary_events) as (name, start, end) lists.
     """
     se: List[tuple] = []
     be: List[tuple] = []
     t_s = 0.0
     t_b = 0.0
-    frac = 1.0 / iters
-    for it in range(iters):
-        k_done: Dict[int, float] = {}
-        v_done: Dict[int, float] = {}
-        ctx_done: Dict[int, float] = {}
-        for ph in ("q", "k", "v"):
-            for h in range(heads):
-                dt = macs[ph][h] * frac
-                se.append((f"{ph}{h}@{it}", t_s, t_s + dt))
-                t_s += dt
-                if ph == "k":
-                    k_done[h] = t_s
-                elif ph == "v":
-                    v_done[h] = t_s
+    k_done: Dict[int, float] = {}
+    v_done: Dict[int, float] = {}
+    ctx_done: Dict[int, float] = {}
+    for ph in ("q", "k", "v"):
         for h in range(heads):
-            dt = macs["qkt"][h] * frac
-            start = max(t_b, k_done[h])
-            be.append((f"qkt{h}@{it}", start, start + dt))
-            t_b = start + dt
+            dt = macs[ph][h]
+            se.append((f"{ph}{h}", t_s, t_s + dt))
+            t_s += dt
+            if ph == "k":
+                k_done[h] = t_s
+            elif ph == "v":
+                v_done[h] = t_s
+    for h in range(heads):
+        dt = macs["qkt"][h]
+        start = max(t_b, k_done[h])
+        be.append((f"qkt{h}", start, start + dt))
+        t_b = start + dt
+    for h in range(heads):
+        dt = macs["qktv"][h]
+        start = max(t_b, v_done[h])
+        be.append((f"qktv{h}", start, start + dt))
+        t_b = start + dt
+        ctx_done[h] = t_b
+    for h in range(heads):
+        dt = macs["wo"][h]
+        start = max(t_s, ctx_done[h])
+        se.append((f"wo{h}", start, start + dt))
+        t_s = start + dt
+    for ph in ("up", "down"):
         for h in range(heads):
-            dt = macs["qktv"][h] * frac
-            start = max(t_b, v_done[h])
-            be.append((f"qktv{h}@{it}", start, start + dt))
-            t_b = start + dt
-            ctx_done[h] = t_b
-        for h in range(heads):
-            dt = macs["wo"][h] * frac
-            start = max(t_s, ctx_done[h])
-            se.append((f"wo{h}@{it}", start, start + dt))
-            t_s = start + dt
-        for ph in ("up", "down"):
-            for h in range(heads):
-                dt = macs[ph][h] * frac
-                se.append((f"{ph}{h}@{it}", t_s, t_s + dt))
-                t_s += dt
+            dt = macs[ph][h]
+            se.append((f"{ph}{h}", t_s, t_s + dt))
+            t_s += dt
     return se, be
 
 
-def _layer_step_metrics(counts, *, seq, k_dim, head_dim, t_steps, batch,
-                        d_model, d_ff, l_block, sparse, c_block,
-                        pipeline) -> Dict[str, float]:
-    """The occupancy-map consumer: per-(head, phase, L-block) executed
-    sub-block counts from the fused-layer kernel -> executed-MAC phase
+def fused_step_metrics(counts, *, seq: int, k_dim: int, head_dim: int,
+                       t_steps: int, batch: int, d_model: int, d_ff: int,
+                       l_block: int, sparse: str = "tile",
+                       c_block: int = None) -> Dict[str, float]:
+    """Measured overlap report from the fused layer kernel's
+    ``(H, 8, n_l_blocks)`` occupancy map (``kernels/fused_layer.
+    fused_layer``: executed sub-blocks per head, phase and L-block;
+    ``sparse='decoded'`` needs ``c_block``) -> executed-MAC phase
     durations -> layer event schedule -> *binary-hidden fraction* (the
     share of binary-engine busy time that runs under sparse-engine busy
-    time). Unlike the SSA-only makespan ratio, this is the quantity the
-    layer program actually improves: the MLP tail (wo/up/down) gives the
-    sparse engine work to run *under* the binary tail, and the pipeline
-    grid additionally folds the next timestep's q/k/v into the wo stall
-    windows."""
+    time). The MLP tail (wo/up/down) gives the sparse engine work to run
+    *under* the binary tail.
+
+    This is the "measured, not modeled" hidden fraction: op durations in
+    the Fig. 5 schedule are the *executed* MACs of each phase — a
+    sub-block the kernel skipped simply isn't there. Deterministic for a
+    fixed input, so CI gates it (benchmarks/check_regression.py).
+    """
     cnt = [[[int(c) for c in lbrow] for lbrow in row] for row in counts]
     heads = len(cnt)
     nlb = len(cnt[0][0])
@@ -319,8 +315,7 @@ def _layer_step_metrics(counts, *, seq, k_dim, head_dim, t_steps, batch,
                            for lb in range(nlb)) * unit[ph])
                  for h in range(heads)]
             for p, ph in enumerate(LAYER_PHASE_NAMES)}
-    iters = t_steps if pipeline else 1
-    se, be = layer_event_schedule(macs, heads, iters)
+    se, be = layer_event_schedule(macs, heads)
     sparse_busy = sum(e - s for _, s, e in se)
     binary_busy = sum(e - s for _, s, e in be)
     makespan = max([e for _, _, e in se + be], default=0.0)
@@ -345,7 +340,6 @@ def _layer_step_metrics(counts, *, seq, k_dim, head_dim, t_steps, batch,
         "heads": heads,
         "phases": len(LAYER_PHASE_NAMES),
         "l_blocks": nlb,
-        "pipeline_iters": iters,
         "executed_steps": tot_exec,
         "possible_steps": tot_poss,
         "step_reduction": 0.0 if tot_poss == 0
@@ -365,68 +359,6 @@ def _layer_step_metrics(counts, *, seq, k_dim, head_dim, t_steps, batch,
         else _interval_overlap(qktv_ev, se) / qktv_busy,
         **{f"executed_{ph}": executed[ph] for ph in LAYER_PHASE_NAMES},
     }
-
-
-def fused_step_metrics(counts, *, seq: int, k_dim: int, head_dim: int,
-                       t_steps: int, batch: int, d_model: int = None,
-                       d_ff: int = None, l_block: int = None,
-                       sparse: str = "tile", c_block: int = None,
-                       pipeline: bool = False) -> Dict[str, float]:
-    """Measured overlap report from the fused kernel's executed-step
-    counts — either the SSA bundle's ``(H, 4)`` int32 counts
-    (``kernels/fused_ssa.fused_ssa``: executed Q/K/V projection dots and
-    attention dots per head) or the layer program's ``(H, 8, n_l_blocks)``
-    occupancy map (``kernels/fused_layer.fused_layer``: executed
-    sub-blocks per head, phase and L-block — dispatched on the counts'
-    rank; the layer path needs ``d_model``/``d_ff``/``l_block`` and, for
-    ``sparse='decoded'``, ``c_block``).
-
-    This is the "measured, not modeled" hidden fraction: op durations in
-    the Fig. 5 schedule are the *executed* MACs of each phase — a
-    projection sub-step the kernel skipped (all-dark spike slab) simply
-    isn't there — with exact per-dot weights (projection dot = L*K*hd
-    MACs, attention dot = L*L*hd). Deterministic for a fixed input, so
-    CI gates it (benchmarks/check_regression.py).
-    """
-    ndim = counts.ndim if hasattr(counts, "ndim") else \
-        (3 if isinstance(counts[0][0], (list, tuple)) else 2)
-    if ndim == 3:
-        return _layer_step_metrics(
-            counts, seq=seq, k_dim=k_dim, head_dim=head_dim,
-            t_steps=t_steps, batch=batch, d_model=d_model, d_ff=d_ff,
-            l_block=l_block, sparse=sparse, c_block=c_block,
-            pipeline=pipeline)
-    rows = [[int(c) for c in row] for row in counts]
-    heads = len(rows)
-    w_proj = seq * k_dim * head_dim          # MACs per executed proj dot
-    w_attn = seq * seq * head_dim            # MACs per executed attn dot
-    sparse = [(r[0] * w_proj, r[1] * w_proj, r[2] * w_proj) for r in rows]
-    binary = [(r[3] // 2 * w_attn, (r[3] - r[3] // 2) * w_attn)
-              for r in rows]
-    m = schedule_metrics(sparse, binary, heads)
-    exec_q = sum(r[0] for r in rows)
-    exec_k = sum(r[1] for r in rows)
-    exec_v = sum(r[2] for r in rows)
-    exec_attn = sum(r[3] for r in rows)
-    possible_proj = 3 * t_steps * batch * heads
-    possible_attn = 2 * t_steps * batch * heads
-    executed = exec_q + exec_k + exec_v + exec_attn
-    possible = possible_proj + possible_attn
-    m.update({
-        "heads": heads,
-        "executed_q": exec_q, "executed_k": exec_k, "executed_v": exec_v,
-        "executed_attn": exec_attn,
-        "possible_steps": possible,
-        "executed_steps": executed,
-        # sequential baseline executes every sub-step back-to-back; the
-        # fused step both *skips* dark projection slabs and *hides*
-        # binary work behind sparse work — this is the skip half:
-        "step_reduction": 0.0 if possible == 0
-        else 1.0 - executed / possible,
-        "proj_skip_fraction": 0.0 if possible_proj == 0
-        else 1.0 - (exec_q + exec_k + exec_v) / possible_proj,
-    })
-    return m
 
 
 def pipeline_efficiency(w: AttentionWorkload, p: EngineParallelism,

@@ -11,7 +11,7 @@ anything whose input is a {0,1} spike tensor) routes through
   * ``dense``  — plain XLA dot, fp32 accumulation (the measurement
     baseline every perf PR compares against), and
   * ``sparse`` — one of two zero-skipping Pallas datapaths, selected by
-    ``EngineConfig.sparse`` (tile | decoded | auto, DESIGN.md §9):
+    ``EngineConfig.sparse`` (tile | decoded, DESIGN.md §9):
     the block-sparse ``spike_matmul`` kernel skips all-zero (block_m x
     block_k) spike tiles via the occupancy map, and the rank-decoded
     ``spike_decode`` kernel sorts rows by occupancy into pow2 buckets and
@@ -33,27 +33,23 @@ spiking_attention``, the transformer family's spiking SSA) consults
     AND-PopCount semantics and to quantify that the MXU form dominates
     on TPU (never chosen by ``auto``).
 
-Fused overlap — ``EngineConfig.overlap = off|fused|pipeline|auto`` lets
-an engine-owned step run as *one* Pallas grid in which the two engines
-execute interleaved per head — the paper's Fig. 5 latency-hiding
-schedule made structural instead of sequential-composition-plus-
-arithmetic-model. Two step surfaces exist: the SSA bundle
-(:func:`ssa_step` / :func:`ssa_step_causal` — Q/K/V projections +
-epilogues + binary attention, ``kernels/fused_ssa.py``) and the *layer
-program* (:func:`layer_step` / :func:`layer_step_causal` — the bundle
-plus output projection, residuals and the spiking MLP as one grid,
-``kernels/fused_layer.py``). The layer program's ``pipeline`` mode
-additionally walks the timestep axis as a grid dimension (the
-timestep/layer wavefront from ROADMAP), and :func:`resolve_layer_plan`
-folds the overlap mode and the sparse datapath into one static plan so
-``sparse='decoded'`` rides inside ``overlap='fused'|'pipeline'``.
+Layer program — :func:`layer_step` / :func:`layer_step_causal` own a
+whole encoder layer (the SSA bundle, output projection, residuals and
+the spiking MLP). ``EngineConfig.overlap`` (off | fused) and
+``EngineConfig.sparse`` (tile | decoded) are read as they are set, the
+same under jit as eagerly: ``fused`` runs an eligible eval layer as one
+Pallas grid (``kernels/fused_layer.py``) in which the two engines run
+interleaved per head, the paper's Fig. 5 schedule made structural, and
+``sparse='decoded'`` puts the compacted-chunk datapath inside it;
+``off`` runs the layer's sequential oracle (``reference_layer``).
 
-Dispatch is *static* (shape/config driven, resolved at trace time): jit
-can't branch on runtime density, so ``auto`` mode uses the flop volume as
-the proxy — tiny matmuls / tiny attention can't amortize kernel staging
-and stay on the XLA path. The engine is installed ambiently
-(thread-local, like sharding rules) by the step builders from
-``ModelConfig.engine``, so model code stays free of engine plumbing.
+The dense/sparse and binary-engine decisions (``mode``, ``binary``) stay
+*static* on shape (resolved at trace time): jit can't branch on runtime
+density, so their ``auto`` uses the flop volume as the proxy — tiny
+matmuls / tiny attention can't amortize kernel staging and stay on the
+XLA path. The engine is installed ambiently (thread-local, like
+sharding rules) by the step builders from ``ModelConfig.engine``, so
+model code stays free of engine plumbing.
 Off-TPU the kernels run in ``interpret`` mode — the bit-exact Python
 evaluation this container's tests validate against.
 
@@ -76,7 +72,7 @@ import jax.numpy as jnp
 from repro.core.scopes import annotate, disable_annotations  # noqa: F401
 
 SPARSE_PATHS = ("tile", "decoded")
-OVERLAP_MODES = ("off", "fused", "pipeline")
+OVERLAP_MODES = ("off", "fused")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +84,7 @@ class EngineConfig:
       matmul's flop volume clears ``min_flops`` (occupancy staging and
       per-block control flow need real work to amortize — and it keeps
       CPU smoke configs on the fast XLA path).
-    sparse: 'tile' | 'decoded' | 'auto' — which sparse datapath a
+    sparse: 'tile' | 'decoded' — which sparse datapath a
       sparse-resolved matmul runs (DESIGN.md §9):
       - 'tile': the block-occupancy kernel (skips whole block_m x
         block_k spike tiles) — the conservative default, profitable at
@@ -96,13 +92,7 @@ class EngineConfig:
       - 'decoded': the rank-decoded kernel (kernels/spike_decode.py) —
         rows sorted by occupancy into groups, pow2 bucket caps, one
         masked full-K dot per executed compacted chunk. It skips whole
-        groups of dark rows, never the MACs inside a live group;
-      - 'auto': picks per call from the *concrete* occupancy histogram
-        (kernels/spike_decode.choose_sparse_path — tile live-tile share
-        vs decoded full-K dots per row group, with the decoded path's
-        overhead handicap). Under jit the spikes are traced and the
-        histogram is unobservable, so auto falls back to 'tile' — the
-        same static-dispatch principle as ``mode`` / ``binary``.
+        groups of dark rows, never the MACs inside a live group.
     block_*: VMEM tile sizes of the kernel; (block_m x block_k) is the
       tile path's skip granularity and block_k doubles as the decoded
       path's compacted-chunk width.
@@ -120,26 +110,16 @@ class EngineConfig:
       AND-PopCount; layout is static per config, so this lives here and
       not in the ambient state.
 
-    overlap: 'off' | 'fused' | 'pipeline' | 'auto' — whether an
-      engine-owned step runs as a fused dual-engine grid (projection
-      tiles and AND-PopCount tiles interleaved per head, the Fig. 5
-      overlap made structural) or as the sequential composition.
-      'fused' runs the whole-layer program (kernels/fused_layer.py) for
-      layer_step/layer_step_causal and the SSA bundle
-      (kernels/fused_ssa.py) for ssa_step/ssa_step_causal; 'pipeline'
-      is the layer program on its (B, T, P, H) wavefront grid — the
-      timestep axis becomes a grid dimension so MLP tiles of layer l
-      interleave with layer l+1's Q/K/V phases on a pipelined backend
-      (bundle-level steps treat it as 'fused': the bundle has no MLP
-      tail to pipeline). 'auto' fuses only when the step's flop volume
-      clears ``min_flops`` and the input is concrete (same static-
-      dispatch discipline as ``sparse``: under jit auto resolves 'off';
-      explicit 'fused'/'pipeline' are honored everywhere — auto never
-      volunteers 'pipeline'). The fused steps are eval-only (train-mode BN needs
-      global batch stats) and fall back to the sequential composition
-      for layer shapes they do not cover (bias terms, mixed
+    overlap: 'off' | 'fused' — whether an eligible eval layer of
+      layer_step/layer_step_causal runs as the fused dual-engine grid
+      (kernels/fused_layer.py: projection tiles and AND-PopCount tiles
+      interleaved per head, the Fig. 5 overlap made structural) or as
+      its sequential oracle. Honored as set, eagerly and under jit. The
+      fused grid is eval-only (train-mode BN needs global batch stats);
+      train mode and layer shapes it does not cover (bias terms, mixed
       quantization, GQA, qk_norm, gated MLPs — see layer_step /
-      layer_step_causal / ssa_step / ssa_step_causal).
+      layer_step_causal) run the sequential composition whatever this
+      field says.
 
     weights: weight datapath dtype — 'fp32' (native params), 'int8', or
       'int4'. This is the *declared* serving datapath (launch/serve.py
@@ -170,12 +150,12 @@ class EngineConfig:
         if self.weights not in ("fp32", "int8", "int4"):
             raise ValueError(f"unknown weights datapath {self.weights!r} "
                              f"(expected fp32|int8|int4)")
-        if self.sparse not in SPARSE_PATHS + ("auto",):
+        if self.sparse not in SPARSE_PATHS:
             raise ValueError(f"unknown sparse datapath {self.sparse!r} "
-                             f"(expected tile|decoded|auto)")
-        if self.overlap not in OVERLAP_MODES + ("auto",):
+                             f"(expected tile|decoded)")
+        if self.overlap not in OVERLAP_MODES:
             raise ValueError(f"unknown overlap mode {self.overlap!r} "
-                             f"(expected off|fused|pipeline|auto)")
+                             f"(expected off|fused)")
 
     def replace(self, **kw) -> "EngineConfig":
         return dataclasses.replace(self, **kw)
@@ -234,28 +214,6 @@ def resolve_mode(engine: Optional[EngineConfig], m: int, k: int, n: int
     return "sparse" if 2 * m * k * n >= engine.min_flops else "dense"
 
 
-def resolve_sparse_path(engine: Optional[EngineConfig],
-                        s2d: Optional[jax.Array] = None) -> str:
-    """Tile-vs-decoded decision for a sparse-resolved matmul.
-
-    Static when it has to be: 'auto' consults the concrete occupancy
-    histogram (the decoded path's per-call crossover, DESIGN.md §9) only
-    when the spikes are concrete — under jit the input is a tracer and
-    auto resolves 'tile', the conservative static default. An explicit
-    'tile'/'decoded' declaration is honored everywhere.
-    """
-    if engine is None:
-        return "tile"
-    if engine.sparse in SPARSE_PATHS:
-        return engine.sparse
-    # EngineConfig.__post_init__ already rejected anything else
-    assert engine.sparse == "auto", engine.sparse
-    if s2d is None or isinstance(s2d, jax.core.Tracer):
-        return "tile"
-    from repro.kernels.spike_decode import choose_sparse_path  # lazy
-    return choose_sparse_path(s2d, engine.block_m, engine.block_k)
-
-
 BINARY_MODES = ("jnp", "mxu_kernel", "popcount")
 
 
@@ -276,61 +234,6 @@ def resolve_binary_mode(engine: Optional[EngineConfig], bh: int, l: int,
     if engine.binary != "auto":
         raise ValueError(f"unknown binary engine mode {engine.binary!r}")
     return "mxu_kernel" if 4 * bh * l * l * d >= engine.min_flops else "jnp"
-
-
-def resolve_overlap(engine: Optional[EngineConfig],
-                    x: Optional[jax.Array] = None,
-                    flops: int = 0) -> str:
-    """Fused-vs-sequential decision for an SSA layer step.
-
-    Same static-dispatch discipline as :func:`resolve_sparse_path`:
-    'auto' fuses only when the input is concrete (under jit — e.g. inside
-    the block scan — it is a tracer and auto resolves 'off') and when the
-    bundle's flop volume
-    (three projections + both attention matmuls) clears ``min_flops`` —
-    the fused grid stages whole Q/K/V spike trains through VMEM scratch,
-    which tiny smoke shapes can't amortize. Explicit 'fused' and
-    'pipeline' are honored everywhere; 'auto' never volunteers
-    'pipeline' (the wavefront grid's payoff is a backend-scheduling
-    property, not something the flop proxy can see).
-    """
-    if engine is None:
-        return "off"
-    if engine.overlap in OVERLAP_MODES:
-        return engine.overlap
-    # EngineConfig.__post_init__ already rejected anything else
-    assert engine.overlap == "auto", engine.overlap
-    if x is None or isinstance(x, jax.core.Tracer):
-        return "off"
-    return "fused" if flops >= engine.min_flops else "off"
-
-
-class LayerPlan(NamedTuple):
-    """The static execution plan of a whole-layer step: which overlap
-    grid (off | fused | pipeline) and which sparse projection datapath
-    (tile | decoded) the fused layer program composes."""
-    overlap: str
-    sparse: str
-
-
-def resolve_layer_plan(engine: Optional[EngineConfig],
-                       x: Optional[jax.Array] = None,
-                       flops: int = 0) -> LayerPlan:
-    """One static plan for a whole-layer step.
-
-    PR 6 resolved the overlap mode (:func:`resolve_overlap`, per bundle)
-    and the sparse datapath (:func:`resolve_sparse_path`, per matmul)
-    independently — the layer program needs them as *one* decision so
-    ``sparse='decoded'`` rides inside ``overlap='fused' | 'pipeline'``
-    (the decoded gather runs *inside* the fused kernel's projection
-    phases). Same static-dispatch discipline as both parents: under jit
-    ``x`` is a tracer, so 'auto' resolves (off, tile).
-    """
-    overlap = resolve_overlap(engine, x, flops)
-    x2d = None
-    if x is not None and not isinstance(x, jax.core.Tracer):
-        x2d = x.reshape(-1, x.shape[-1])
-    return LayerPlan(overlap, resolve_sparse_path(engine, x2d))
 
 
 # ---------------------------------------------------------------------------
@@ -519,7 +422,7 @@ def spike_linear(p: Dict[str, Any], x: jax.Array, *,
             return dense_quant_linear(p, x) if quantized \
                 else dense_spike_linear(p, x)
     x2d = x.reshape(-1, k)
-    path = resolve_sparse_path(engine, x2d)
+    path = engine.sparse
     with annotate(f"sparse_engine.{path}"):
         if quantized:
             out = _quant_sparse_matmul(
@@ -535,121 +438,27 @@ def spike_linear(p: Dict[str, Any], x: jax.Array, *,
 
 
 # ---------------------------------------------------------------------------
-# fused dual-engine SSA step (overlap='fused'): one Pallas grid runs the
-# sparse engine (Q/K/V projections + epilogues) and the binary engine
-# (AND-PopCount attention) interleaved per head — kernels/fused_ssa.py,
-# the Fig. 5 schedule. Custom VJP recomputes the sequential oracle in bwd.
+# the SSA bundle, sequential: what train mode and ineligible layers run
 # ---------------------------------------------------------------------------
 
 
-class _BundleSpec(NamedTuple):
-    """Static (hashable) closure of a fused SSA step — the nondiff arg of
-    the custom VJP, shared verbatim by the kernel fwd and the oracle bwd."""
-    family: str
-    num_heads: int
-    head_dim: int
-    scale: float
-    causal: bool
-    scfg: Any                   # SpikingConfig (frozen dataclass)
-    eps: float
-    interpret: Optional[bool]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _fused_bundle(x, w3, scale3, aux, delta, spec):
-    from repro.kernels.fused_ssa import fused_ssa  # lazy: no cycle
-    out, _ = fused_ssa(
-        x, w3, scale3, aux, delta, family=spec.family,
-        num_heads=spec.num_heads, head_dim=spec.head_dim, scale=spec.scale,
-        causal=spec.causal, binarize_scores=spec.scfg.binarize_scores,
-        decay=spec.scfg.decay, v_th=spec.scfg.v_threshold,
-        soft_reset=spec.scfg.soft_reset, eps=spec.eps,
-        interpret=spec.interpret)
-    return out
-
-
-def _fused_fwd(x, w3, scale3, aux, delta, spec):
-    return _fused_bundle(x, w3, scale3, aux, delta, spec), \
-        (x, w3, scale3, aux, delta)
-
-
-def _fused_bwd(spec, res, g):
-    """Recompute-through-the-oracle bwd: differentiating
-    ``kernels.fused_ssa.reference_bundle`` (the sequential composition the
-    kernel is pinned against bitwise) gives exactly the sequential path's
-    gradients — surrogate LIF/binarize jvps included. Quantized int codes
-    are cast to the activation dtype *before* this boundary, so their
-    cotangent stops at the convert just like the dense path's."""
-    from repro.kernels.fused_ssa import reference_bundle  # lazy: no cycle
-    x, w3, scale3, aux, delta = res
-
-    def f(x_, w3_, scale3_, aux_, delta_):
-        return reference_bundle(
-            x_, w3_, scale3_, aux_, delta_, spec.scfg, family=spec.family,
-            num_heads=spec.num_heads, head_dim=spec.head_dim,
-            scale=spec.scale, causal=spec.causal, eps=spec.eps)
-
-    _, vjp = jax.vjp(f, x, w3, scale3, aux, delta)
-    return vjp(g)
-
-
-_fused_bundle.defvjp(_fused_fwd, _fused_bwd)
-
-
 def ssa_step(p: Dict[str, Any], st: Dict[str, Any], cfg, s: jax.Array, *,
-             train: bool = False,
-             engine: Optional[EngineConfig] = None):
+             train: bool = False):
     """The vision-family SSA bundle (bidirectional, BN epilogues):
-    projections Q/K/V (+ BatchNorm + LIF) and binary attention as one
-    engine-owned step. ``models/spikingformer._ssa`` hands the whole
-    bundle here instead of composing primitives itself.
+    projections Q/K/V (+ BatchNorm + LIF) and binary attention, composed
+    from the engine's primitives (:func:`spike_linear` through
+    ``nn.linear``, ``spiking_attention``). :func:`layer_step` hands the
+    bundle here for train mode and ineligible layers.
 
     p: {'wq','wk','wv','bn_q','bn_k','bn_v','delta', ...}; st: the BN
     running-stats subtree; s: (T, B, L, D) {0,1} spikes (post input LIF);
     cfg: ModelConfig. Returns (ctx (T, B, L, q_dim), new BN state).
-
-    ``overlap='fused'`` runs the pipelined dual-engine kernel when the
-    step is expressible there: eval only (train-mode BN needs global
-    batch statistics), bias-free projections, all-or-none quantization.
-    Otherwise — and always for ``overlap='off'`` — the sequential
-    composition below, which is the bit-parity reference.
     """
-    engine = engine if engine is not None else get_engine()
     from repro.core.attention import spiking_attention  # lazy: no cycle
     from repro.core.spiking import lif_scan
     from repro.models import nn
-    t, b, l, d = s.shape
+    t, b, l, _ = s.shape
     heads, hd = cfg.num_heads, cfg.head_dim
-    names = (("q", "wq"), ("k", "wk"), ("v", "wv"))
-    quant = ["qw" in p[w] for _, w in names]
-    flops = 6 * (t * b * l) * d * cfg.q_dim \
-        + 4 * (t * b * heads) * l * l * hd
-    eligible = (not train
-                and (all(quant) or not any(quant))
-                and not any("b" in p[w] for _, w in names))
-    if eligible and resolve_overlap(engine, s, flops) in ("fused",
-                                                          "pipeline"):
-        if all(quant):
-            w3 = jnp.stack([_unpacked_qw(p[w], d) for _, w in names]
-                           ).astype(s.dtype)
-            scale3 = jnp.stack([p[w]["scale"].astype(jnp.float32)
-                                for _, w in names])
-        else:
-            w3 = jnp.stack([p[w]["w"] for _, w in names])
-            scale3 = None
-        aux = jnp.stack([
-            jnp.stack([st[f"bn_{n}"]["mean"].astype(jnp.float32),
-                       st[f"bn_{n}"]["var"].astype(jnp.float32),
-                       p[f"bn_{n}"]["scale"].astype(jnp.float32),
-                       p[f"bn_{n}"]["bias"].astype(jnp.float32)])
-            for n, _ in names])
-        spec = _BundleSpec("bn", heads, hd, 1.0 / math.sqrt(hd), False,
-                           cfg.spiking, 1e-5, engine.interpret)
-        with annotate("dual_engine.fused_ssa"):
-            ctx = _fused_bundle(s, w3, scale3, aux, p["delta"], spec)
-        return ctx, dict(st)
-    # sequential composition (what models/spikingformer._ssa used to
-    # inline) — the reference the fused path is pinned against bitwise
     new_st = dict(st)
 
     def proj(name, w):
@@ -670,66 +479,20 @@ def ssa_step(p: Dict[str, Any], st: Dict[str, Any], cfg, s: jax.Array, *,
     return ctx.transpose(0, 2, 1, 3).reshape(t, b, l, cfg.q_dim), new_st
 
 
-def ssa_step_causal(p: Dict[str, Any], cfg, h: jax.Array, positions, *,
-                    train: bool = False,
-                    engine: Optional[EngineConfig] = None) -> jax.Array:
+def ssa_step_causal(p: Dict[str, Any], cfg, h: jax.Array, positions
+                    ) -> jax.Array:
     """The token-family SSA bundle (causal, RoPE epilogues): Q/K/V
-    projections (+ RoPE + LIF) and causal binary attention as one
-    engine-owned step — the spiking full-attention branch of
-    ``models/transformer.apply_layer`` hands the bundle here (the
-    sliding-window branch keeps its banded jnp dataflow).
+    projections (+ RoPE + LIF) and causal binary attention, composed from
+    the engine's primitives. :func:`layer_step_causal` hands the bundle
+    here for ineligible layers.
 
     h: (T, B, S, D) normed membrane currents (post ln1); positions: (S,).
     Returns attn (T, B, S, q_dim) — pre-wo context.
-
-    Fused eligibility beyond the vision family's: no qk_norm, no GQA
-    (num_kv_heads == num_heads — the fused grid is one head per step),
-    shared 1-D positions, even head_dim (RoPE halves), and fp32
-    activations unless quantized (the sequential path's plain ``nn.
-    linear`` accumulates in the activation dtype; the kernel accumulates
-    fp32, which only coincides bitwise when they agree).
     """
-    engine = engine if engine is not None else get_engine()
     from repro.core.attention import spiking_attention  # lazy: no cycle
     from repro.core.spiking import lif_scan
-    t, b, s_len, d = h.shape
-    heads, hd = cfg.num_heads, cfg.head_dim
-    names = ("wq", "wk", "wv")
-    quant = ["qw" in p[w] for w in names]
-    flops = 6 * (t * b * s_len) * d * cfg.q_dim \
-        + 4 * (t * b * heads) * s_len * s_len * hd
-    positions = jnp.asarray(positions)
-    eligible = (not cfg.qk_norm
-                and cfg.num_kv_heads == cfg.num_heads
-                and (all(quant) or not any(quant))
-                and not any("b" in p[w] for w in names)
-                and (all(quant) or h.dtype == jnp.float32)
-                and hd % 2 == 0
-                and positions.ndim == 1)
-    if eligible and resolve_overlap(engine, h, flops) in ("fused",
-                                                          "pipeline"):
-        if all(quant):
-            w3 = jnp.stack([_unpacked_qw(p[w], d) for w in names]
-                           ).astype(h.dtype)
-            scale3 = jnp.stack([p[w]["scale"].astype(jnp.float32)
-                                for w in names])
-        else:
-            w3 = jnp.stack([p[w]["w"] for w in names])
-            scale3 = None
-        half = hd // 2
-        # nn.rope's table, verbatim (same f32 expression -> same values)
-        freqs = cfg.rope_theta ** (
-            -jnp.arange(0, half, dtype=jnp.float32) / half)
-        ang = positions.astype(jnp.float32)[:, None] * freqs
-        aux = jnp.stack([jnp.cos(ang), jnp.sin(ang)])
-        spec = _BundleSpec("rope", heads, hd, 1.0 / math.sqrt(hd), True,
-                           cfg.spiking, 1e-5, engine.interpret)
-        with annotate("dual_engine.fused_ssa"):
-            ctx = _fused_bundle(h, w3, scale3, aux, p["delta"], spec)
-        return ctx
-    # sequential composition (what models/transformer.apply_layer used to
-    # inline for the spiking full-attention branch)
     from repro.models.transformer import _project_qkv  # lazy: no cycle
+    t, b, s_len, _ = h.shape
     q, k, v = _project_qkv(p, cfg, h, positions, repeat_kv=True)
     q, k, v = (lif_scan(u, cfg.spiking)[0] for u in (q, k, v))
     fold = lambda u: u.reshape(-1, *u.shape[2:])     # (T*B, S, H, hd)
@@ -741,19 +504,19 @@ def ssa_step_causal(p: Dict[str, Any], cfg, h: jax.Array, positions, *,
 
 
 # ---------------------------------------------------------------------------
-# fused whole-layer step (overlap='fused'|'pipeline'): the layer program —
-# SSA bundle + output projection + residuals + spiking MLP — runs as one
-# Pallas grid (kernels/fused_layer.py) with the decoded gather datapath
-# available inside the projection phases and a per-phase occupancy map for
-# the binary engine. Custom VJP recomputes the sequential oracle in bwd.
+# the layer program: SSA bundle + output projection + residuals + spiking
+# MLP as one engine step. overlap='fused' runs it as one Pallas grid
+# (kernels/fused_layer.py) with the decoded gather datapath available
+# inside the projection phases and a per-phase occupancy map for the
+# binary engine; overlap='off' runs its sequential oracle. Custom VJP
+# recomputes the sequential oracle in bwd.
 # ---------------------------------------------------------------------------
 
 
 class _LayerSpec(NamedTuple):
     """Static (hashable) closure of a layer-program step — the nondiff
     arg of the custom VJP, shared verbatim by the fwd (kernel or oracle)
-    and the oracle bwd (the PR 6 ``_BundleSpec`` pattern, extended with
-    the layer plan)."""
+    and the oracle bwd."""
     family: str
     num_heads: int
     head_dim: int
@@ -762,7 +525,7 @@ class _LayerSpec(NamedTuple):
     scfg: Any                   # SpikingConfig (frozen dataclass)
     eps: float
     norm_eps: float
-    overlap: str                # off | fused | pipeline
+    overlap: str                # off | fused
     sparse: str                 # tile | decoded
     l_block: int
     c_block: int
@@ -783,9 +546,9 @@ def _layer_kernel_args(ops, spec):
 def _fused_layer(ops, spec):
     """Every *eligible* layer runs through this step — also with
     ``overlap='off'``, where the fwd is the sequential oracle itself.
-    One function means one gradient program for all overlap modes (the
-    same bwd jaxpr below), which is what makes off/fused/pipeline
-    gradients bitwise-identical *by construction*: an inline-autodiff
+    One function means one gradient program for both overlap modes (the
+    same bwd jaxpr below), which is what makes off and fused gradients
+    bitwise-identical *by construction*: an inline-autodiff
     bwd and a recompute bwd are different jaxprs computing the same
     math, and XLA's FMA contraction resolves them differently at the
     one-ulp level once the layer scan splits fwd and bwd into separate
@@ -797,7 +560,7 @@ def _fused_layer(ops, spec):
     from repro.kernels.fused_layer import fused_layer  # lazy: no cycle
     args, kw = _layer_kernel_args(ops, spec)
     out, _ = fused_layer(
-        *args, sparse=spec.sparse, pipeline=spec.overlap == "pipeline",
+        *args, sparse=spec.sparse,
         binarize_scores=spec.scfg.binarize_scores, decay=spec.scfg.decay,
         v_th=spec.scfg.v_threshold, soft_reset=spec.scfg.soft_reset,
         l_block=spec.l_block, c_block=spec.c_block,
@@ -810,7 +573,7 @@ def _layer_fwd(ops, spec):
 
 
 def _layer_bwd(spec, res, g):
-    """Recompute-through-the-oracle bwd (the PR 6 pattern): differentiate
+    """Recompute-through-the-oracle bwd: differentiate
     ``kernels.fused_layer.reference_layer`` — the sequential layer
     composition the kernel is pinned against bitwise — so the fused path
     returns exactly the sequential path's gradients, surrogate LIF /
@@ -866,6 +629,16 @@ def _pad_ff(w1, w2, sc1, aux1, heads):
     return w1, w2, sc1, aux1
 
 
+def _layer_spec(engine, family, cfg, causal, norm_eps):
+    """The layer program's static plan: the engine's overlap and sparse
+    datapath as set, and its block sizes (no engine: the defaults)."""
+    eng = engine if engine is not None else EngineConfig()
+    hd = cfg.head_dim
+    return _LayerSpec(family, cfg.num_heads, hd, 1.0 / math.sqrt(hd),
+                      causal, cfg.spiking, 1e-5, norm_eps, eng.overlap,
+                      eng.sparse, eng.block_m, eng.block_k, eng.interpret)
+
+
 def _bn_rows(p, st, name):
     return jnp.stack([st[name]["mean"].astype(jnp.float32),
                       st[name]["var"].astype(jnp.float32),
@@ -885,22 +658,20 @@ def layer_step(p: Dict[str, Any], st: Dict[str, Any], cfg, x: jax.Array,
     layout); x: (T, B, L, D) membrane currents (the residual stream);
     cfg: ModelConfig. Returns (y (T, B, L, D), new BN state).
 
-    With ``overlap='fused' | 'pipeline'`` (and an eligible layer) the
-    whole program runs as one Pallas grid — kernels/fused_layer.py, with
-    ``sparse='decoded'`` composing the gather-compacted projection
-    datapath into the fused phases (resolve_layer_plan). Eligibility
-    follows the PR 6 static-fallback discipline: eval only (train-mode
-    BN needs global batch stats), bias-free linears, all-or-none
+    The plan is the engine's ``overlap`` and ``sparse`` as set (no
+    engine: off, tile). An eligible layer — eval only (train-mode BN
+    needs global batch stats), bias-free linears, all-or-none
     quantization, binarized scores with analog context (the blocked
     binary phases and the head-split wo contraction stay exact on
-    integer contexts). Eligible layers route through the shared
-    custom-VJP step for *every* overlap mode — ``overlap='off'`` runs
-    the sequential oracle as its fwd — so off/fused/pipeline agree
-    bitwise on gradients by construction (see ``_fused_layer``).
-    Ineligible layers (and train mode) run the plain sequential
-    composition below, which still hands the SSA bundle to
-    :func:`ssa_step`, so bundle-level fusion survives a layer-level
-    fallback.
+    integer contexts) — routes through the shared custom-VJP step:
+    ``overlap='fused'`` runs the whole program as one Pallas grid
+    (kernels/fused_layer.py, with ``sparse='decoded'`` composing the
+    gather-compacted projection datapath into the fused phases), and
+    ``overlap='off'`` runs the sequential oracle ``reference_layer`` as
+    its fwd, so both agree bitwise on gradients by construction (see
+    ``_fused_layer``). Ineligible layers (and train mode) run the plain
+    sequential composition below, with the SSA bundle in
+    :func:`ssa_step`.
     """
     engine = engine if engine is not None else get_engine()
     from repro.core.spiking import lif_scan
@@ -909,17 +680,12 @@ def layer_step(p: Dict[str, Any], st: Dict[str, Any], cfg, x: jax.Array,
     heads, hd = cfg.num_heads, cfg.head_dim
     lin_names = ("wq", "wk", "wv", "wo", "w1", "w2")
     quant = ["qw" in p[w] for w in lin_names]
-    flops = 6 * (t * b * l) * d * cfg.q_dim \
-        + 4 * (t * b * heads) * l * l * hd \
-        + 2 * (t * b * l) * cfg.q_dim * d \
-        + 4 * (t * b * l) * d * cfg.d_ff
     eligible = (not train
                 and (all(quant) or not any(quant))
                 and not any("b" in p[w] for w in lin_names)
                 and cfg.spiking.binarize_scores
                 and not cfg.spiking.binarize_context)
     s = lif_scan(x, cfg.spiking)[0]
-    plan = resolve_layer_plan(engine, s, flops)
     if eligible:
         dtype = x.dtype
         if all(quant):
@@ -941,21 +707,14 @@ def layer_step(p: Dict[str, Any], st: Dict[str, Any], cfg, x: jax.Array,
             "aux1": aux1, "aux2": _bn_rows(p, st, "bn_2"),
             "delta": p["delta"],
         }
-        spec = _LayerSpec("bn", heads, hd, 1.0 / math.sqrt(hd), False,
-                          cfg.spiking, 1e-5, 1e-6, plan.overlap,
-                          plan.sparse,
-                          engine.block_m if engine else 128,
-                          engine.block_k if engine else 128,
-                          engine.interpret if engine else None)
+        spec = _layer_spec(engine, "bn", cfg, False, 1e-6)
         with annotate("dual_engine.fused_layer"):
             y = _fused_layer(ops, spec)
         return y, dict(st)
     # sequential composition (what models/spikingformer._block used to
-    # inline) — the reference the fused path is pinned against bitwise.
-    # The bundle still routes through ssa_step: a layer-level fallback
-    # keeps bundle-level fusion.
+    # inline): train mode and ineligible layers
     ctx, new_st = ssa_step(p, {n: st[n] for n in ("bn_q", "bn_k", "bn_v")},
-                           cfg, s, train=train, engine=engine)
+                           cfg, s, train=train)
     new_st = dict(st, **new_st)
     # ctx is binarized-attention output: sparse integer counts, not {0,1}
     # spikes — but zero blocks are zero blocks, so the sparse engine
@@ -980,7 +739,6 @@ def layer_step(p: Dict[str, Any], st: Dict[str, Any], cfg, x: jax.Array,
 
 
 def layer_step_causal(p: Dict[str, Any], cfg, x: jax.Array, positions, *,
-                      train: bool = False,
                       engine: Optional[EngineConfig] = None) -> jax.Array:
     """The token-family *layer program* (causal, RoPE/rmsnorm epilogues):
     ln1 + SSA bundle + wo + residual + ln2 + spiking MLP + residual as
@@ -995,11 +753,12 @@ def layer_step_causal(p: Dict[str, Any], cfg, x: jax.Array, positions, *,
     activations unless quantized) plus the MLP tail's: a plain
     (up, down) MLP — a gated MLP has no fused phase mapping — and
     binarized scores with analog context (integer contexts keep the
-    head-split wo and the blocked binary phases exact). Eligible layers
-    route through the shared custom-VJP step for every overlap mode
-    (``off`` runs the sequential oracle as its fwd — one gradient
+    head-split wo and the blocked binary phases exact). The plan is
+    read from the engine as set, as in :func:`layer_step`. Eligible
+    layers route through the shared custom-VJP step for both overlap
+    modes (``off`` runs the sequential oracle as its fwd — one gradient
     program, see ``_fused_layer``); ineligible layers fall back to the
-    plain sequential composition, which still hands the bundle to
+    plain sequential composition, with the bundle in
     :func:`ssa_step_causal`.
     """
     engine = engine if engine is not None else get_engine()
@@ -1012,12 +771,6 @@ def layer_step_causal(p: Dict[str, Any], cfg, x: jax.Array, positions, *,
     lin_ps = [p["wq"], p["wk"], p["wv"], p["wo"],
               p["mlp"].get("up"), p["mlp"].get("down")]
     quant = ["qw" in q for q in lin_ps if q is not None]
-    d_ff = 0 if lin_ps[4] is None else \
-        (lin_ps[4]["qw"] if "qw" in lin_ps[4] else lin_ps[4]["w"]).shape[-1]
-    flops = 6 * (t * b * s_len) * d * cfg.q_dim \
-        + 4 * (t * b * heads) * s_len * s_len * hd \
-        + 2 * (t * b * s_len) * cfg.q_dim * d \
-        + 4 * (t * b * s_len) * d * d_ff
     positions = jnp.asarray(positions)
     eligible = (not cfg.qk_norm
                 and cfg.num_kv_heads == cfg.num_heads
@@ -1029,7 +782,6 @@ def layer_step_causal(p: Dict[str, Any], cfg, x: jax.Array, positions, *,
                 and positions.ndim == 1
                 and cfg.spiking.binarize_scores
                 and not cfg.spiking.binarize_context)
-    plan = resolve_layer_plan(engine, h, flops)
     if eligible:
         dtype = x.dtype
         if all(quant):
@@ -1039,7 +791,7 @@ def layer_step_causal(p: Dict[str, Any], cfg, x: jax.Array, positions, *,
             sc3 = jnp.ones((3, cfg.q_dim), jnp.float32)
         wo, sco = _layer_linear(p["wo"], cfg.q_dim, dtype)
         w1, sc1 = _layer_linear(p["mlp"]["up"], d, dtype)
-        w2, sc2 = _layer_linear(p["mlp"]["down"], d_ff, dtype)
+        w2, sc2 = _layer_linear(p["mlp"]["down"], w1.shape[1], dtype)
         w1, w2, sc1, _ = _pad_ff(w1, w2, sc1, None, heads)
         half = hd // 2
         # nn.rope's table, verbatim (same f32 expression -> same values)
@@ -1054,20 +806,13 @@ def layer_step_causal(p: Dict[str, Any], cfg, x: jax.Array, positions, *,
             "aux1": None, "aux2": None,
             "delta": p["delta"],
         }
-        spec = _LayerSpec("rope", heads, hd, 1.0 / math.sqrt(hd), True,
-                          cfg.spiking, 1e-5, cfg.norm_eps, plan.overlap,
-                          plan.sparse,
-                          engine.block_m if engine else 128,
-                          engine.block_k if engine else 128,
-                          engine.interpret if engine else None)
+        spec = _layer_spec(engine, "rope", cfg, True, cfg.norm_eps)
         with annotate("dual_engine.fused_layer"):
             y = _fused_layer(ops, spec)
         return constrain(y, "batch", "seq", "embed")
     # sequential composition (what models/transformer.apply_layer used
-    # to inline for the spiking full-attention branch); the bundle still
-    # routes through ssa_step_causal
-    attn = ssa_step_causal(p, cfg, h, positions, train=train,
-                           engine=engine)
+    # to inline for the spiking full-attention branch)
+    attn = ssa_step_causal(p, cfg, h, positions)
     attn = constrain(attn, "batch", "seq", "model")
     x = x + nn.linear(p["wo"], attn)
     h2 = nn.rmsnorm(p["ln2"], x, cfg.norm_eps)

@@ -1,19 +1,11 @@
 """Fused whole-layer step — the layer-program the dual-engine overlay runs.
 
-PR 6 (``kernels/fused_ssa.py``) fused the SSA *bundle* (Q/K/V
-projections + binary attention) onto one Pallas grid; the MLP of layer
-l still ran sequentially after attention, the fused projections only
-skipped at spike-*slab* granularity, and the binary phases never
-skipped at all. This kernel extends the fusion to the **entire encoder
-layer** — the paper's orchestrator overlaps the sparse and binary
-engines across the whole layer dataflow, not just the bundle:
-
-Grid ``(B, P, H)`` (``overlap='fused'``) or ``(B, T, P, H)``
-(``overlap='pipeline'`` — the timestep/layer axis from ROADMAP made a
-grid axis: every phase advances one timestep at a time, LIF membranes
-ride VMEM scratch across the T axis, and on a pipelined backend layer
-l+1's projection phases stream in behind layer l's MLP tiles on the
-same wavefront), with P = 8 per-head phases (:data:`LAYER_PHASES`):
+The paper's orchestrator overlaps the sparse and binary engines across
+the whole encoder layer dataflow. This kernel runs one layer — the SSA
+bundle (Q/K/V projections + binary attention), the output projection,
+the residuals and the spiking MLP — as one Pallas grid ``(B, P, H)``
+(``overlap='fused'``), with P = 8 per-head phases
+(:data:`LAYER_PHASES`):
 
   sparse engine: ``q / k / v`` projections (+ BN/RoPE epilogue + LIF),
                  ``wo`` head-slice, ``up`` / ``down`` MLP ff-chunks
@@ -34,7 +26,7 @@ reach the counts output) and all exact (skipped work contributes +0):
   the token family's projections consume *analog* normed currents, so
   there ``decoded`` degenerates to the tile skip (same dispatch
   outcome, still bitwise). ``sparse='tile'`` keeps block-granular
-  occupancy skips (the PR 6 slab skip, refined to L-block resolution).
+  occupancy skips (an all-dark spike block skips its dot).
 * occupancy map for the binary phases: per (head, key/value-axis
   L-block) the ``qkt`` phase skips all-dark key blocks (their scores
   are exact zeros, which binarize to zero whenever delta > 0 — when
@@ -46,19 +38,18 @@ reach the counts output) and all exact (skipped work contributes +0):
   (bias-free linears: a zero row block contributes exact fp32 zeros).
 
 The counts output is a ``(H, 8, n_l_blocks)`` int32 occupancy map —
-the PR 6 ``(H, 4)`` executed-step counts extended per phase and per
-L-block — consumed by ``core.dual_engine.fused_step_metrics`` for the
-per-phase measured hidden fraction.
+executed sub-blocks per head, phase and L-block — consumed by
+``core.dual_engine.fused_step_metrics`` for the per-phase measured
+hidden fraction.
 
 Bit-exactness (DESIGN.md §4 contract): every contraction accumulates
 fp32 over exact or un-split operands, epilogues repeat the reference
 expressions (``nn.batchnorm`` eval affine, ``nn.rope``, ``nn.rmsnorm``,
-``core.spiking.lif_step``) on identical dtypes, and the fused / pipeline
-grids execute identical math — so :func:`reference_layer` below (the
-sequential layer composition ``models/spikingformer._block`` /
-``models/transformer.apply_layer`` used to inline) is matched bitwise
-on the layer output, and is the recompute target of the fused path's
-custom VJP (``core.engine``).
+``core.spiking.lif_step``) on identical dtypes — so
+:func:`reference_layer` below (the sequential layer composition, with
+the SSA bundle in :func:`reference_bundle`) is matched bitwise on the
+layer output. It is also what ``overlap='off'`` runs in eval, and the
+recompute target of the layer step's custom VJP (``core.engine``).
 
 Mosaic layout (pinned by ``tests/test_tpu_compile.py``): per-head
 weight, scale and epilogue operands are laid out head-major so each
@@ -78,7 +69,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.scopes import annotate
-from repro.core.spiking import SpikingConfig, lif_scan
+from repro.core.spiking import SpikingConfig, binarize, lif_scan
 
 FAMILIES = ("bn", "rope")
 # per-head phases of the layer program: three sparse projections, the
@@ -109,7 +100,7 @@ def _vmem_bytes(shape, dtype) -> int:
     return n * (-(-r // sub) * sub) * (-(-c // 128) * 128) * item
 
 
-def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
+def _kernel(*refs, family, decoded, t_steps, l, d_model,
             head_dim, num_heads, ffc, l_block, c_block, nc, nlb, scale,
             causal, binarize_scores, decay, v_th, soft_reset, eps,
             norm_eps, dtype):
@@ -120,16 +111,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
     cid_ref, cap_ref = refs[15:17] if decoded else (None, None)
     (o_ref, cnt_ref, sq, sk, sv, scr, ctxs, hids, attn_acc, dn_acc, x1s,
      s2s, uq, uk, uv, us2, uh) = refs[n_in:]
-    if pipeline:
-        b, ti = pl.program_id(0), pl.program_id(1)
-        p, h = pl.program_id(2), pl.program_id(3)
-        trange = (ti,)
-        first_step = (b == 0) & (ti == 0) & (p == 0) & (h == 0)
-    else:
-        b = pl.program_id(0)
-        p, h = pl.program_id(1), pl.program_id(2)
-        trange = tuple(range(t_steps))
-        first_step = (b == 0) & (p == 0) & (h == 0)
+    b, p, h = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     half = head_dim // 2
     # every phase walks the L axis in l_block row blocks: the skip
     # granularity of the occupancy map, and static ref slices that Mosaic
@@ -141,7 +123,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
     dot = lambda a, w: jax.lax.dot_general(
         a, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    @pl.when(first_step)
+    @pl.when((b == 0) & (p == 0) & (h == 0))
     def _init_counts():
         def zero(i, c):
             cnt_ref[i] = jnp.int32(0)
@@ -155,13 +137,9 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
             cnt_ref[i] = cnt_ref[i] + nexec[lb]
 
     def _lif(u_ref, uslot, t, y, r0, r1):
-        # one lif_step on rows r0:r1; the membrane rides scratch so the
-        # pipeline grid carries it across the T axis (the fused grid
-        # round-trips it within one invocation — identical values)
-        if pipeline:
-            u = jnp.where(t == 0, jnp.zeros_like(y), u_ref[uslot, r0:r1])
-        else:
-            u = jnp.zeros_like(y) if t == 0 else u_ref[uslot, r0:r1]
+        # one lif_step on rows r0:r1; the membrane rides scratch between
+        # the timesteps of one invocation
+        u = jnp.zeros_like(y) if t == 0 else u_ref[uslot, r0:r1]
         u = decay * u + y
         s_t = (u - v_th >= 0).astype(dtype)
         u = u - s_t * v_th if soft_reset else u * (1.0 - s_t)
@@ -189,7 +167,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
         # epilogue (quant scale, BN affine / RoPE) and LIF — per head
         w = w3_ref[0, 0]                                 # (K, hd)
         nexec = [jnp.int32(0)] * nlb
-        for t in trange:
+        for t in range(t_steps):
             for lb, r0, r1 in blocks:
                 rows = s_ref[0, t, r0:r1]                # (r, K)
                 if decoded:
@@ -264,7 +242,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
         # in VMEM scratch for the qktv phase; dark blocks skip and the
         # skip is recorded in the occupancy map
         nexec = [jnp.int32(0)] * nlb
-        for t in trange:
+        for t in range(t_steps):
             q_t = sq[slot(t)]
             for lb, r0, r1 in blocks:
                 k_blk = sk[slot(t), r0:r1]
@@ -283,7 +261,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
         # blocks with the value blocks; a block whose scores or value
         # spikes are all dark contributes exact +0 and is skipped
         nexec = [jnp.int32(0)] * nlb
-        for t in trange:
+        for t in range(t_steps):
             ctx = jnp.zeros((l, head_dim), jnp.float32)
             for lb, r0, r1 in blocks:
                 v_blk = sv[slot(t), r0:r1]
@@ -307,7 +285,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
         # ln2 rmsnorm (token) into the MLP input scratch.
         w = wo_ref[0]                                    # (hd, D)
         nexec = [jnp.int32(0)] * nlb
-        for t in trange:
+        for t in range(t_steps):
             @pl.when(h == 0)
             def _zero():
                 attn_acc[t] = jnp.zeros((l, d_model), jnp.float32)
@@ -342,7 +320,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
         # bn_1 + LIF (vision) or LIF (token) into the hidden spikes
         w = w1_ref[0]                                    # (D, ffc)
         nexec = [jnp.int32(0)] * nlb
-        for t in trange:
+        for t in range(t_steps):
             for lb, r0, r1 in blocks:
                 acc, n = _skip_dot(s2s[t, r0:r1], w, ffc)
                 nexec[lb] += n
@@ -360,7 +338,7 @@ def _kernel(*refs, family, decoded, pipeline, t_steps, l, d_model,
         # the layer output
         w = w2_ref[0]                                    # (ffc, D)
         nexec = [jnp.int32(0)] * nlb
-        for t in trange:
+        for t in range(t_steps):
             @pl.when(h == 0)
             def _zero():
                 dn_acc[t] = jnp.zeros((l, d_model), jnp.float32)
@@ -387,7 +365,7 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
                 aux1: Optional[jax.Array], aux2: Optional[jax.Array],
                 delta, *, family: str, num_heads: int, head_dim: int,
                 scale: float, causal: bool = False, sparse: str = "tile",
-                pipeline: bool = False, binarize_scores: bool = True,
+                binarize_scores: bool = True,
                 decay: float = 0.5, v_th: float = 1.0,
                 soft_reset: bool = False, eps: float = 1e-5,
                 norm_eps: float = 1e-6, l_block: int = 128,
@@ -419,8 +397,6 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
       sparse: ``'tile'`` (L-block occupancy skip) or ``'decoded'``
         (compacted-chunk projection contraction; spike-driven family
         only — see module docstring).
-      pipeline: run the ``(B, T, P, H)`` per-timestep wavefront grid
-        instead of ``(B, P, H)``; outputs and counts are identical.
 
     Returns:
       (layer output ``(T, B, L, D)`` activation dtype,
@@ -464,28 +440,21 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
                   jnp.ones((d_model,), jnp.float32))
     sc3, sco, sc1, sc2 = (jnp.asarray(a, jnp.float32) for a in scales)
 
-    if pipeline:
-        grid = (b, t, N_PHASES, num_heads)
-        ix = lambda f: (lambda bi, ti, pi, hi: f(bi, pi, hi))
-    else:
-        grid = (b, N_PHASES, num_heads)
-        ix = lambda f: (lambda bi, pi, hi: f(bi, pi, hi))
-
     # Per-head operands are laid out head-major, so every block's two
     # minor dims are whole array dims: Mosaic's (8, 128) tiling rule then
     # holds for any head_dim and ff-chunk width.
     def heads(a, n):                  # (..., H*n) -> (H, ..., n)
         return jnp.moveaxis(a.reshape(*a.shape[:-1], num_heads, n), -2, 0)
 
-    whole = lambda a: pl.BlockSpec(a.shape, ix(lambda bi, pi, hi:
-                                               (0,) * a.ndim))
+    whole = lambda a: pl.BlockSpec(a.shape, lambda bi, pi, hi:
+                                   (0,) * a.ndim)
     per_head = lambda a: pl.BlockSpec(
-        (1, *a.shape[1:]), ix(lambda bi, pi, hi: (hi,) + (0,) * (a.ndim - 1)))
+        (1, *a.shape[1:]), lambda bi, pi, hi: (hi,) + (0,) * (a.ndim - 1))
     per_proj = lambda a: pl.BlockSpec(
         (1, 1, *a.shape[2:]),
-        ix(lambda bi, pi, hi: (jnp.minimum(pi, 2), hi, 0, 0)))
+        lambda bi, pi, hi: (jnp.minimum(pi, 2), hi, 0, 0))
     per_row = pl.BlockSpec((1, t, l, d_model),
-                           ix(lambda bi, pi, hi: (bi, 0, 0, 0)))
+                           lambda bi, pi, hi: (bi, 0, 0, 0))
 
     w3h = jnp.swapaxes(heads(w3, head_dim), 0, 1)    # (3, H, K, hd)
     sc3h = jnp.swapaxes(heads(sc3[:, None, :], head_dim), 0, 1)
@@ -531,8 +500,8 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
         in_specs += [per_row, smem]
 
     kernel = functools.partial(
-        _kernel, family=family, decoded=decoded, pipeline=pipeline,
-        t_steps=t, l=l, d_model=d_model, head_dim=head_dim,
+        _kernel, family=family, decoded=decoded, t_steps=t, l=l,
+        d_model=d_model, head_dim=head_dim,
         num_heads=num_heads, ffc=ffc, l_block=l_block, c_block=c_blk,
         nc=nc, nlb=nlb, scale=float(scale), causal=causal,
         binarize_scores=binarize_scores, decay=float(decay),
@@ -558,7 +527,7 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
         ((num_heads, l, ffc), dtype),                # mlp membrane
     ]
     # VMEM reckoning: scratch once, every VMEM block double-buffered by
-    # the grid pipeline (the output row block included)
+    # Pallas across grid steps (the output row block included)
     blocks = [(spec.block_shape, op.dtype)
               for spec, op in zip(in_specs, operands)
               if spec.block_shape is not None]
@@ -573,7 +542,7 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
 
     out, cnt = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, N_PHASES, num_heads),
         in_specs=in_specs,
         out_specs=[per_row, smem],
         out_shape=[
@@ -589,6 +558,62 @@ def fused_layer(x: jax.Array, s: jax.Array, w3: jax.Array, wo: jax.Array,
             cnt.reshape(num_heads, N_PHASES, nlb))
 
 
+def reference_bundle(x: jax.Array, w3: jax.Array,
+                     scale3: Optional[jax.Array], aux: jax.Array, delta,
+                     scfg: SpikingConfig, *, family: str, num_heads: int,
+                     head_dim: int, scale: float, causal: bool = False,
+                     eps: float = 1e-5) -> jax.Array:
+    """The SSA bundle of :func:`reference_layer`: dense fp32-accumulated
+    Q/K/V projections -> BN affine / RoPE -> ``lif_scan`` -> jnp binary
+    attention, on the raw operands the kernel's projection phases see.
+    It is the eval path's bundle on the chip (``overlap='off'``), and
+    equals the train path's sequential bundle (``core.engine.ssa_step``
+    / ``ssa_step_causal``) bitwise."""
+    t, b, l, _ = x.shape
+    q_dim = num_heads * head_dim
+    half = head_dim // 2
+    projected = []
+    for j in range(3):
+        with annotate("sparse_engine.dense"):
+            acc = jnp.dot(x, w3[j], preferred_element_type=jnp.float32)
+            if scale3 is not None:
+                acc = acc * scale3[j].astype(jnp.float32)
+            y = acc.astype(x.dtype)
+        if family == "bn":
+            mean, var = aux[j, 0], aux[j, 1]
+            y32 = y.astype(jnp.float32)
+            y32 = (y32 - mean) * jax.lax.rsqrt(var + eps)
+            y32 = y32 * aux[j, 2] + aux[j, 3]
+            y = y32.astype(x.dtype)
+        elif j < 2:                                  # rope on q, k
+            y5 = y.reshape(t, b, l, num_heads, head_dim)
+            cos = aux[0][None, None, :, None, :]
+            sin = aux[1][None, None, :, None, :]
+            x1 = y5[..., :half].astype(jnp.float32)
+            x2 = y5[..., half:].astype(jnp.float32)
+            y = jnp.concatenate([x1 * cos - x2 * sin,
+                                 x2 * cos + x1 * sin],
+                                -1).astype(x.dtype).reshape(t, b, l, q_dim)
+        s_j, _ = lif_scan(y, scfg)
+        projected.append(s_j)
+    fold = lambda u: u.reshape(t * b, l, num_heads,
+                               head_dim).transpose(0, 2, 1, 3)
+    with annotate("binary_engine.jnp"):
+        q, k, v = (fold(u) for u in projected)
+        scores = jnp.einsum("...qd,...kd->...qk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        if scfg.binarize_scores:
+            attn = binarize(scores, delta, scfg.surrogate_alpha)
+        else:
+            attn = scores
+        if causal:
+            mask = jnp.tril(jnp.ones((l, l), bool))
+            attn = jnp.where(mask, attn, 0.0)
+        ctx = jnp.einsum("...qk,...kd->...qd", attn, v,
+                         preferred_element_type=jnp.float32).astype(q.dtype)
+        return ctx.transpose(0, 2, 1, 3).reshape(t, b, l, q_dim)
+
+
 def reference_layer(x: jax.Array, s: jax.Array, w3, wo, w1, w2,
                     scales, auxp, auxo, aux1, aux2, delta,
                     scfg: SpikingConfig, *, family: str, num_heads: int,
@@ -596,13 +621,12 @@ def reference_layer(x: jax.Array, s: jax.Array, w3, wo, w1, w2,
                     eps: float = 1e-5, norm_eps: float = 1e-6
                     ) -> jax.Array:
     """The sequential oracle: term-for-term the ``overlap='off'`` layer
-    composition (the SSA bundle via ``fused_ssa.reference_bundle``, then
-    wo + epilogue + residual, input neuron / ln2, and the spiking MLP)
-    on the same raw operands the kernel sees. The fused custom VJP
-    recomputes through this in bwd, so fused-layer gradients are the
-    sequential path's gradients by construction (surrogate LIF /
-    binarize jvps included)."""
-    from repro.kernels.fused_ssa import reference_bundle
+    composition (the SSA bundle via :func:`reference_bundle`, then wo +
+    epilogue + residual, input neuron / ln2, and the spiking MLP) on the
+    same raw operands the kernel sees. The fused custom VJP recomputes
+    through this in bwd, so fused-layer gradients are the sequential
+    path's gradients by construction (surrogate LIF / binarize jvps
+    included)."""
     if scales is None:
         sc3 = sco = sc1 = sc2 = None
     else:

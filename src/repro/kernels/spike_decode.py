@@ -60,14 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitpack import pad_to_multiple
-from repro.kernels.spike_matmul import block_occupancy, int8_dot
-
-# Crossover factor for ``sparse='auto'`` (DESIGN.md §9): on top of its
-# dots the decoded path sorts the rows, stages the chunk ids (an (M, K)
-# int32 operand beside the spikes) and masks each resident chunk, so its
-# modeled MXU work must sit below the tile path's by at least this
-# factor before auto picks it.
-DECODED_OVERHEAD = 2.0
+from repro.kernels.spike_matmul import int8_dot
 
 
 def pow2ceil(x: jax.Array) -> jax.Array:
@@ -170,30 +163,6 @@ def decoded_dot_fraction(sched) -> float:
     It is 1 or more whenever every group holds a live row, and falls
     below 1 only as far as the sort fills whole groups with dark rows."""
     return float(sched["executed"]) / sched["caps"].shape[0]
-
-
-def choose_sparse_path(s: jax.Array, block_m: int, block_k: int) -> str:
-    """Per-call tile-vs-decoded decision from the concrete occupancy
-    histogram (``sparse='auto'``, DESIGN.md §9). The tile path costs its
-    live-tile share; the decoded path costs :func:`decoded_dot_fraction`
-    handicapped by ``DECODED_OVERHEAD``. Decoded wins only where the
-    occupancy sort gathers dark rows, scattered among live ones, into
-    whole dark groups that the tile map cannot skip.
-
-    The occupancy reduction here is recomputed by the kernel's staging
-    when 'decoded' wins — deliberate: the engine's custom-VJP static
-    args can't carry arrays, the chooser only runs on eager (non-jit)
-    calls, and the duplicated work is O(M*K), ~1/N of the matmul it
-    gates.
-    """
-    m, k = s.shape
-    bm, bk = min(block_m, m), min(block_k, k)
-    sp = pad_to_multiple(pad_to_multiple(s, 0, bm), 1, bk)
-    tile_frac = float(block_occupancy(sp, bm, bk).mean())
-    smp = pad_to_multiple(s, 0, bm)
-    occ = (smp != 0).sum(-1).astype(jnp.int32)
-    dec_frac = decoded_dot_fraction(build_schedule(occ, bm, bk, cap=k))
-    return "decoded" if dec_frac * DECODED_OVERHEAD < tile_frac else "tile"
 
 
 # ---------------------------------------------------------------------------

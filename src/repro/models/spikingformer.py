@@ -166,12 +166,12 @@ def _block(p, st, cfg: ModelConfig, x, train: bool):
 
     The whole layer program — input LIF + SSA bundle + wo/bn_o +
     pre-neuron residuals + spiking MLP — is owned by the engine
-    (core.engine.layer_step): with ``overlap='fused' | 'pipeline'`` both
-    overlay halves run as one Pallas grid spanning the layer (Fig. 5,
-    with the MLP phases riding the same wavefront), otherwise the engine
-    composes the sequential reference (which still hands the SSA bundle
-    to ssa_step, so bundle-level fusion survives a layer-level
-    fallback). The model keeps only the scan plumbing.
+    (core.engine.layer_step): in eval, ``overlap='fused'`` runs both
+    overlay halves as one Pallas grid spanning the layer (Fig. 5, with
+    the MLP phases in the same grid) and ``overlap='off'`` its
+    sequential oracle; train mode and layers the grid does not cover
+    compose the layer from the engine's primitives. The model keeps only
+    the scan plumbing.
     """
     from repro.core.engine import layer_step
     return layer_step(p, st, cfg, x, train=train)

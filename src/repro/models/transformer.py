@@ -181,15 +181,14 @@ def apply_layer(p, cfg: ModelConfig, x, positions, kind: str, train: bool):
     if spiking and kind == "full":
         # the whole layer program — ln1 + SSA bundle + wo + residual +
         # ln2 + spiking MLP + residual — is engine-owned: with
-        # overlap='fused' | 'pipeline' both overlay halves run as one
-        # Pallas grid spanning the layer (Fig. 5, the MLP phases riding
-        # the same wavefront; pipeline adds the timestep axis to the
-        # grid), otherwise the engine composes the sequential reference
-        # (which still hands the bundle to ssa_step_causal). The
+        # overlap='fused' both overlay halves run as one Pallas grid
+        # spanning the layer (Fig. 5, the MLP phases in the same grid),
+        # with overlap='off' its sequential oracle; layers
+        # the grid does not cover get the sequential composition. The
         # sliding-window branch below keeps its banded jnp dataflow
         # (the fused grid is full-attention only).
         from repro.core.engine import layer_step_causal
-        return layer_step_causal(p, cfg, x, positions, train=train)
+        return layer_step_causal(p, cfg, x, positions)
     with _attn_scope(cfg, kind):
         h = nn.rmsnorm(p["ln1"], x, cfg.norm_eps)
         q, k, v = _project_qkv(p, cfg, h, positions, repeat_kv=True,

@@ -1,34 +1,37 @@
-"""Fused layer-program step (kernels/fused_layer.py, engine.layer_step).
+"""The layer program (kernels/fused_layer.py, engine.layer_step).
 
 Pins, in order of the stack:
   * the whole-layer kernel (SSA bundle + output projection + spiking
     MLP as one Pallas grid) is bitwise equal to the *jitted* sequential
-    oracle (``reference_layer``) for both epilogue families, across
-    ``sparse in {tile, decoded}`` and ``overlap in {fused, pipeline}``,
-    including non-divisible L, dark time slabs, all-zero inputs and
-    int8-quantized weights. The oracle must be jitted: the kernel body
-    is always compiled and compiled dots FMA-contract, so the eager
-    reference is NOT the contract (see tests/test_spike_decode.py);
-  * the ``(H, 8, n_l_blocks)`` occupancy map is exact and identical
-    between the fused and pipeline grids;
-  * ``resolve_layer_plan`` folds overlap + sparse dispatch into one
-    static plan (tracer -> off, below min_flops -> off, explicit
-    honored) and ineligible layers (gated MLP, biased linears) take the
-    sequential fallback instead of the kernel;
+    oracle (``reference_layer``) for both epilogue families and
+    ``sparse in {tile, decoded}``, across non-divisible L, dark time
+    slabs, all-zero inputs, random densities and int8-quantized
+    weights. The oracle must be jitted: the kernel body is always
+    compiled and compiled dots FMA-contract, so the eager reference is
+    NOT the contract (see tests/test_spike_decode.py);
+  * the ``(H, 8, n_l_blocks)`` occupancy map is exact;
+  * the sequential SSA bundle of train mode and ineligible layers
+    (``ssa_step`` / ``ssa_step_causal``) equals the eval path's
+    ``reference_bundle`` bitwise;
+  * the plan is static: ``overlap`` (off | fused) and ``sparse`` (tile |
+    decoded) are read as set, eagerly and under jit alike, ``auto`` and
+    ``pipeline`` are refused, and ineligible layers (gated MLP, biased
+    linears) take the sequential fallback instead of the kernel;
   * whole-model logits AND grads are bitwise identical across
-    ``overlap in {off, fused, pipeline}`` x ``sparse in {tile,
-    decoded}`` on the spikingformer configs — also under jit and with
-    int8-quantized weights (eligible layers share one custom-VJP step,
-    so all modes run one gradient program: ``engine._fused_layer``);
-  * ``fused_step_metrics``' 3-D occupancy-map path (layer event
-    schedule, binary-hidden fraction) and the ``sim/balance_sim
+    ``overlap in {off, fused}`` x ``sparse in {tile, decoded}`` on the
+    spikingformer configs — also under jit and with int8-quantized
+    weights (eligible layers share one custom-VJP step, so all modes
+    run one gradient program: ``engine._fused_layer``); profiler scopes
+    are bitwise-neutral;
+  * ``fused_step_metrics`` (layer event schedule, binary-hidden
+    fraction), the Fig. 5 ``measured_schedule`` and the ``sim/balance_sim
     .binary_block_schedule`` numpy twin;
   * the bench-regression gate fails loud on stale baseline key families
     and enforces the layer hidden-fraction floor even at
     ``--update-baselines`` time (negative-tested).
 
-Bit-exactness strategy matches tests/test_fused_ssa.py: dyadic-grid
-weights make fp32 accumulation order-exact, so equality is to the bit.
+Bit-exactness strategy: dyadic-grid weights make fp32 accumulation
+order-exact, so equality is to the bit.
 """
 import json
 import math
@@ -39,6 +42,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+try:
+    from hypothesis import given, settings, strategies as st
+except ImportError:  # container ships the fixed-seed shim
+    from _propcheck import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.core import dual_engine as de
@@ -65,12 +73,13 @@ def _bn_rows(key, n):
                       jnp.full((n,), 0.0625)])
 
 
-def _layer_ops(key, t, b, l, d, heads, hd, ff, *, family, quant=False):
+def _layer_ops(key, t, b, l, d, heads, hd, ff, *, family, quant=False,
+               density=0.3):
     """Raw kernel operands (the layout ``engine.layer_step`` builds),
     with a dark (t=0, b=0) slab and an all-zero row."""
     q_dim = heads * hd
     ks = jax.random.split(key, 8)
-    x = (jax.random.uniform(ks[0], (t, b, l, d)) < 0.3
+    x = (jax.random.uniform(ks[0], (t, b, l, d)) < density
          ).astype(jnp.float32)
     x = x.at[:, :, min(2, l - 1)].set(0.0)
     x = x.at[0, 0].set(0.0)
@@ -121,16 +130,21 @@ def _layer_ops(key, t, b, l, d, heads, hd, ff, *, family, quant=False):
 # (t, b, l, d, heads, hd, ff): non-divisible L vs l_block=8, ff not a
 # heads multiple (exercises the exact zero-pad)
 SHAPE = (2, 2, 13, 16, 2, 8, 21)
+# more layer shapes: non-divisible L, an L of whole blocks, and odd
+# everything (even head_dim for RoPE); q_dim != d in the first and last
+SHAPES = [(2, 2, 13, 24, 4, 8, 42),
+          (2, 1, 16, 32, 2, 16, 64),
+          (3, 2, 9, 17, 3, 6, 35)]
 L_BLOCK, C_BLOCK = 8, 8
 
 
-def _run(args, family, sparse, pipeline, causal=None):
-    causal = (family == "rope") if causal is None else causal
-    kw = dict(family=family, num_heads=SHAPE[4], head_dim=SHAPE[5],
-              scale=1.0 / math.sqrt(SHAPE[5]), causal=causal)
-    out, cnt = FL.fused_layer(*args, sparse=sparse, pipeline=pipeline,
-                              l_block=L_BLOCK, c_block=C_BLOCK, **kw)
-    scfg = SpikingConfig(time_steps=SHAPE[0])
+def _run(args, family, sparse, shape=SHAPE):
+    heads, hd = shape[4], shape[5]
+    kw = dict(family=family, num_heads=heads, head_dim=hd,
+              scale=1.0 / math.sqrt(hd), causal=family == "rope")
+    out, cnt = FL.fused_layer(*args, sparse=sparse, l_block=L_BLOCK,
+                              c_block=C_BLOCK, **kw)
+    scfg = SpikingConfig(time_steps=shape[0])
     ref = jax.jit(lambda *a: FL.reference_layer(*a, scfg, **kw))(*args)
     return out, cnt, ref
 
@@ -138,13 +152,12 @@ def _run(args, family, sparse, pipeline, causal=None):
 @pytest.mark.parametrize("family,sparse", [("bn", "tile"),
                                            ("bn", "decoded"),
                                            ("rope", "tile")])
-@pytest.mark.parametrize("pipeline", [False, True])
-def test_layer_kernel_matches_jitted_oracle_bitwise(family, sparse,
-                                                    pipeline):
-    t, b, l, d, heads, hd, ff = SHAPE
+@pytest.mark.parametrize("shape", SHAPES)
+def test_layer_kernel_matches_jitted_oracle_bitwise(family, sparse, shape):
+    t, b, l, d, heads, hd, ff = shape
     args = _layer_ops(jax.random.PRNGKey(11), t, b, l, d, heads, hd, ff,
                       family=family)
-    out, cnt, ref = _run(args, family, sparse, pipeline)
+    out, cnt, ref = _run(args, family, sparse, shape)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     cnt = np.asarray(cnt)
     assert cnt.shape == (heads, 8, -(-l // L_BLOCK))
@@ -153,22 +166,34 @@ def test_layer_kernel_matches_jitted_oracle_bitwise(family, sparse,
         assert (cnt[:, :3].sum(axis=-1) <= 3 * (t * b - 1)).all()
 
 
-def test_layer_counts_identical_fused_vs_pipeline():
-    t, b, l, d, heads, hd, ff = SHAPE
-    args = _layer_ops(jax.random.PRNGKey(5), t, b, l, d, heads, hd, ff,
-                      family="bn")
-    _, c_f, _ = _run(args, "bn", "tile", False)
-    _, c_p, _ = _run(args, "bn", "tile", True)
-    np.testing.assert_array_equal(np.asarray(c_f), np.asarray(c_p))
-
-
-def test_layer_kernel_int8_weights_bitwise():
+@pytest.mark.parametrize("family", ["bn", "rope"])
+def test_layer_kernel_int8_weights_bitwise(family):
     t, b, l, d, heads, hd, ff = SHAPE
     args = _layer_ops(jax.random.PRNGKey(9), t, b, l, d, heads, hd, ff,
-                      family="bn", quant=True)
+                      family=family, quant=True)
     for sparse in ("tile", "decoded"):
-        out, _, ref = _run(args, "bn", sparse, True)
+        out, _, ref = _run(args, family, sparse)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def _random_density_bitwise(family, seed, density):
+    t, b, l, d, heads, hd, ff = SHAPE
+    args = _layer_ops(jax.random.PRNGKey(seed), t, b, l, d, heads, hd, ff,
+                      family=family, density=density)
+    out, _, ref = _run(args, family, "tile")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@settings(max_examples=6, deadline=None)
+@given(st.integers(0, 10 ** 6), st.floats(0.05, 0.6))
+def test_layer_kernel_property_random_density_bn(seed, density):
+    _random_density_bitwise("bn", seed, density)
+
+
+@settings(max_examples=6, deadline=None)
+@given(st.integers(0, 10 ** 6), st.floats(0.05, 0.6))
+def test_layer_kernel_property_random_density_rope(seed, density):
+    _random_density_bitwise("rope", seed, density)
 
 
 def test_layer_kernel_all_zero_timestep():
@@ -176,7 +201,7 @@ def test_layer_kernel_all_zero_timestep():
     args = _layer_ops(jax.random.PRNGKey(3), t, b, l, d, heads, hd, ff,
                       family="bn")
     args = (jnp.zeros_like(args[0]), jnp.zeros_like(args[1])) + args[2:]
-    out, cnt, ref = _run(args, "bn", "tile", False)
+    out, cnt, ref = _run(args, "bn", "tile")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     # every projection slab dark -> zero executed projection sub-blocks
     np.testing.assert_array_equal(np.asarray(cnt)[:, :3], 0)
@@ -186,7 +211,7 @@ def test_binary_block_schedule_twin_matches_kernel_counts():
     t, b, l, d, heads, hd, ff = SHAPE
     args = _layer_ops(jax.random.PRNGKey(13), t, b, l, d, heads, hd, ff,
                       family="bn")
-    _, cnt, _ = _run(args, "bn", "tile", False)
+    _, cnt, _ = _run(args, "bn", "tile")
     # the twin predicts the binary phases from the projection spikes the
     # kernel emits; recompute them under jit (compiled dots contract)
     scfg = SpikingConfig(time_steps=t)
@@ -226,32 +251,177 @@ def test_binary_block_schedule_predicate_edges():
 
 
 # ---------------------------------------------------------------------------
-# dispatch rules + sequential fallback
+# the SSA bundle: the sequential steps vs the eval layer's oracle bundle
 # ---------------------------------------------------------------------------
 
 
-BIG = 1 << 40
+def _one_layer(arch, quant=None):
+    """(cfg, layer params, BN state) of ``arch``'s SMOKE config, dyadic
+    (optionally int8); the BN state is ``None`` for the token family."""
+    cfg, params, _ = _model_setup(arch, quant)
+    if cfg.family == "dense":
+        return cfg, jax.tree_util.tree_map(lambda a: a[0],
+                                           params["layers"]), None
+    from repro.models import spikingformer
+    bst = spikingformer.init_state(cfg)["blocks"]
+    return (cfg, jax.tree_util.tree_map(lambda a: a[0], params["blocks"]),
+            jax.tree_util.tree_map(lambda a: a[0], bst))
 
 
-def test_resolve_layer_plan_rules():
-    x = jnp.ones((2, 2, 8, 16))
-    assert E.resolve_layer_plan(None, x, BIG) == ("off", "tile")
-    eng = E.EngineConfig(overlap="pipeline", sparse="decoded")
-    assert E.resolve_layer_plan(eng, x, 0) == ("pipeline", "decoded")
-    auto = E.EngineConfig(overlap="auto")
-    assert E.resolve_layer_plan(auto, x, BIG).overlap == "fused"
-    assert E.resolve_layer_plan(auto, x, 10).overlap == "off"
+@pytest.mark.parametrize("quant", [None, "int8"])
+@pytest.mark.parametrize("family", ["bn", "rope"])
+def test_sequential_bundle_matches_reference_bundle(family, quant):
+    """The SSA bundle that train mode and ineligible layers run
+    (``ssa_step`` / ``ssa_step_causal``, composed from the engine's
+    primitives) equals the bundle the eval layer program runs on the
+    chip (``reference_bundle``), bitwise, eagerly and under jit."""
+    arch = "spikingformer-4-256" if family == "bn" else "spikingformer-lm"
+    cfg, p, bst = _one_layer(arch, quant)
+    t, b, l, d = cfg.spiking.time_steps, 2, 13, cfg.d_model
+    hd, names = cfg.head_dim, ("wq", "wk", "wv")
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    if family == "bn":
+        # running stats off the identity, and BN affines that make the
+        # projections fire
+        bst = {f"bn_{n}": {"mean": _dyadic(ks[i], (cfg.q_dim,)) * 0.25,
+                           "var": jnp.abs(_dyadic(ks[i], (cfg.q_dim,))) + 0.5}
+               for i, n in enumerate("qkv")}
+        p = dict(p, **{f"bn_{n}": {"scale": jnp.full((cfg.q_dim,), 2.0),
+                                   "bias": jnp.full((cfg.q_dim,), 0.5)}
+                       for n in "qkv"})
+        s = lif_scan(jax.random.normal(ks[3], (t, b, l, d)),
+                     cfg.spiking)[0]
+        aux = jnp.stack([E._bn_rows(p, bst, f"bn_{n}") for n in "qkv"])
+        seq = lambda p, s: E.ssa_step(p, bst, cfg, s)[0]
+    else:
+        pos = jnp.arange(l)
+        s = jax.random.normal(ks[3], (t, b, l, d))
+        freqs = cfg.rope_theta ** (
+            -jnp.arange(0, hd // 2, dtype=jnp.float32) / (hd // 2))
+        ang = pos.astype(jnp.float32)[:, None] * freqs
+        aux = jnp.stack([jnp.cos(ang), jnp.sin(ang)])
+        seq = lambda p, s: E.ssa_step_causal(p, cfg, s, pos)
+    if quant:
+        w3, sc3 = E._layer_quant_w3(p, names, d, s.dtype)
+    else:
+        w3, sc3 = jnp.stack([p[w]["w"] for w in names]), None
 
+    def ref(s, w3, sc3, aux, delta):
+        return FL.reference_bundle(
+            s, w3, sc3, aux, delta, cfg.spiking, family=family,
+            num_heads=cfg.num_heads, head_dim=hd, scale=1.0 / math.sqrt(hd),
+            causal=family == "rope")
+
+    with E.use_engine(cfg.engine):
+        got, got_jit = seq(p, s), jax.jit(seq)(p, s)
+        want = ref(s, w3, sc3, aux, p["delta"])
+        want_jit = jax.jit(ref)(s, w3, sc3, aux, p["delta"])
+    assert np.any(np.asarray(want) != 0)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got_jit), np.asarray(want_jit))
+
+
+# ---------------------------------------------------------------------------
+# the static plan + sequential fallback
+# ---------------------------------------------------------------------------
+
+
+def _spy_programs(monkeypatch):
+    """Record the layer program each call runs: the fused kernel (with
+    the sparse datapath it was handed; stubbed to return the layer input,
+    its results are pinned above) or the sequential oracle."""
     seen = []
+    oracle = FL.reference_layer
 
-    @jax.jit
-    def f(u):
-        seen.append((E.resolve_layer_plan(auto, u, BIG).overlap,
-                     E.resolve_layer_plan(eng, u, 0).overlap))
-        return u
+    def spy_kernel(*a, **k):
+        seen.append(("fused", k["sparse"]))
+        return a[0], None
 
-    f(x)
-    assert seen == [("off", "pipeline")]  # tracer -> off; explicit honored
+    def spy_oracle(*a, **k):
+        seen.append(("off",))
+        return oracle(*a, **k)
+
+    monkeypatch.setattr(FL, "fused_layer", spy_kernel)
+    monkeypatch.setattr(FL, "reference_layer", spy_oracle)
+    return seen
+
+
+def _eager_and_jit(f, *args):
+    return f(*args), jax.jit(f)(*args)
+
+
+def test_resolve_overlap_modes(monkeypatch):
+    """``overlap`` is read as set: 'fused' runs the layer kernel, 'off'
+    (and no engine) the sequential oracle — eagerly and under jit alike,
+    whatever the input's size."""
+    seen = _spy_programs(monkeypatch)
+    cfg, p, bst = _one_layer("spikingformer-4-256")
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 1, 8, cfg.d_model))
+    for eng, want in ((None, ("off",)),
+                      (E.EngineConfig(overlap="off"), ("off",)),
+                      (E.EngineConfig(overlap="fused"), ("fused", "tile"))):
+        seen.clear()
+        _eager_and_jit(lambda x, e=eng: E.layer_step(p, bst, cfg, x,
+                                                     engine=e)[0], x)
+        assert seen == [want, want], (eng, seen)
+
+
+def test_resolve_layer_plan_rules(monkeypatch):
+    """The plan is (overlap, sparse) as set: the kernel is handed the
+    engine's sparse datapath on both layer families, eagerly and under
+    jit."""
+    seen = _spy_programs(monkeypatch)
+    for arch in ("spikingformer-4-256", "spikingformer-lm"):
+        cfg, p, bst = _one_layer(arch)
+        x = jax.random.normal(jax.random.PRNGKey(1),
+                              (2, 1, 8, cfg.d_model))
+        for sparse in ("tile", "decoded"):
+            eng = E.EngineConfig(overlap="fused", sparse=sparse)
+            if bst is None:
+                f = lambda x, e=eng: E.layer_step_causal(
+                    p, cfg, x, jnp.arange(8), engine=e)
+            else:
+                f = lambda x, e=eng: E.layer_step(p, bst, cfg, x,
+                                                  engine=e)[0]
+            seen.clear()
+            _eager_and_jit(f, x)
+            assert seen == [("fused", sparse)] * 2, (arch, seen)
+
+
+@pytest.mark.parametrize("overlap", ["pipelined", "auto", "pipeline"])
+def test_engine_config_rejects_bad_overlap(overlap):
+    with pytest.raises(ValueError, match="overlap mode"):
+        E.EngineConfig(overlap=overlap)
+
+
+@pytest.mark.parametrize("arch", ["spikingformer-4-256",
+                                  "spikingformer-8-512",
+                                  "spikingformer-lm"])
+def test_layer_step_eager_equals_jit(arch, monkeypatch):
+    """An eager ``layer_step`` call runs the program every jitted step
+    runs, at the config's own engine and at a shape above ``min_flops``
+    (a per-call ``auto`` would fuse it eagerly and not under jit).
+    Weights sit on the dyadic grid and BN running variances at 1 - eps,
+    so the vision layer's arithmetic is exact and XLA's FMA contraction
+    under jit cannot split the two calls by an ulp: what is compared is
+    the program."""
+    seen = _spy_programs(monkeypatch)
+    cfg, p, bst = _one_layer(arch)
+    t, b, l, d = cfg.spiking.time_steps, 4, 64, cfg.d_model
+    assert 6 * t * b * l * d * cfg.q_dim >= cfg.engine.min_flops
+    x = jax.random.normal(jax.random.PRNGKey(3), (t, b, l, d))
+    if bst is None:
+        f = lambda x: E.layer_step_causal(p, cfg, x, jnp.arange(l),
+                                          engine=cfg.engine)
+    else:
+        one = jnp.float32(1.0) - jnp.float32(1e-5)
+        assert one + jnp.float32(1e-5) == 1.0
+        bst = {n: {"mean": v["mean"], "var": jnp.full_like(v["var"], one)}
+               for n, v in bst.items()}
+        f = lambda x: E.layer_step(p, bst, cfg, x, engine=cfg.engine)[0]
+    eager, jitted = _eager_and_jit(f, x)
+    assert seen == [("off",)] * 2, seen
+    np.testing.assert_array_equal(np.asarray(eager), np.asarray(jitted))
 
 
 def test_ineligible_layer_takes_sequential_fallback(monkeypatch):
@@ -291,8 +461,7 @@ def test_ineligible_layer_takes_sequential_fallback(monkeypatch):
 
 SPIKING_ARCHS = ["spikingformer-4-256", "spikingformer-8-512",
                  "spikingformer-lm"]
-MODES = [("off", "tile"), ("fused", "tile"), ("fused", "decoded"),
-         ("pipeline", "tile"), ("pipeline", "decoded")]
+MODES = [("off", "tile"), ("fused", "tile"), ("fused", "decoded")]
 
 
 def _model_setup(arch, quant=None):
@@ -346,7 +515,7 @@ def test_model_grads_bitwise_all_modes(arch, sparse):
 
     grads = [jax.grad(loss)(params,
                             cfg.engine.replace(overlap=ov, sparse=sparse))
-             for ov in ("off", "fused", "pipeline")]
+             for ov in ("off", "fused")]
     for g in grads[1:]:
         for a, b in zip(jax.tree_util.tree_leaves(grads[0]),
                         jax.tree_util.tree_leaves(g)):
@@ -357,7 +526,7 @@ def test_model_int8_logits_and_grads_bitwise():
     cfg, params, batch = _model_setup("spikingformer-4-256", quant="int8")
     outs = _mode_logits(cfg, params, batch,
                         [("off", "tile"), ("fused", "decoded"),
-                         ("pipeline", "tile")])
+                         ("fused", "tile")])
     for got in outs[1:]:
         np.testing.assert_array_equal(outs[0], got)
 
@@ -371,19 +540,20 @@ def test_model_int8_logits_and_grads_bitwise():
     ga = jax.grad(loss, allow_int=True)(params,
                                         cfg.engine.replace(overlap="off"))
     gb = jax.grad(loss, allow_int=True)(
-        params, cfg.engine.replace(overlap="pipeline"))
+        params, cfg.engine.replace(overlap="fused"))
     for a, b in zip(jax.tree_util.tree_leaves(ga),
                     jax.tree_util.tree_leaves(gb)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_model_logits_bitwise_under_jit():
-    """Explicit overlap='pipeline' is honored under jit (the layer sits
-    inside the block scan, so the plan resolves on a tracer — explicit
-    modes must survive that)."""
-    cfg, params, batch = _model_setup("spikingformer-lm")
+@pytest.mark.parametrize("arch", ["spikingformer-4-256",
+                                  "spikingformer-lm"])
+def test_model_logits_bitwise_under_jit(arch):
+    """Explicit overlap='fused' is honored under jit (the layer sits
+    inside the block scan, so its input is always traced)."""
+    cfg, params, batch = _model_setup(arch)
     outs = {}
-    for ov in ("off", "pipeline"):
+    for ov in ("off", "fused"):
         eng = cfg.engine.replace(overlap=ov)
 
         @jax.jit
@@ -392,29 +562,114 @@ def test_model_logits_bitwise_under_jit():
                 return registry.forward(p, cfg, batch)[0]
 
         outs[ov] = np.asarray(f(params))
-    np.testing.assert_array_equal(outs["off"], outs["pipeline"])
+    np.testing.assert_array_equal(outs["off"], outs["fused"])
+
+
+@pytest.mark.parametrize("arch", SPIKING_ARCHS)
+def test_model_logits_bitwise_fused_vs_off(arch):
+    cfg, params, batch = _model_setup(arch)
+    outs = {}
+    for ov in ("off", "fused"):
+        with E.use_engine(cfg.engine.replace(overlap=ov)):
+            logits, _ = registry.forward(params, cfg, batch)
+        outs[ov] = np.asarray(logits)
+    np.testing.assert_array_equal(outs["off"], outs["fused"])
+
+
+@pytest.mark.parametrize("arch", ["spikingformer-4-256", "spikingformer-lm"])
+def test_model_grads_bitwise_fused_vs_off(arch):
+    cfg, params, batch = _model_setup(arch)
+
+    def loss(p, eng):
+        with E.use_engine(eng):
+            logits, _ = registry.forward(p, cfg, batch)
+        return jnp.sum(logits ** 2) * 1e-3
+
+    grads = {ov: jax.grad(loss)(params, cfg.engine.replace(overlap=ov))
+             for ov in ("off", "fused")}
+    for a, b in zip(jax.tree_util.tree_leaves(grads["off"]),
+                    jax.tree_util.tree_leaves(grads["fused"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("arch,ov", [
+    pytest.param("spikingformer-4-256", "off", id="off"),
+    pytest.param("spikingformer-4-256", "fused", id="fused"),
+    pytest.param("spikingformer-8-512", "off", id="8-512-off"),
+    pytest.param("spikingformer-8-512", "fused", id="8-512-fused"),
+])
+def test_annotations_are_bitwise_neutral(arch, ov):
+    """Every program scope (stem, blocks, head, LIF, both engines, the
+    layer program) is metadata: logits match with scopes disabled."""
+    cfg, params, batch = _model_setup(arch)
+    eng = cfg.engine.replace(overlap=ov)
+    with E.use_engine(eng):
+        annotated, _ = registry.forward(params, cfg, batch)
+        with E.disable_annotations():
+            plain, _ = registry.forward(params, cfg, batch)
+    np.testing.assert_array_equal(np.asarray(annotated), np.asarray(plain))
 
 
 # ---------------------------------------------------------------------------
-# occupancy-map metrics (the 3-D fused_step_metrics path)
+# schedules: the Fig. 5 measured schedule and the occupancy-map metrics
 # ---------------------------------------------------------------------------
+
+
+def test_measured_schedule_scalar_path_pinned():
+    ts, tb, heads = 1.3, 0.7, 8
+    se, be, overlapped, serial = de.measured_schedule(ts, tb, heads)
+    # the original two-scalar arithmetic, replayed op-for-op
+    t_sparse = 0.0
+    qk_done, v_done = {}, {}
+    for h in range(heads):
+        for name in ("Q", "K", "V"):
+            t_sparse += ts
+            if name == "K":
+                qk_done[h] = t_sparse
+            if name == "V":
+                v_done[h] = t_sparse
+    t_bin = 0.0
+    for h in range(heads):
+        t_bin = max(t_bin, qk_done[h]) + tb
+        t_bin = max(t_bin, v_done[h]) + tb
+    assert overlapped == max(t_sparse, t_bin)
+    assert serial == t_sparse + 2 * tb * heads
+    assert len(se) == 3 * heads and len(be) == 2 * heads
+
+
+def test_measured_schedule_per_head_matches_uniform_scalar():
+    heads = 4
+    uniform = de.measured_schedule(2.0, 1.0, heads)
+    per_head = de.measured_schedule([(2.0, 2.0, 2.0)] * heads,
+                                    [(1.0, 1.0)] * heads, heads)
+    assert uniform[2] == per_head[2]          # overlapped makespan
+    assert uniform[3] == per_head[3]          # serial total
+    assert uniform[0] == per_head[0] and uniform[1] == per_head[1]
+
+
+def test_measured_schedule_rejects_length_mismatch():
+    with pytest.raises(ValueError):
+        de.measured_schedule([1.0, 2.0], 1.0, heads=4)
+
+
+def test_schedule_metrics_utilization():
+    m = de.schedule_metrics(1.0, 1.0, heads=4)
+    assert 0.0 < m["hidden_fraction"] < 1.0
+    assert 0.0 < m["sparse_util"] <= 1.0
+    assert 0.0 < m["binary_util"] <= 1.0
+    assert m["hidden_fraction"] == pytest.approx(
+        de.measured_overlap_efficiency(1.0, 1.0, 4))
+    # sparse engine never stalls in the Fig. 5 schedule
+    assert m["sparse_util"] == pytest.approx(
+        3 * 4 * 1.0 / m["overlapped"])
 
 
 def _layer_metrics(counts, **over):
     kw = dict(seq=16, k_dim=16, head_dim=8, t_steps=2, batch=2,
               d_model=16, d_ff=32, l_block=8, sparse="tile",
-              c_block=None, pipeline=False)
+              c_block=None)
     kw.update(over)
     return de.fused_step_metrics(counts, **kw)
-
-
-def test_fused_step_metrics_dispatches_on_rank():
-    m2 = de.fused_step_metrics([[4, 4, 4, 8], [4, 4, 4, 8]],
-                               seq=16, k_dim=16, head_dim=8, t_steps=2,
-                               batch=2)
-    assert "proj_skip_fraction" in m2 and "executed_down" not in m2
-    m3 = _layer_metrics([[[4]] * 8, [[4]] * 8])
-    assert "executed_down" in m3 and m3["l_blocks"] == 1
 
 
 def test_layer_metrics_counts_and_bounds():
@@ -452,17 +707,12 @@ def test_layer_event_schedule_dependencies():
     ends = {n: e for n, _, e in se}
     starts = {n: s for n, s, _ in be}
     # binary qkt waits for the sparse k phase; qktv for v
-    assert starts["qkt0@0"] >= ends["k0@0"]
-    assert starts["qktv0@0"] >= ends["v0@0"]
+    assert starts["qkt0"] >= ends["k0"]
+    assert starts["qktv0"] >= ends["v0"]
     # sparse wo stalls on the binary context (qktv) of its head
-    wo_start = [s for n, s, _ in se if n == "wo0@0"][0]
-    qktv_end = [e for n, _, e in be if n == "qktv0@0"][0]
+    wo_start = [s for n, s, _ in se if n == "wo0"][0]
+    qktv_end = [e for n, _, e in be if n == "qktv0"][0]
     assert wo_start >= qktv_end
-    # pipeline chaining keeps total busy time, never stretches it
-    se2, be2 = de.layer_event_schedule(macs, heads=1, iters=2)
-    busy = sum(e - s for _, s, e in se)
-    busy2 = sum(e - s for _, s, e in se2)
-    assert abs(busy - busy2) < 1e-6
 
 
 # ---------------------------------------------------------------------------

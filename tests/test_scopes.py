@@ -10,8 +10,8 @@ They compile the jitted eval step at SMOKE size on the CPU and read its
 * every instruction traced from the step (an ``op_name`` under ``jit(``;
   parameters carry their argument path instead) has a component from the
   program's scopes;
-* where the layer program runs as XLA ops (``overlap`` ``off``, and
-  ``auto`` under jit), the sparse engine's projections, the binary
+* where the layer program runs as XLA ops (``overlap`` ``off``, the
+  configs' default), the sparse engine's projections, the binary
   engine's attention and the LIF scans each appear inside
   ``dual_engine.fused_layer``;
 * with ``overlap='fused'`` the layer kernel sits inside

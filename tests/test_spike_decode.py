@@ -15,10 +15,8 @@ Pins, in order of the pipeline:
   * gradients flow through the shared custom VJP identically to dense;
   * whole-model logits are bitwise equal across dense/tile/decoded on
     both spikingformer configs;
-  * ``sparse='auto'`` picks tile at coherent and at fine-grained/ragged
-    sparsity (every live decoded row group costs a full-K dot per
-    chunk), decoded only where the occupancy sort leaves whole row
-    groups dark, and tile under jit (traced spikes).
+  * the engine's ``sparse`` field is honored as set, eagerly and under
+    jit (no per-call choice).
 
 Bit-exactness strategy matches tests/test_engine.py: dyadic-grid weights
 make fp32 accumulation order-exact, so equality is to the bit, not a
@@ -339,50 +337,32 @@ def test_decoded_dot_fraction_counts_full_k_dots():
     assert frac(_scattered_dark_rows(96, 160)) == 1 / 3   # 2 of 3 dark
 
 
-def test_resolve_sparse_path_modes():
-    auto = E.EngineConfig(mode="sparse", sparse="auto", block_m=32,
-                          block_n=32, block_k=32)
-    coherent = jnp.zeros((96, 160)).at[:, :32].set(1.0)  # dark tiles
-    ragged = _ragged_spikes(jax.random.PRNGKey(0), 96, 160,
-                            lo=0.0, hi=0.2)
-    assert E.resolve_sparse_path(None, ragged) == "tile"
-    assert E.resolve_sparse_path(TILE32, ragged) == "tile"
-    assert E.resolve_sparse_path(DEC32, coherent) == "decoded"
-    assert E.resolve_sparse_path(auto, coherent) == "tile"
-    # no tile goes dark, but every live group costs a full-K dot per chunk
-    assert E.resolve_sparse_path(auto, ragged) == "tile"
-    assert E.resolve_sparse_path(auto, _scattered_dark_rows(96, 160)) == \
-        "decoded"
-
+def test_resolve_sparse_path_modes(monkeypatch):
+    """The engine's sparse datapath is read as set: 'tile' and 'decoded'
+    each reach their own kernel, eagerly and under jit alike, and the
+    two calls agree bitwise."""
+    from repro.kernels import spike_matmul as SM
     seen = []
-
-    @jax.jit
-    def f(s):
-        seen.append(E.resolve_sparse_path(auto, s))
-        return s
-
-    f(ragged)
-    assert seen == ["tile"]  # traced spikes: static fallback
-
-
-def test_sparse_auto_engine_end_to_end_bitwise():
-    """auto dispatch through spike_linear is still bitwise vs dense on
-    every regime (whichever datapath it picks)."""
-    auto = E.EngineConfig(mode="sparse", sparse="auto", block_m=32,
-                          block_n=32, block_k=32)
-    ks = jax.random.split(jax.random.PRNGKey(9), 3)
-    w = _dyadic(ks[2], (160, 64))
-    for s in (_ragged_spikes(ks[0], 96, 160, lo=0.0, hi=0.2),
-              jnp.zeros((96, 160)).at[:, :32].set(1.0),
-              _scattered_dark_rows(96, 160)):
-        dense = E.spike_linear({"w": w}, s, engine=E.DENSE)
-        got = E.spike_linear({"w": w}, s, engine=auto)
-        np.testing.assert_array_equal(np.asarray(dense), np.asarray(got))
+    for mod, name, path in ((SM, "spike_matmul", "tile"),
+                            (SD, "gather_spike_matmul", "decoded")):
+        def spy(*a, real=getattr(mod, name), path=path, **k):
+            seen.append(path)
+            return real(*a, **k)
+        monkeypatch.setattr(mod, name, spy)
+    s = _ragged_spikes(jax.random.PRNGKey(0), 96, 160, lo=0.0, hi=0.2)
+    p = {"w": _dyadic(jax.random.PRNGKey(1), (160, 64))}
+    for eng in (TILE32, DEC32):
+        seen.clear()
+        eager = E.spike_linear(p, s, engine=eng)
+        jitted = jax.jit(lambda u, e=eng: E.spike_linear(p, u, engine=e))(s)
+        assert seen == [eng.sparse] * 2
+        np.testing.assert_array_equal(np.asarray(eager), np.asarray(jitted))
 
 
-def test_engine_config_validates_sparse_field():
+@pytest.mark.parametrize("sparse", ["rowwise", "auto"])
+def test_engine_config_validates_sparse_field(sparse):
     with pytest.raises(ValueError, match="sparse datapath"):
-        E.EngineConfig(sparse="rowwise")
+        E.EngineConfig(sparse=sparse)
 
 
 # ---------------------------------------------------------------------------

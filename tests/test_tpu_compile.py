@@ -36,7 +36,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.fused_layer import fused_layer
-from repro.kernels.fused_ssa import fused_ssa
 from repro.kernels.lif import lif_forward
 from repro.kernels.spike_attention import spike_attention
 from repro.kernels.spike_decode import (gather_spike_matmul,
@@ -133,22 +132,8 @@ def _family(family):
 
 
 @pytest.mark.parametrize("family", ["bn", "rope"])
-def test_fused_ssa(one_chip, family):
-    c = _family(family)
-    q = c["h"] * c["hd"]
-    aux = (3, 4, q) if family == "bn" else (2, c["l"], c["hd"] // 2)
-    _compile(lambda x, w3, a: fused_ssa(
-        x, w3, None, a, 0.5, family=family, num_heads=c["h"],
-        head_dim=c["hd"], scale=c["hd"] ** -0.5, causal=family == "rope",
-        interpret=False)[0],
-        one_chip, ((c["t"], c["b"], c["l"], c["d"]), f32),
-        ((3, c["d"], q), f32), (aux, f32))
-
-
-@pytest.mark.parametrize("family", ["bn", "rope"])
-@pytest.mark.parametrize("overlap", ["fused", "pipeline"])
 @pytest.mark.parametrize("sparse", ["tile", "decoded"])
-def test_fused_layer(one_chip, family, overlap, sparse):
+def test_fused_layer(one_chip, family, sparse):
     c = _family(family)
     t, b, l, d, q, ff = c["t"], c["b"], c["l"], c["d"], c["h"] * c["hd"], \
         c["ff"]
@@ -166,7 +151,7 @@ def test_fused_layer(one_chip, family, overlap, sparse):
             x, s, w3, wo, w1, w2, None, auxp, auxo, aux1, aux2, 0.5,
             family=family, num_heads=c["h"], head_dim=c["hd"],
             scale=c["hd"] ** -0.5, causal=family == "rope", sparse=sparse,
-            pipeline=overlap == "pipeline", interpret=False)[0]
+            interpret=False)[0]
 
     _compile(step, one_chip, *shapes)
 
@@ -201,8 +186,8 @@ def _matmul_sites(text):
 
 
 def test_eval_step_matmul_fusions_carry_engine_scopes(one_chip):
-    """spikingformer-8-512's eval step (bf16, batch 8 at 224x224,
-    ``overlap='auto'``, as the benchmark runs it): inside the layer
+    """spikingformer-8-512's eval step (bf16, batch 8 at 224x224, the
+    config's engine as the benchmark runs it): inside the layer
     program, every fusion holding a matmul sits in the sparse or binary
     engine's scope; outside it, in the stem's or the head's."""
     cfg = get_config("spikingformer-8-512")
